@@ -13,7 +13,7 @@ Measures, per configuration (width-scaled AlexNet / VGG-16 CONV stacks):
   commits to), from the same compiled ``LayerSchedule`` the engine runs.
 
 The headline configurations run AlexNet under an accelerator-class VMEM
-budget (5.875-7.5 MiB): there the pooled output block that
+budget (8.625 MiB): there the pooled output block that
 ``ConvPlan.fuse_pool`` credits against ``vmem_bytes`` is exactly what
 keeps the conv1 11x11 patch tile inside the budget, so the fused plan
 contracts all 121 taps in one MXU pass while the unfused plan must
@@ -44,17 +44,18 @@ except ImportError:                     # direct script execution
 
 Row = tuple[str, float, str]
 
-#: Accelerator-class on-chip budgets for the headline configs: inside
-#: each window, AlexNet conv1's fused-pool plan keeps tap fusion (the
+#: Accelerator-class on-chip budget for the headline configs: inside
+#: its window, AlexNet conv1's fused-pool plan keeps tap fusion (the
 #: 11x11 patch tile + the POOLED output block fit) while the unfused plan
 #: (full 55x55 output block) must stream all 121 taps through the
 #: accumulator one dot at a time — the fused epilogue's VMEM credit
 #: speeds up the convolution itself (~20x on that layer in interpret
-#: mode), on top of the deleted pool pass + OFM roundtrip.  The windows
-#: are (7.01, 7.85) MiB at width 1.0 and (5.78, 5.99) MiB at width 0.25
-#: (the conv1 patch tile is channel-independent: ci = 3 either way).
-FLIP_VMEM_BUDGET = 7864320          # 7.5 MiB, width 1.0
-FLIP_VMEM_BUDGET_W25 = 6160384      # 5.875 MiB, width 0.25
+#: mode), on top of the deleted pool pass + OFM roundtrip.  Under the
+#: tiled VMEM model (``dataflow.conv_vmem_bytes``) the window is
+#: 9014656-9096576 B (8.60-8.68 MiB) at both widths: conv1's 96 (w=1.0)
+#: and 24 (w=0.25) output channels both pad to one 128-lane tile, and its
+#: patch tile is channel-independent (ci = 3 either way).
+FLIP_VMEM_BUDGET = 9043968          # 8.625 MiB, both widths
 
 #: (net, width_mult, in_res, batch, vmem_budget, reps, trials) per tier.
 #: Resolutions are chosen so the pool windows tile their OFMs (the plan
@@ -63,7 +64,7 @@ CONFIGS = {
     "fast": [("alexnet", 0.25, 67, 2, None, 5, 5),
              ("vgg16", 0.125, 32, 2, None, 5, 5)],
     "full": [("alexnet", 1.0, 227, 1, FLIP_VMEM_BUDGET, 3, 9),
-             ("alexnet", 0.25, 227, 1, FLIP_VMEM_BUDGET_W25, 3, 9),
+             ("alexnet", 0.25, 227, 1, FLIP_VMEM_BUDGET, 3, 9),
              ("alexnet", 0.25, 227, 1, None, 3, 7),
              ("vgg16", 0.25, 64, 1, None, 3, 7)],
 }
@@ -178,7 +179,7 @@ def bench_net(net: str, width_mult: float, in_res: int, batch: int = 1,
     x = jax.random.normal(jax.random.PRNGKey(1), (batch, in_res, in_res, 3),
                           jnp.float32)
     policy = DispatchPolicy(vmem_budget=vmem_budget)
-    eng = Engine(backend="pallas", interpret=True, policy=policy)
+    eng = Engine(backend="pallas", policy=policy)
 
     fused_fn, unfused_fn = _conv_stack_fns(net, params, eng)
     # numerics: a tap-mode flip changes accumulation order (one fused dot

@@ -109,7 +109,7 @@ def wall_section(net: str, width_mult: float, batches, *,
 
     head = cnn.fc_head(net, width_mult=width_mult)
     params = cnn.init_fc_head(head, jax.random.PRNGKey(0))
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     k0 = head[0][0]
     # the shared deterministic traffic source: one seeded request pool,
     # batch b serves its first b requests (same bytes as the zoo/pipeline

@@ -53,12 +53,13 @@ schedule replays bit-for-bit; and every served logit row is bitwise
 equal to its model's single-device unbatched forward (no non-finite
 values), no matter which replica or how many retries served it.
 
-The modeled schedule never reads the JAX device count — run with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (set below as a
-default) to spread the execution lanes over a real multi-device CPU
-mesh; the artifact is identical either way.
+The modeled schedule never reads the JAX device count — on the CPU, run
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to spread the
+execution lanes over a real multi-device mesh; the artifact is identical
+either way.
 
-    PYTHONPATH=src python benchmarks/fleet_serve.py --fast --out BENCH_sharded.json
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python benchmarks/fleet_serve.py --fast --out BENCH_sharded.json
 """
 from __future__ import annotations
 
@@ -66,9 +67,6 @@ import argparse
 import json
 import os
 import time
-
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
 
 import numpy as np
 

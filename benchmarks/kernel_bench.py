@@ -1,8 +1,8 @@
 """Kernel micro-benchmarks: MPNA dataflow kernels vs. the jnp oracle.
 
-On this CPU container Pallas runs in interpret mode, so the wall numbers
-characterize the *oracle/XLA* path; the kernels' TPU-side performance is
-what the dry-run roofline models.  The derived column reports the
+On the CPU Pallas runs in the interpreter, so the wall numbers there
+characterize the interpreter, not the chip; on a TPU the same rows time
+the compiled kernels.  The derived column reports the
 dataflow planner's analytic HBM traffic vs. the compulsory minimum —
 the figure of merit the SA-CONV/SA-FC designs optimize.
 """
@@ -86,14 +86,14 @@ def conv_kernels() -> list[Row]:
     return [
         ("kernel/conv_implicit_gemm_interp",
          _time(lambda: conv2d_mpna(x, f, b, act="relu"), reps=3),
-         "pallas interpret, patches on-chip"),
+         "pallas, patches on-chip"),
         ("kernel/conv_im2col_interp",
          _time(lambda: conv2d_im2col(x, f, b, act="relu"), reps=3),
          "legacy: patch matrix in HBM"),
     ]
 
 
-def kernels_interpret() -> list[Row]:
+def kernels_pallas() -> list[Row]:
     from repro.kernels import ref
     from repro.kernels.sa_conv import sa_conv_matmul
     from repro.kernels.sa_fc import sa_fc_matmul
@@ -103,11 +103,11 @@ def kernels_interpret() -> list[Row]:
     ws = jax.random.normal(jax.random.PRNGKey(3), (2048, 1024), jnp.float32)
     rows = [
         ("kernel/sa_conv_256x512x512_interp",
-         _time(lambda: sa_conv_matmul(x, w)), "pallas interpret"),
+         _time(lambda: sa_conv_matmul(x, w)), "pallas"),
         ("kernel/ref_matmul_256x512x512",
          _time(lambda: ref.matmul(x, w)), "jnp oracle"),
         ("kernel/sa_fc_8x2048x1024_interp",
-         _time(lambda: sa_fc_matmul(xs, ws)), "pallas interpret"),
+         _time(lambda: sa_fc_matmul(xs, ws)), "pallas"),
         ("kernel/ref_gemv_8x2048x1024",
          _time(lambda: ref.gemv(xs, ws)), "jnp oracle"),
     ]
@@ -115,7 +115,7 @@ def kernels_interpret() -> list[Row]:
     q = jax.random.normal(jax.random.PRNGKey(4), (1, 256, 4, 64), jnp.float32)
     k = jax.random.normal(jax.random.PRNGKey(5), (1, 256, 2, 64), jnp.float32)
     rows.append(("kernel/flash_attn_256_interp",
-                 _time(lambda: flash_attention(q, k, k)), "pallas interpret"))
+                 _time(lambda: flash_attention(q, k, k)), "pallas"))
     rows.append(("kernel/ref_attn_256",
                  _time(lambda: ref.attention(q, k, k)), "jnp oracle"))
     return rows
@@ -202,5 +202,5 @@ def dispatch_census() -> list[Row]:
     return rows
 
 
-ALL = [matmul_planner, conv_planner, conv_kernels, kernels_interpret,
+ALL = [matmul_planner, conv_planner, conv_kernels, kernels_pallas,
        engine_dispatch, dispatch_census]

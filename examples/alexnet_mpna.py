@@ -48,7 +48,7 @@ def main(argv=None) -> None:
 
     sched = LayerSchedule.compile_cnn("alexnet", batch=args.batch, in_res=67,
                                       width_mult=0.125)
-    eng = Engine(backend="pallas", interpret=True).with_schedule(sched)
+    eng = Engine(backend="pallas").with_schedule(sched)
     with eng.tracing() as tr:
         t0 = time.perf_counter()
         y_mpna = cnn.cnn_forward("alexnet", params, x, eng=eng)
@@ -229,4 +229,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

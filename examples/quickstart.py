@@ -40,7 +40,7 @@ def main():
     print("\n== 2. explicit Engine: both dataflows, same operator ==")
     x = jax.random.normal(jax.random.PRNGKey(0), (64, 512), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (512, 256), jnp.float32)
-    pal = Engine(backend="pallas", interpret=True)
+    pal = Engine(backend="pallas")
     xla = Engine(backend="xla")
     with pal.tracing() as tr:
         y_pal = pal.matmul(x, w, act="relu")
@@ -82,4 +82,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

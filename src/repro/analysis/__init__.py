@@ -28,11 +28,11 @@ Invariants checked, by pass:
 ``residency`` — VMEM residency sanitizer
     * the resident working set re-derived from the block specs alone
       (double-buffered inputs, fp32 accumulator scratch, the pooled or
-      full output tile, the conv patch-tile / tap-streaming temporaries)
-      equals the plan's ``vmem_bytes`` byte-for-byte;
-    * it fits the policy's effective VMEM budget (the conv planner's
-      documented honest over-budget fallback — no tiling fits at all —
-      downgrades to a warning).
+      full output tile; conv blocks in the chip's (sublane, 128) tiles,
+      with the patch-row / tap-streaming temporaries) equals the plan's
+      ``vmem_bytes`` byte-for-byte;
+    * it fits the policy's effective VMEM budget (the conv planner
+      raises ``PlanError`` instead of planning over it).
 
 ``race`` — grid write-race detector
     * the reduction ("arbitrary") grid dimensions form the

@@ -17,7 +17,7 @@ import argparse
 import sys
 
 from repro.analysis.determinism import lint_scheduler_sources
-from repro.analysis.report import AnalysisReport, merge_reports
+from repro.analysis.report import AnalysisReport, Finding, merge_reports
 from repro.analysis.verify import verify_stage_pair
 from repro.core.schedule import ScheduleRegistry
 
@@ -35,10 +35,16 @@ def _verify_zoo_variants(max_batch: int) -> list[AnalysisReport]:
     registers it: abstract (eval_shape) parameter trees, the server's
     planner-preferred micro-batch, the server's engine policy.  The
     int8 variant quantizes its abstract tree so the schedule keys carry
-    the real 1-byte weight stream."""
+    the real 1-byte weight stream.
+
+    A variant the planner refuses (a layer no tiling fits on the chip,
+    e.g. VGG-16's 224x224 stem) has no schedule to verify: it is reported
+    as a warning naming the layer, and the variants that plan are still
+    verified."""
     import jax
 
     from repro.configs.registry import ZOO_MODELS
+    from repro.core.dataflow import PlanError
     from repro.core.quant import quantize_cnn_params
     from repro.models import cnn
     from repro.serve.cnn_server import CNNServer
@@ -51,8 +57,15 @@ def _verify_zoo_variants(max_batch: int) -> list[AnalysisReport]:
                                            in_res=spec.in_res))
         if spec.weight_dtype == "int8":
             params = jax.eval_shape(quantize_cnn_params, params)
-        srv = CNNServer(spec.net, params, in_res=spec.in_res,
-                        max_batch=max_batch)
+        try:
+            srv = CNNServer(spec.net, params, in_res=spec.in_res,
+                            max_batch=max_batch)
+        except PlanError as e:
+            reports.append(AnalysisReport(label=spec.name, findings=[Finding(
+                "residency", f"{spec.name}/{e.op}",
+                f"cannot be served on this chip: {e}",
+                severity="warning")]))
+            continue
         pair = registry.register(
             spec.net, dtype_tag=spec.weight_dtype, batch=srv.microbatch,
             in_res=srv.in_res, in_ch=srv.in_ch,

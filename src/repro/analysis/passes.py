@@ -181,33 +181,51 @@ def check_coverage(ctx: OpContext) -> list[Finding]:
 # ---------------------------------------------------------------------------
 # pass 2: VMEM residency sanitizer
 # ---------------------------------------------------------------------------
+def _vmem_layout_bytes(shape: tuple[int, ...], itemsize: int) -> int:
+    """VMEM bytes of one array: its two minor dims occupy whole tiles of
+    ``32 // itemsize`` rows by 128 lanes (8 x 128 for 32-bit data)."""
+    *lead, rows, cols = (1, *shape) if len(shape) < 2 else shape
+    return (math.prod(lead) * _round_up(rows, 32 // itemsize)
+            * _round_up(cols, LANE) * itemsize)
+
+
 def derive_vmem_bytes(ctx: OpContext) -> int:
     """The op's resident working set, re-derived from the geometry's
     block specs alone (never from the plan's own ``vmem_bytes``), under
     the planner's charging conventions: inputs double-buffered, fp32
-    scratch accumulators, fp32 output tile for the FC/conv kernels (the
+    scratch accumulators, fp32 output tile for the FC kernel (the
     SA-CONV matmul planner historically charges no output-tile term —
     its psum flushes through the accumulator scratch it already
-    charged), and the conv kernel's on-chip patch tile / tap-streaming
-    temporaries.  The (1, bn) fp32 scale/bias rows are uncharged, as in
-    the planner."""
+    charged).  The conv kernel's blocks and scratch are counted as the TPU
+    compiler lays them out (:func:`_vmem_layout_bytes`), plus the values
+    one iteration of its output-row loop holds live — computed here, not
+    by the planner's :func:`~repro.core.dataflow.conv_vmem_bytes`, so a
+    charging error in either shows up as a mismatch.  The (1, bn) fp32
+    scale/bias rows of the matmul kernels are uncharged, as in their
+    planners."""
     g = ctx.geom
     bi, bw = ctx.act_bytes, ctx.weight_bytes
+    if ctx.kind == "conv":
+        itemsize = {"x": bi, "w": bw}          # scale, bias, out: fp32
+        total = sum(2 * _vmem_layout_bytes(s.block, itemsize.get(s.name, 4))
+                    for s in (*g.inputs, g.out))
+        total += sum(_vmem_layout_bytes(s, 4) for s in g.scratch)
+        # one output row: the fp32 dot result and its running sum, the
+        # (ow, taps*pbi) patch row (one view when streaming taps), and
+        # the filter widened to the activation dtype when it is narrower
+        pp, qq, pbi, bj = g.input("w").block
+        ow = g.scratch[0][2]
+        taps = pp * qq if ctx.plan.fuse_taps else 1
+        total += 2 * _vmem_layout_bytes((ow, bj), 4)
+        total += _vmem_layout_bytes((ow, taps * pbi), bi)
+        if bw != bi:
+            total += _vmem_layout_bytes((taps * pbi, bj), bi)
+        return total
     scratch = sum(math.prod(s) for s in g.scratch) * 4
     base = 2 * (g.input("x").elems * bi + g.input("w").elems * bw) + scratch
     if ctx.kind == "matmul":
         return base
-    if ctx.kind == "fc":
-        return base + math.prod(g.out.block) * 4
-    # conv: pooled/full output tile + the tap-mode working set
-    p, q, cbi, cbj = g.input("w").block
-    rows = g.scratch[0][0]                      # oh * ow
-    base += math.prod(g.out.block) * 4
-    if ctx.plan.fuse_taps:
-        base += rows * p * q * cbi * bi         # on-chip patch tile
-    else:
-        base += rows * (cbi * bi + cbj * 4)     # live view + loop temp
-    return base
+    return base + math.prod(g.out.block) * 4
 
 
 def check_residency(ctx: OpContext) -> list[Finding]:
@@ -221,33 +239,11 @@ def check_residency(ctx: OpContext) -> list[Finding]:
             f"{ctx.plan.vmem_bytes} B — plan and kernel disagree about "
             "the working set"))
     if derived > budget:
-        severity = "error"
-        if ctx.kind == "conv" and _conv_nothing_fits(ctx):
-            # plan_conv's honest over-budget fallback: no tiling of this
-            # op fits at all, the plan says so in vmem_bytes, and the
-            # kernel still runs in interpret mode — report, don't fail.
-            severity = "warning"
         out.append(Finding(
             "residency", ctx.op,
             f"resident working set {derived} B overflows the policy "
-            f"VMEM budget {budget} B", severity=severity))
+            f"VMEM budget {budget} B"))
     return out
-
-
-def _conv_nothing_fits(ctx: OpContext) -> bool:
-    """True when not even the minimum conv tiling fits the budget — the
-    planner's documented fallback regime."""
-    k = ctx.key
-    min_bi = dataflow._channel_tiles(k.ci)[0]
-    min_bj = dataflow._channel_tiles(k.co)[0]
-    oh = (k.h - k.p) // k.stride + 1
-    ow = (k.w - k.q) // k.stride + 1
-    minimal = (2 * k.h * k.w * min_bi * ctx.act_bytes
-               + 2 * k.p * k.q * min_bi * min_bj * ctx.weight_bytes
-               + oh * ow * min_bj * 4
-               + oh * ow * min_bj * 4
-               + oh * ow * (min_bi * ctx.act_bytes + min_bj * 4))
-    return minimal > ctx.policy.effective_vmem_budget
 
 
 # ---------------------------------------------------------------------------

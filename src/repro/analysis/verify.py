@@ -14,7 +14,7 @@ from __future__ import annotations
 from repro.analysis.determinism import lint_scheduler_sources
 from repro.analysis.passes import SCHEDULE_PASSES, OpContext
 from repro.analysis.report import AnalysisReport, Finding, merge_reports
-from repro.core.dataflow import ConvPlan, FCPlan, MatmulPlan
+from repro.core.dataflow import ConvPlan, FCPlan, MatmulPlan, conv_phase_dims
 from repro.core.schedule import ConvOpKey, LayerSchedule, OpKey
 from repro.kernels.geometry import (
     conv_geometry,
@@ -42,8 +42,11 @@ def context_for(key: OpKey | ConvOpKey,
         if plan.fuse_pool:
             ooh = (oh - plan.pool_window) // plan.pool_stride + 1
             oow = (ow - plan.pool_window) // plan.pool_stride + 1
-        extents = {"x": (k.batch, k.h, k.w, k.ci),
-                   "w": (k.p, k.q, k.ci, k.co),
+        # a strided conv launches over its phase-split operands
+        hh, ww, pp, qq = conv_phase_dims(k.h, k.w, k.p, k.q, k.stride)
+        pci = k.stride * k.stride * k.ci
+        extents = {"x": (k.batch, hh, ww, pci),
+                   "w": (pp, qq, pci, k.co),
                    "scale": (1, k.co), "bias": (1, k.co),
                    "out": (k.batch, ooh, oow, k.co)}
         return OpContext(op=f"{k.name} [conv]", kind="conv", key=key,

@@ -94,3 +94,30 @@ class TPUChip:
 
 MPNA_PAPER = MPNAConfig()
 TPU_V5E = TPUChip()
+
+#: The chips the planner has constants for, keyed by the ``device_kind``
+#: JAX reports (a v5e reports ``"TPU v5 lite"``).
+CHIPS: dict[str, TPUChip] = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_for(device_kind: str) -> TPUChip:
+    """The planner's constants for a TPU ``device_kind``.  A kind with no
+    entry is an error, never a default: planning one chip with another's
+    VMEM and bandwidth constants would hand the compiler plans it may
+    refuse."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(f"no planner constants for TPU device kind "
+                         f"{device_kind!r}; known: {sorted(CHIPS)}") from None
+
+
+def current_chip() -> TPUChip:
+    """The chip this process plans for: the attached TPU's own constants,
+    looked up by ``device_kind``, or :data:`TPU_V5E` — the modeled target
+    — when the kernels run in the interpreter on another backend."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return TPU_V5E
+    return chip_for(jax.devices()[0].device_kind)

@@ -53,6 +53,21 @@ def _round_down_pow2ish(x: int, m: int) -> int:
     return max(m, (x // m) * m)
 
 
+def tiled_bytes(shape: tuple[int, ...], itemsize: int) -> int:
+    """VMEM bytes one array of ``shape`` occupies on the TPU: the last two
+    dims are laid out in (sublane, 128-lane) tiles, with 8 sublanes of
+    32-bit words (16 for 2-byte, 32 for 1-byte elements), so a ``(227,
+    3)`` fp32 minor pair holds as much VMEM as ``(232, 128)``.  The
+    compiler counts this padded size; a planner that counted the logical
+    size would let it refuse the plan."""
+    if len(shape) < 2:
+        shape = (1,) * (2 - len(shape)) + tuple(shape)
+    *lead, rows, cols = shape
+    sub = 8 * 4 // itemsize
+    return (math.prod(lead) * _round_up(rows, sub) * _round_up(cols, LANE)
+            * itemsize)
+
+
 class PlanError(ValueError):
     """A planner search found no feasible tiling (or refused the request).
 
@@ -85,8 +100,9 @@ class PlanError(ValueError):
         return str(self.args[0]) if self.args else ""
 
     def with_op(self, op: str) -> PlanError:
-        """The same failure, attributed to a named dispatch site."""
-        if self.op:
+        """The same failure, attributed to a named dispatch site (in
+        place of the planner entrypoint that raised it)."""
+        if self.op == op:
             return self
         base = self.message.split(" [", 1)[0]
         return PlanError(base, op=op, shape=self.shape,
@@ -468,10 +484,11 @@ def plan_fc(b: int, n: int, k: int, *,
 # ---------------------------------------------------------------------------
 # CONV planning — the implicit-GEMM SA-CONV schedule (paper Fig. 5 loop nest)
 # ---------------------------------------------------------------------------
-#: Patch-tile element cap for the kernel's fused-tap mode: up to this many
-#: elements the P*Q patch views are assembled into one on-chip tile for a
-#:  single MXU pass; above it (or when the tile would blow the VMEM
-#: budget) the taps stream through the accumulator one dot at a time.
+#: Patch element cap (over the whole output tile) for the kernel's
+#: fused-tap mode: up to this many elements the P*Q patch views of each
+#: output row are assembled into one on-chip patch row for a single MXU
+#: pass; above it (or when that would blow the VMEM budget) the taps
+#: stream through the accumulator one dot at a time.
 #: The decision is made HERE, by the planner, and carried in
 #: :attr:`ConvPlan.fuse_taps` — the kernel obeys the plan.
 TAP_FUSE_ELEMS = 1 << 22
@@ -587,14 +604,63 @@ def classify_conv_regime(batch: int, h: int, w: int, ci: int,
 
 
 def _channel_tiles(c: int) -> list[int]:
-    """Aligned candidate channel tiles <= MAX_TILE, plus the exact channel
-    count (padding-free — e.g. the 3-channel RGB stem)."""
-    out = {min(c, MAX_TILE)}
-    t = SUBLANE
-    while t < c and t < MAX_TILE:
+    """Candidate channel tiles: lane multiples <= MAX_TILE, plus the exact
+    channel count when it is at most one lane tile (padding-free — e.g.
+    the 3-channel RGB stem).  Channels are the minor (lane) dim of every
+    conv block, which the TPU accepts only as a multiple of 128 or as the
+    whole dimension; a tile is either below one lane tile or a whole
+    number of them, so the accumulator splits into 128-lane blocks."""
+    out = {c} if c <= LANE or (c % LANE == 0 and c <= MAX_TILE) else set()
+    t = LANE
+    while t < c and t <= MAX_TILE:
         out.add(t)
         t *= 2
     return sorted(out)
+
+
+def conv_phase_dims(h: int, w: int, p: int, q: int,
+                    stride: int) -> tuple[int, int, int, int]:
+    """``(h', w', p', q')`` of the stride-1 conv a stride-``s`` conv runs
+    as.  The input is split into its ``s*s`` phases, stacked on the
+    channel dim (space-to-depth), and the filter likewise, zero-padded to
+    ``p' = ceil(p/s)`` taps per spatial dim: output ``(r, t)`` then reads
+    only unit-stride windows, the one access pattern the TPU compiler
+    takes for the kernel's shifted views.  ``h'`` is exactly what the
+    ``oh`` output rows read (``oh + p' - 1``); stride 1 is the identity."""
+    oh = (h - p) // stride + 1
+    ow = (w - q) // stride + 1
+    pp, qq = -(-p // stride), -(-q // stride)
+    return oh + pp - 1, ow + qq - 1, pp, qq
+
+
+def conv_vmem_bytes(hh: int, ww: int, pp: int, qq: int, pbi: int, bj: int,
+                    *, oh: int, ow: int, ooh: int, oow: int,
+                    fuse_taps: bool, bytes_in: int, bytes_w: int,
+                    bytes_out: int = 4) -> int:
+    """Resident working set of the implicit-GEMM SA-CONV kernel as the TPU
+    compiler counts it: every block padded to its (sublane, 128) tiles
+    (:func:`tiled_bytes`), pipelined blocks double-buffered, plus the
+    temporaries of one output row of the kernel's row loop.  Dims are the
+    launched (phase-split) ones: a ``(hh, ww, pbi)`` input slab, a
+    ``(pp, qq, pbi, bj)`` filter tile, an ``(oh, ow, bj)`` fp32
+    accumulator and an ``(ooh, oow, bj)`` (pooled) output block.
+    :func:`plan_conv` budgets with it; the static verifier's residency
+    pass recounts the same working set from the launch geometry on its
+    own and must agree byte for byte."""
+    t = tiled_bytes
+    total = (2 * t((hh, ww, pbi), bytes_in)          # input slab
+             + 2 * t((pp, qq, pbi, bj), bytes_w)     # 'parallel weight movement'
+             + 2 * 2 * t((1, bj), 4)                 # scale + bias rows
+             + 2 * t((ooh, oow, bj), bytes_out)      # (pooled) output block
+             + t((oh, ow, bj), 4))                   # fp32 accumulator SPM
+    row = 2 * t((ow, bj), 4)                         # dot result + row sum
+    taps = pp * qq if fuse_taps else 1
+    # fused: the (ow, p*q*bi) patch row one MXU pass contracts; tap-wise:
+    # one live (ow, bi) view.  Narrow filters widen on-chip.
+    row += t((ow, taps * pbi), bytes_in)
+    if bytes_w != bytes_in:
+        row += t((taps * pbi, bj), bytes_in)
+    return total + row
 
 
 def plan_conv(batch: int, h: int, w: int, ci: int,
@@ -631,9 +697,12 @@ def plan_conv(batch: int, h: int, w: int, ci: int,
     *pooled* map ``batch*poh*pow*co*bytes_out`` — the OFM write and the
     pool pass's re-read both disappear.  Fusion is declined (plan falls
     back to the unfused epilogue) when ``act`` is not in
-    :data:`MONOTONE_ACTS` (the reorder act(maxpool(.)) is invalid), when
-    the pool windows don't tile the OFM, or when no tiling fits the VMEM
-    budget.
+    :data:`MONOTONE_ACTS` (the reorder act(maxpool(.)) is invalid) or when
+    the pool windows don't tile the OFM.
+
+    ``vmem_bytes`` is the working set as the TPU compiler counts it
+    (:func:`conv_vmem_bytes`); when no tiling fits the budget the conv
+    cannot run on the chip and a :class:`PlanError` is raised.
     """
     budget = vmem_budget if vmem_budget is not None else chip.vmem_budget
     bw = bytes_w if bytes_w is not None else bytes_in
@@ -652,20 +721,13 @@ def plan_conv(batch: int, h: int, w: int, ci: int,
                  and pool.tiles(oh, ow))
     poh, pow_ = pool.out(oh, ow) if fuse_pool else (oh, ow)
 
+    hh, ww, pp, qq = conv_phase_dims(h, w, p, q, stride)
+
     def vmem(bi: int, bj: int, fused: bool) -> int:
-        base = (2 * h * w * bi * bytes_in        # input slab, double-buffered
-                + 2 * p * q * bi * bj * bw       # 'parallel weight movement'
-                + oh * ow * bj * 4               # fp32 accumulator SPM
-                + poh * pow_ * bj * bytes_out)   # (pooled) output tile
-        if fused:
-            # the on-chip (oh*ow, p*q*bi) patch tile the fused MXU pass
-            # assembles (it never exists in HBM, but it IS working set)
-            base += oh * ow * p * q * bi * bytes_in
-        else:
-            # tap-wise streaming: one live (oh*ow, bi) view plus the
-            # local fp32 accumulator temp the loop carries
-            base += oh * ow * (bi * bytes_in + bj * 4)
-        return base
+        return conv_vmem_bytes(hh, ww, pp, qq, stride * stride * bi, bj,
+                               oh=oh, ow=ow, ooh=poh, oow=pow_,
+                               fuse_taps=fused, bytes_in=bytes_in,
+                               bytes_w=bw, bytes_out=bytes_out)
 
     def fuse(bi: int, bj: int) -> bool:
         return (oh * ow * p * q * bi <= TAP_FUSE_ELEMS
@@ -719,26 +781,19 @@ def plan_conv(batch: int, h: int, w: int, ci: int,
             key = (traffic(bi, bj), case(bi, bj), not fused, -(bi * bj))
             if best is None or key < best[0]:
                 best = (key, bi, bj, fused)
-    if best is not None:
-        _, bi, bj, fused = best
-        final_case = case(bi, bj)
-    else:
-        # Even the minimum (h, w, bi) slab exceeds the budget (no spatial
-        # tiling yet — a huge-resolution input).  Plan the smallest
-        # working set rather than fail: the plan is over budget and says
-        # so honestly in vmem_bytes (on CPU interpret this still runs;
-        # a TPU lowering would need the future spatially-tiled schedule).
-        # A requested pool fusion is declined here — the budget-overflow
-        # fallback sticks to the minimal, well-trodden unfused epilogue.
-        if fuse_pool:
-            return plan_conv(batch, h, w, ci, p, q, co, stride=stride,
-                             bytes_in=bytes_in, bytes_out=bytes_out,
-                             bytes_w=bytes_w, vmem_budget=vmem_budget,
-                             chip=chip, regime=regime)
-        bi = _channel_tiles(ci)[0]
-        bj = _channel_tiles(co)[0]
-        fused = False
-        final_case = 4
+    if best is None:
+        # Not even the minimum (h, w, bi) slab fits: there is no spatial
+        # tiling yet (a huge-resolution input), and declining a pool
+        # fusion would only grow the output block.  The op cannot run on
+        # the chip, so say so here, at schedule time, not in the compiler.
+        bi, bj = _channel_tiles(ci)[0], _channel_tiles(co)[0]
+        raise PlanError(
+            "VMEM budget too small for the minimum SA-CONV input slab "
+            f"({vmem(bi, bj, False)} bytes; the conv needs spatial "
+            "tiling)", op="plan_conv",
+            shape=(batch, h, w, ci, p, q, co, stride), vmem_budget=budget)
+    _, bi, bj, fused = best
+    final_case = case(bi, bj)
     return ConvPlan(final_case, regime, bi, bj, fuse_taps=fused,
                     hbm_bytes=traffic(bi, bj), flops=flops,
                     vmem_bytes=vmem(bi, bj, fused), m=m, n=n, k=k,

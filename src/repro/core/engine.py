@@ -7,7 +7,7 @@ FC -> SA-FC) in an *offline, per-layer schedule* (Sec. V).  This module is
 the runtime half of that design:
 
 * :class:`Engine` — owns the execution configuration (``chip``,
-  ``backend``, ``interpret``), a pluggable :class:`DispatchPolicy` (the
+  ``backend``), a pluggable :class:`DispatchPolicy` (the
   SA-CONV/SA-FC classifier + Case-1..4 planner), an optional compiled
   :class:`repro.core.schedule.LayerSchedule`, and a structured
   :class:`DispatchTrace`.  ``engine.matmul`` / ``engine.attention`` are
@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import dataflow
-from repro.core.accelerator import TPU_V5E, TPUChip
+from repro.core.accelerator import TPU_V5E, TPUChip, current_chip
 from repro.core.dataflow import ConvPlan, FCPlan, MatmulPlan, PoolSpec
 from repro.kernels import ref
 from repro.kernels.pool_act import maxpool_act
@@ -292,7 +292,7 @@ def _cached_conv_plan(policy: DispatchPolicy, batch: int, h: int, w: int,
 # sa_fc).  Bias-less ops get a structurally bias-less VJP — no sentinel
 # zero-bias argument and no fabricated scalar tangent.
 # ---------------------------------------------------------------------------
-def _pallas_matmul(x2d, w, bias, act, regime, interpret, *,
+def _pallas_matmul(x2d, w, bias, act, regime, *,
                    plan=None, w_scale=None, out_dtype=None,
                    vmem_limit=None):
     if regime == "sa_fc":
@@ -306,12 +306,12 @@ def _pallas_matmul(x2d, w, bias, act, regime, interpret, *,
             bn, bk = plan.bn, plan.bk
         return sa_fc_matmul(x2d, w, bias, act=act, bb=bb, bn=bn, bk=bk,
                             w_scale=w_scale, out_dtype=out_dtype,
-                            vmem_limit=vmem_limit, interpret=interpret)
+                            vmem_limit=vmem_limit)
     if isinstance(plan, FCPlan):
         plan = None                  # sa_conv kernel plans its own tiling
     return sa_conv_matmul(x2d, w, bias, act=act, plan=plan,
                           w_scale=w_scale, out_dtype=out_dtype,
-                          interpret=interpret)
+                          vmem_limit=vmem_limit)
 
 
 def _fc_dx_plan(b, n_out, k_con, x_dtype, w_dtype, vmem_limit):
@@ -334,12 +334,11 @@ def _act_grad(pre, act):
 
 
 @functools.lru_cache(maxsize=256)
-def _make_pallas_vjp(act: str, regime: str, interpret: bool,
+def _make_pallas_vjp(act: str, regime: str,
                      has_bias: bool, out_dtype,
                      plan, vmem_limit: int | None = None):
     def _bwd_core(x2d, w, bias, g):
-        pre = _pallas_matmul(x2d, w, bias, "none", regime, interpret,
-                             plan=plan,
+        pre = _pallas_matmul(x2d, w, bias, "none", regime, plan=plan,
                              vmem_limit=vmem_limit).astype(jnp.float32)
         dpre = (g.astype(jnp.float32) * _act_grad(pre, act)).astype(x2d.dtype)
         dx_plan = None
@@ -347,15 +346,16 @@ def _make_pallas_vjp(act: str, regime: str, interpret: bool,
             # dx = dpre (b, n) @ w.T (n, k): plan the transposed stream
             dx_plan = _fc_dx_plan(x2d.shape[0], w.shape[0], w.shape[1],
                                   x2d.dtype, w.dtype, vmem_limit)
-        dx = _pallas_matmul(dpre, w.T, None, "none", regime, interpret,
+        dx = _pallas_matmul(dpre, w.T, None, "none", regime,
                             plan=dx_plan, vmem_limit=vmem_limit)
-        dw = _pallas_matmul(x2d.T, dpre, None, "none", "sa_conv", interpret)
+        dw = _pallas_matmul(x2d.T, dpre, None, "none", "sa_conv",
+                            vmem_limit=vmem_limit)
         return dpre, dx, dw.astype(w.dtype)
 
     if has_bias:
         @jax.custom_vjp
         def f(x2d, w, bias):
-            return _pallas_matmul(x2d, w, bias, act, regime, interpret,
+            return _pallas_matmul(x2d, w, bias, act, regime,
                                   plan=plan, out_dtype=out_dtype,
                                   vmem_limit=vmem_limit)
 
@@ -370,7 +370,7 @@ def _make_pallas_vjp(act: str, regime: str, interpret: bool,
     else:
         @jax.custom_vjp
         def f(x2d, w):
-            return _pallas_matmul(x2d, w, None, act, regime, interpret,
+            return _pallas_matmul(x2d, w, None, act, regime,
                                   plan=plan, out_dtype=out_dtype,
                                   vmem_limit=vmem_limit)
 
@@ -386,7 +386,7 @@ def _make_pallas_vjp(act: str, regime: str, interpret: bool,
     return f
 
 
-def _quantized_pallas_matmul(x2d, wq, w_scale, bias, act, regime, interpret,
+def _quantized_pallas_matmul(x2d, wq, w_scale, bias, act, regime,
                              plan, out_dtype, vmem_limit=None):
     """Quantized pallas matmul, differentiable in ``x`` (and ``bias``).
 
@@ -398,14 +398,14 @@ def _quantized_pallas_matmul(x2d, wq, w_scale, bias, act, regime, interpret,
     has_bias = bias is not None
 
     def pre_fn(xv, bv):
-        return _pallas_matmul(xv, wq, bv, "none", regime, interpret,
+        return _pallas_matmul(xv, wq, bv, "none", regime,
                               plan=plan, w_scale=w_scale,
                               vmem_limit=vmem_limit)
 
     @jax.custom_vjp
     def f(xv, bv):
         return _pallas_matmul(xv, wq, bv if has_bias else None, act, regime,
-                              interpret, plan=plan, w_scale=w_scale,
+                              plan=plan, w_scale=w_scale,
                               out_dtype=out_dtype, vmem_limit=vmem_limit)
 
     def fwd(xv, bv):
@@ -422,7 +422,7 @@ def _quantized_pallas_matmul(x2d, wq, w_scale, bias, act, regime, interpret,
         if regime == "sa_fc":
             dx_plan = _fc_dx_plan(xv.shape[0], wq.shape[0], wq.shape[1],
                                   xv.dtype, wq.dtype, vmem_limit)
-        dx = _pallas_matmul(dscaled, wq.T, None, "none", regime, interpret,
+        dx = _pallas_matmul(dscaled, wq.T, None, "none", regime,
                             plan=dx_plan, vmem_limit=vmem_limit)
         if has_bias:
             db = jnp.sum(dpre, axis=0).astype(bv.dtype)
@@ -446,7 +446,7 @@ class Engine:
     directly or :meth:`activate` it so model code reaching the module-level
     shims resolves to it::
 
-        eng = Engine(backend="pallas", interpret=True)
+        eng = Engine(backend="pallas")
         with eng.tracing() as tr:
             y = eng.matmul(x, w, act="relu", name="fc1")
         print(tr.summary())
@@ -455,9 +455,16 @@ class Engine:
     :meth:`with_schedule` and every named op resolves its
     :class:`~repro.core.dataflow.MatmulPlan` by lookup instead of
     re-planning at trace time (recorded as ``schedule="hit"``).
+
+    The pallas backend's kernels run compiled on a TPU and in the Pallas
+    interpreter elsewhere; the platform decides
+    (:func:`repro.kernels.geometry.interpret_mode`), never the caller.
+    Plans are made for the chip the process runs on
+    (:func:`repro.core.accelerator.current_chip`) unless ``chip`` or
+    ``policy`` names one.
     """
 
-    def __init__(self, *, backend: str = "xla", interpret: bool = True,
+    def __init__(self, *, backend: str = "xla",
                  chip: TPUChip | None = None,
                  policy: DispatchPolicy | None = None,
                  schedule: Any | None = None,
@@ -465,12 +472,11 @@ class Engine:
                  verify_schedules: bool = False) -> None:
         if policy is None:
             policy = DispatchPolicy(chip=chip if chip is not None
-                                    else TPU_V5E)
+                                    else current_chip())
         elif chip is not None and chip is not policy.chip:
             policy = dataclasses.replace(policy, chip=chip)
         self.policy = policy
         self.backend = backend
-        self.interpret = interpret
         self.schedule = schedule
         # debug hook: statically verify any schedule at attach time (and
         # through with_schedule, which round-trips this flag via with_)
@@ -501,9 +507,8 @@ class Engine:
     # -- derivation ---------------------------------------------------------
     def with_(self, **overrides: Any) -> Engine:
         """A derived engine sharing this engine's live trace."""
-        kw = dict(backend=self.backend, interpret=self.interpret,
-                  policy=self.policy, schedule=self.schedule,
-                  trace=self.trace,
+        kw = dict(backend=self.backend, policy=self.policy,
+                  schedule=self.schedule, trace=self.trace,
                   verify_schedules=self.verify_schedules)
         kw.update(overrides)
         return Engine(**kw)
@@ -665,19 +670,19 @@ class Engine:
         x2d = x.reshape(m, k)
         out_dt = jnp.dtype(out_dtype) if out_dtype is not None else x.dtype
         if self.backend == "pallas":
-            vmem_limit = self.policy.effective_vmem_budget if is_fc else None
+            vmem_limit = self.policy.effective_vmem_budget
             if w_scale is not None:
                 # frozen quantized weights: differentiable in x/bias only
                 out = _quantized_pallas_matmul(x2d, wq, w_scale, bias, act,
-                                               plan.regime, self.interpret,
-                                               plan, out_dt, vmem_limit)
+                                               plan.regime, plan, out_dt,
+                                               vmem_limit)
             elif bias is not None:
-                fn = _make_pallas_vjp(act, plan.regime, self.interpret,
-                                      True, out_dt, plan, vmem_limit)
+                fn = _make_pallas_vjp(act, plan.regime, True, out_dt, plan,
+                                      vmem_limit)
                 out = fn(x2d, wq, bias)
             else:
-                fn = _make_pallas_vjp(act, plan.regime, self.interpret,
-                                      False, out_dt, plan, vmem_limit)
+                fn = _make_pallas_vjp(act, plan.regime, False, out_dt, plan,
+                                      vmem_limit)
                 out = fn(x2d, wq)
         else:
             out = ref.matmul_bias_act(x2d, wq, bias, act=act,
@@ -737,10 +742,10 @@ class Engine:
                      pool=pool)
         out_dt = jnp.dtype(out_dtype) if out_dtype is not None else x.dtype
         if self.backend == "pallas":
-            out = sa_conv_implicit(x, fq, bias, stride=stride, act=act,
-                                   plan=plan, w_scale=f_scale,
-                                   out_dtype=out_dt,
-                                   interpret=self.interpret)
+            out = sa_conv_implicit(
+                x, fq, bias, stride=stride, act=act, plan=plan,
+                w_scale=f_scale, out_dtype=out_dt,
+                vmem_limit=self.policy.effective_vmem_budget)
         else:
             ff = fq if f_scale is None else \
                 (fq.astype(jnp.float32) * f_scale.reshape(1, 1, 1, co))
@@ -773,7 +778,7 @@ class Engine:
                      dtype=str(x.dtype), pool=PoolSpec(window, stride))
         if self.backend == "pallas":
             return maxpool_act(x, window=window, stride=stride, act=act,
-                               interpret=self.interpret)
+                               vmem_limit=self.policy.effective_vmem_budget)
         return ref.maxpool_act(x, window=window, stride=stride, act=act)
 
     def attention(self, q, k, v, *, causal=True, window=0, softcap=0.0,
@@ -785,14 +790,13 @@ class Engine:
         if self.backend == "pallas":
             from repro.kernels.attention import flash_attention
             return flash_attention(q, k, v, causal=causal, window=window,
-                                   softcap=softcap, scale=scale,
-                                   interpret=self.interpret)
+                                   softcap=softcap, scale=scale)
         return ref.attention(q, k, v, causal=causal, window=window,
                              softcap=softcap, scale=scale)
 
     def __repr__(self) -> str:
         return (f"Engine(backend={self.backend!r}, "
-                f"interpret={self.interpret}, policy={self.policy}, "
+                f"policy={self.policy}, "
                 f"schedule={'yes' if self.schedule is not None else 'no'})")
 
 
@@ -800,7 +804,7 @@ class Engine:
 # current-engine stack (explicit successor of the old hidden _STATE)
 # ---------------------------------------------------------------------------
 _LOCAL = threading.local()
-_DEFAULT = Engine()
+_DEFAULT: Engine | None = None
 
 
 def _engine_stack() -> list[Engine]:
@@ -814,10 +818,15 @@ def current() -> Engine:
     """The innermost :meth:`Engine.activate`-d engine, else the module
     default (xla backend, default policy)."""
     stack = _engine_stack()
-    return stack[-1] if stack else _DEFAULT
+    return stack[-1] if stack else default_engine()
 
 
 def default_engine() -> Engine:
+    """The module default engine, built on first use (building it asks
+    which chip the process runs on, which importing must not do)."""
+    global _DEFAULT
+    if _DEFAULT is None:
+        _DEFAULT = Engine()
     return _DEFAULT
 
 
@@ -849,10 +858,10 @@ def attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
 
 @contextlib.contextmanager
-def execution(backend: str = "xla", interpret: bool = True):
-    """Deprecated shim: activate a derived engine with these overrides.
+def execution(backend: str = "xla"):
+    """Deprecated shim: activate a derived engine with this backend.
     Prefer constructing an :class:`Engine` and calling its methods."""
-    eng = current().with_(backend=backend, interpret=interpret)
+    eng = current().with_(backend=backend)
     with eng.activate():
         yield eng
 

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
+from repro.kernels.geometry import interpret_mode
 
 NEG_INF = -1e30
 
@@ -91,12 +91,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "softcap", "scale", "bq", "bkv", "interpret"))
+    "causal", "window", "softcap", "scale", "bq", "bkv"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     softcap: float = 0.0, scale: float | None = None,
-                    bq: int = 128, bkv: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    bq: int = 128, bkv: int = 128) -> jax.Array:
     """q: (b, sq, hq, d); k/v: (b, skv, hkv, d) -> (b, sq, hq, d)."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -139,9 +138,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom
             pltpu.VMEM((bq, dp), jnp.float32),    # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(qt, kt, vt)
     out = out[:, :sq, :d].reshape(b, hq, sq, d)
     return jnp.transpose(out, (0, 2, 1, 3))

@@ -31,8 +31,7 @@ from repro.kernels.sa_conv import sa_conv_matmul
 
 def conv2d_mpna(x: jax.Array, f: jax.Array,
                 bias: jax.Array | None = None, *,
-                stride: int = 1, act: str = "none",
-                interpret: bool = True) -> jax.Array:
+                stride: int = 1, act: str = "none") -> jax.Array:
     """Deprecated shim: ``current().conv2d(...)`` on the pallas backend.
 
     x: (N, H, W, I);  f: (P, Q, I, J)  ->  (N, OH, OW, J), VALID.
@@ -40,15 +39,14 @@ def conv2d_mpna(x: jax.Array, f: jax.Array,
     policy/trace/schedule.  Prefer :meth:`Engine.conv2d`.
     """
     from repro.core import engine
-    eng = engine.current().with_(backend="pallas", interpret=interpret)
+    eng = engine.current().with_(backend="pallas")
     return eng.conv2d(x, f, bias, stride=stride, act=act, name="conv2d_mpna")
 
 
-@functools.partial(jax.jit, static_argnames=("stride", "act", "interpret"))
+@functools.partial(jax.jit, static_argnames=("stride", "act"))
 def conv2d_im2col(x: jax.Array, f: jax.Array,
                   bias: jax.Array | None = None, *,
-                  stride: int = 1, act: str = "none",
-                  interpret: bool = True) -> jax.Array:
+                  stride: int = 1, act: str = "none") -> jax.Array:
     """Legacy materialized-im2col CONV — benchmark reference only.
 
     Materializes the (N*OH*OW, I*P*Q) patch matrix in HBM (a kernel-area-
@@ -67,5 +65,5 @@ def conv2d_im2col(x: jax.Array, f: jax.Array,
     lhs = patches.reshape(n * oh * ow, i * p * q)
     rhs = jnp.transpose(f, (2, 0, 1, 3)).reshape(i * p * q, j)
 
-    out = sa_conv_matmul(lhs, rhs, bias, act=act, interpret=interpret)
+    out = sa_conv_matmul(lhs, rhs, bias, act=act)
     return out.reshape(n, oh, ow, j)

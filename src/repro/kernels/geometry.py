@@ -26,10 +26,32 @@ import math
 from collections.abc import Callable
 from dataclasses import dataclass
 
-from repro.core.dataflow import ConvPlan
+import jax
+import jax.numpy as jnp
+
+from repro.core.dataflow import ConvPlan, conv_phase_dims
 
 LANE = 128
 SUBLANE = 16
+
+
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in the interpreter.  The platform
+    decides, in this one place: compiled (Mosaic) on a TPU backend,
+    interpreted everywhere else (the CPU test runs).  Every kernel wrapper
+    asks here at trace time; nothing else picks the mode."""
+    return jax.default_backend() != "tpu"
+
+
+def mxu_precision(dtype) -> jax.lax.Precision | None:
+    """The contraction precision a kernel asks of the MXU for operands of
+    ``dtype``.  fp32 operands ask for ``HIGHEST``: left at the default,
+    the TPU compiler contracts fp32 in a single bf16 pass, and an fp32
+    model would serve at bf16 precision.  Narrower operands keep the
+    default.  (The interpreter computes in fp32 either way.)"""
+    return jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32 \
+        else None
+
 
 #: grid coordinates -> block indices, one int per array dimension
 IndexMap = Callable[..., tuple[int, ...]]
@@ -192,17 +214,27 @@ def conv_geometry(batch: int, h: int, w: int, ci: int,
     :func:`repro.kernels.sa_conv_implicit.sa_conv_implicit` — grid
     ``(batch, co-tiles, ci-tiles)`` with the input-channel contraction
     innermost-sequential; the output block is the *pooled* map when the
-    plan committed the fused maxpool flush epilogue."""
+    plan committed the fused maxpool flush epilogue.
+
+    A strided conv launches as the stride-1 conv over its phase-split
+    operands (:func:`repro.core.dataflow.conv_phase_dims`): the input
+    block is ``(1, h', w', stride**2 * bi)`` and the filter block
+    ``(p', q', stride**2 * bi, bj)``.  The fp32 accumulator is one
+    ``(bj / lb, oh, ow, lb)`` scratch block, split into ``lb = min(bj,
+    128)``-lane blocks: the pooled epilogue's strided row loads need a
+    base memref one lane tile wide."""
     oh = (h - p) // stride + 1
     ow = (w - q) // stride + 1
     ooh, oow = oh, ow
     if plan.fuse_pool:
         ooh = (oh - plan.pool_window) // plan.pool_stride + 1
         oow = (ow - plan.pool_window) // plan.pool_stride + 1
+    hh, ww, pp, qq = conv_phase_dims(h, w, p, q, stride)
     bi, bj = plan.bi, plan.bj
     gi, gj = _cdiv(ci, bi), _cdiv(co, bj)
-    inputs = [BlockSpecInfo("x", (1, h, w, bi), _im_conv_x),
-              BlockSpecInfo("w", (p, q, bi, bj), _im_conv_f)]
+    pbi = stride * stride * bi
+    inputs = [BlockSpecInfo("x", (1, hh, ww, pbi), _im_conv_x),
+              BlockSpecInfo("w", (pp, qq, pbi, bj), _im_conv_f)]
     if has_scale:
         inputs.append(BlockSpecInfo("scale", (1, bj), _im_conv_row))
     if has_bias:
@@ -213,4 +245,4 @@ def conv_geometry(batch: int, h: int, w: int, ci: int,
         inputs=tuple(inputs),
         out=BlockSpecInfo("out", (1, ooh, oow, bj), _im_conv_out),
         out_shape=(batch, ooh, oow, gj * bj),
-        scratch=((oh * ow, bj),))
+        scratch=((bj // min(bj, LANE), oh, ow, min(bj, LANE)),))

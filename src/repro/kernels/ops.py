@@ -2,8 +2,9 @@
 
 Each op has two execution paths with identical semantics:
 
-* ``backend="pallas"``  — the Pallas kernels (interpret=True on CPU; the
-  same code lowers to Mosaic on a TPU backend).  Used by kernel tests,
+* ``backend="pallas"``  — the Pallas kernels (compiled to Mosaic on a TPU
+  backend, run in the Pallas interpreter on the CPU — the platform decides,
+  :func:`repro.kernels.geometry.interpret_mode`).  Used by kernel tests,
   the CNN examples and single-chip benchmarking.
 * ``backend="xla"``     — the pure-jnp oracles from :mod:`repro.kernels.ref`.
   Used under pjit/shard_map (Pallas TPU kernels cannot lower on the CPU

@@ -4,10 +4,10 @@ The paper's pooling & activation unit applies the activation *after*
 MaxPool — valid for monotonically increasing activations (ReLU,
 Leaky-ReLU) and cutting activation-function evaluations by the pool window
 area.  We implement the same operator reordering as one fused VMEM pass:
-each grid step loads an input tile, reduces the pool windows via
-``window**2`` strided shifted-max slices (static, fully vectorized), applies
-the activation to the *pooled* tile, and writes it out — one HBM read and
-one (window^2-times smaller) HBM write per element.
+each grid step lands an input tile in VMEM and, one pooled row at a time,
+reduces the pool windows as the max of ``window**2`` strided row loads from
+the tile ref, applies the activation to the *pooled* row, and writes it out
+— one HBM read and one (window^2-times smaller) HBM write per element.
 """
 from __future__ import annotations
 
@@ -16,33 +16,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
+from repro.kernels.geometry import interpret_mode
 
 
 def _pool_act_kernel(x_ref, o_ref, *, window: int, stride: int, act: str):
-    x = x_ref[...]                       # (1, h, w, bc)
-    _, h, w, bc = x.shape
-    oh = (h - window) // stride + 1
-    ow = (w - window) // stride + 1
-    out = None
-    for p in range(window):
-        for q in range(window):
-            sl = jax.lax.slice(
-                x, (0, p, q, 0),
-                (1, p + (oh - 1) * stride + 1, q + (ow - 1) * stride + 1, bc),
-                (1, stride, stride, 1))
-            out = sl if out is None else jnp.maximum(out, sl)
-    o_ref[...] = ref.apply_act(out, act).astype(o_ref.dtype)
+    _, oh, ow, _ = o_ref.shape
+
+    def pooled_row(i, carry):
+        out = None
+        for p in range(window):
+            for q in range(window):
+                v = x_ref[0, i * stride + p, pl.ds(q, ow, stride=stride), :]
+                out = v if out is None else jnp.maximum(out, v)
+        o_ref[0, i] = ref.apply_act(out, act).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, oh, pooled_row, 0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "stride", "act", "bc",
-                                    "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "stride", "act", "bc",
+                                             "vmem_limit"))
 def maxpool_act(x: jax.Array, *, window: int = 2, stride: int = 2,
                 act: str = "relu", bc: int = 128,
-                interpret: bool = True) -> jax.Array:
-    """(N,H,W,C) -> (N,OH,OW,C) fused maxpool+activation."""
+                vmem_limit: int | None = None) -> jax.Array:
+    """(N,H,W,C) -> (N,OH,OW,C) fused maxpool+activation.  ``vmem_limit``
+    (bytes) is the on-chip budget handed to the compiler.  Runs compiled
+    on a TPU and in the Pallas interpreter elsewhere
+    (:func:`repro.kernels.geometry.interpret_mode`)."""
     n, h, w, c = x.shape
     oh = (h - window) // stride + 1
     ow = (w - window) // stride + 1
@@ -64,6 +67,9 @@ def maxpool_act(x: jax.Array, *, window: int = 2, stride: int = 2,
         in_specs=[pl.BlockSpec((1, h, w, bc), lambda i, j: (i, 0, 0, j))],
         out_specs=pl.BlockSpec((1, oh, ow, bc), lambda i, j: (i, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((n, oh, ow, cp), x.dtype),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret_mode(),
     )(x)
     return out[..., :c]

@@ -32,8 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dataflow import MatmulPlan, plan_matmul
 from repro.kernels import ref
-from repro.kernels.geometry import matmul_geometry
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
+from repro.kernels.geometry import (interpret_mode, matmul_geometry,
+                                    mxu_precision)
 
 
 def _epilogue(acc, scale, bias, act: str):
@@ -56,7 +56,8 @@ def _sa_conv_kernel(x_ref, w_ref, *rest, act: str, has_bias: bool,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...], w_ref[...].astype(x_ref.dtype),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=mxu_precision(x_ref.dtype))
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _flush():
@@ -74,19 +75,21 @@ def _pad_to(x: jax.Array, rows: int, cols: int) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("act", "plan", "out_dtype",
-                                             "interpret"))
+                                             "vmem_limit"))
 def sa_conv_matmul(x: jax.Array, w: jax.Array,
                    bias: jax.Array | None = None, *,
                    act: str = "none",
                    plan: MatmulPlan | None = None,
                    w_scale: jax.Array | None = None,
                    out_dtype=None,
-                   interpret: bool = True) -> jax.Array:
+                   vmem_limit: int | None = None) -> jax.Array:
     """(m,k) @ (k,n) [+ scale, bias, act] through the SA-CONV dataflow.
 
-    ``interpret=True`` is the CPU validation mode; on a real TPU backend the
-    same code lowers to Mosaic with the BlockSpecs chosen by the Case-1..4
-    planner (:func:`repro.core.dataflow.plan_matmul`).  ``w`` may be int8
+    The BlockSpecs are the ones the Case-1..4 planner chose
+    (:func:`repro.core.dataflow.plan_matmul`); ``vmem_limit`` (bytes) is
+    the on-chip budget handed to the compiler.  The kernel runs compiled
+    on a TPU and in the Pallas interpreter elsewhere
+    (:func:`repro.kernels.geometry.interpret_mode`).  ``w`` may be int8
     with ``w_scale`` (1, n) per-output-channel scales; dequantization fuses
     into the accumulator-flush epilogue.
     """
@@ -126,8 +129,9 @@ def sa_conv_matmul(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec(geom.out.block, geom.out.index_map),
         out_shape=jax.ShapeDtypeStruct(geom.out_shape, out_dtype),
         scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in geom.scratch],
-        compiler_params=_CompilerParams(
-            dimension_semantics=geom.dimension_semantics),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=geom.dimension_semantics,
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret_mode(),
     )(*args)
     return out[:m, :n]

@@ -53,8 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dataflow import fc_vmem_bytes
 from repro.kernels import ref
-from repro.kernels.geometry import SUBLANE, fc_geometry
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
+from repro.kernels.geometry import fc_geometry, interpret_mode, mxu_precision
 
 
 def _sa_fc_kernel(x_ref, w_ref, *rest, act: str, has_bias: bool,
@@ -73,7 +72,8 @@ def _sa_fc_kernel(x_ref, w_ref, *rest, act: str, has_bias: bool,
     # revisited inside it.  int8 tiles widen here, on-chip — the raw int8
     # accumulator is rescaled at flush.
     acc_ref[...] += jnp.dot(x_ref[...], w_ref[...].astype(x_ref.dtype),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=mxu_precision(x_ref.dtype))
 
     @pl.when(kk == pl.num_programs(2) - 1)
     def _flush():
@@ -86,8 +86,7 @@ def _sa_fc_kernel(x_ref, w_ref, *rest, act: str, has_bias: bool,
 
 
 @functools.partial(jax.jit, static_argnames=("act", "bb", "bn", "bk",
-                                             "out_dtype", "interpret",
-                                             "vmem_limit"))
+                                             "out_dtype", "vmem_limit"))
 def sa_fc_matmul(x: jax.Array, w: jax.Array,
                  bias: jax.Array | None = None, *,
                  act: str = "none",
@@ -95,8 +94,7 @@ def sa_fc_matmul(x: jax.Array, w: jax.Array,
                  bn: int = 512, bk: int = 512,
                  w_scale: jax.Array | None = None,
                  out_dtype=None,
-                 vmem_limit: int | None = None,
-                 interpret: bool = True) -> jax.Array:
+                 vmem_limit: int | None = None) -> jax.Array:
     """(b,k) @ (k,n) — batch-amortized weight-streaming dataflow.
 
     Grid is ``(batch-tiles, n-tiles, k-tiles)`` with K innermost: each
@@ -114,7 +112,9 @@ def sa_fc_matmul(x: jax.Array, w: jax.Array,
     — activation tile, double-buffered weight tile, fp32 accumulator,
     output tile, per :func:`repro.core.dataflow.fc_vmem_bytes` — exceeds
     the modeled on-chip budget, instead of silently "running" an
-    impossible residency in interpret mode.
+    impossible residency in interpret mode; it is also the limit handed to
+    the compiler.  The kernel runs compiled on a TPU and in the Pallas
+    interpreter elsewhere (:func:`repro.kernels.geometry.interpret_mode`).
     """
     b, k = x.shape
     k2, n = w.shape
@@ -160,8 +160,9 @@ def sa_fc_matmul(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec(geom.out.block, geom.out.index_map),
         out_shape=jax.ShapeDtypeStruct(geom.out_shape, out_dtype),
         scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in geom.scratch],
-        compiler_params=_CompilerParams(
-            dimension_semantics=geom.dimension_semantics),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=geom.dimension_semantics,
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret_mode(),
     )(*args)
     return out[:b, :n]

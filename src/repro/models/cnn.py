@@ -204,7 +204,7 @@ def conv_stage_len(name: str) -> int:
 
 
 def cnn_conv_stage(name: str, params: list, x: jax.Array, *,
-                   backend: str = "pallas", interpret: bool = True,
+                   backend: str = "pallas",
                    eng: engine.Engine | None = None) -> jax.Array:
     """The SA-CONV stage of the dual-array pipeline: the conv+fused-pool
     stack, ``(N, H, W, C) -> (N, features)`` flattened for the classifier
@@ -215,7 +215,7 @@ def cnn_conv_stage(name: str, params: list, x: jax.Array, *,
     bitwise the full forward."""
     spec, _ = NETWORKS[name]
     if eng is None:
-        eng = engine.current().with_(backend=backend, interpret=interpret)
+        eng = engine.current().with_(backend=backend)
     end = conv_stage_len(name)
     ci = pi = 0
     i = 0
@@ -243,7 +243,7 @@ def cnn_conv_stage(name: str, params: list, x: jax.Array, *,
 
 
 def cnn_fc_stage(name: str, params: list, feats: jax.Array, *,
-                 backend: str = "pallas", interpret: bool = True,
+                 backend: str = "pallas",
                  eng: engine.Engine | None = None) -> jax.Array:
     """The SA-FC stage of the dual-array pipeline: the classifier head,
     ``(N, features) -> logits``.  Consumes the hand-off buffer
@@ -252,7 +252,7 @@ def cnn_fc_stage(name: str, params: list, feats: jax.Array, *,
     resolve from a compiled fc-stage schedule unchanged."""
     spec, _ = NETWORKS[name]
     if eng is None:
-        eng = engine.current().with_(backend=backend, interpret=interpret)
+        eng = engine.current().with_(backend=backend)
     start = conv_stage_len(name)
     x = feats
     for fi, (s, p) in enumerate(zip(spec[start:], params[start:]), start=1):
@@ -262,13 +262,13 @@ def cnn_fc_stage(name: str, params: list, feats: jax.Array, *,
 
 
 def cnn_forward(name: str, params: list, x: jax.Array, *,
-                backend: str = "pallas", interpret: bool = True,
+                backend: str = "pallas",
                 eng: engine.Engine | None = None) -> jax.Array:
     """x: (N, H, W, C) -> logits (N, classes).
 
     Supply ``eng`` to run the whole network under an explicit
-    :class:`~repro.core.engine.Engine` (its backend/interpret then govern
-    the CONV kernels too, overriding the ``backend``/``interpret`` args);
+    :class:`~repro.core.engine.Engine` (its backend then governs the CONV
+    kernels too, overriding the ``backend`` arg);
     otherwise one is derived from the ambient engine so an active trace /
     policy / schedule still sees every dispatch.  CONV layers go through
     ``eng.conv2d`` — the implicit-GEMM SA-CONV kernel on the pallas
@@ -289,6 +289,6 @@ def cnn_forward(name: str, params: list, x: jax.Array, *,
     serving pipeline overlaps them across waves without changing any
     per-request math."""
     if eng is None:
-        eng = engine.current().with_(backend=backend, interpret=interpret)
+        eng = engine.current().with_(backend=backend)
     feats = cnn_conv_stage(name, params, x, eng=eng)
     return cnn_fc_stage(name, params, feats, eng=eng)

@@ -118,9 +118,16 @@ class CNNServer:
         self.dtype = jnp.dtype(dtype)
         self.pipeline = pipeline
         self.engine = engine if engine is not None \
-            else Engine(backend="pallas", interpret=True)
+            else Engine(backend="pallas")
         self._planner_microbatch = self._preferred_microbatch()
         self.microbatch = self._planner_microbatch
+        # Plan the conv stack now: a layer no tiling fits on the chip (VGG-16's
+        # 224x224 stem) raises PlanError naming it here, not inside a served
+        # wave.  Conv VMEM does not depend on the batch, so batch 1 suffices.
+        LayerSchedule.compile_cnn(
+            net, stage="conv", batch=1, in_res=self.in_res, in_ch=in_ch,
+            width_mult=width_mult, dtype=self.dtype,
+            policy=self.engine.policy, params=params)
         self.queue: list[CNNRequest] = []
         self.waves: list[WaveReport] = []
         self._wave_counter = 0

@@ -469,17 +469,40 @@ class FleetServer:
         return Mesh(np.array(distinct), axis_names=("data",))
 
     def _lane(self, rid: str, model: str) -> CNNServer:
+        """The replica's execution lane for one model: a ``CNNServer``
+        whose parameters are committed to the replica's own device, so a
+        wave never copies weights between devices."""
+        import jax
+
         if self._lanes is None:
             self._lanes = {}
         lane = self._lanes.setdefault(rid, {})
         srv = lane.get(model)
         if srv is None:
             m = self.models[model]
+            device = self.replica_device(self.replica_ids.index(rid))
             srv = lane[model] = CNNServer(
-                m.spec.net, m.params, in_res=m.server.in_res,
-                width_mult=m.server.width_mult,
+                m.spec.net, jax.device_put(m.params, device),
+                in_res=m.server.in_res, width_mult=m.server.width_mult,
                 max_batch=m.server.max_batch)
         return srv
+
+    def _mesh_params(self, model: str, rids: Sequence[str], mesh):
+        """``model``'s parameters replicated over ``mesh``, assembled from
+        the participant lanes' own committed copies: no second copy of
+        the weights is made on any device."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        on_device = {}
+        for rid in rids:
+            d = self.replica_device(self.replica_ids.index(rid))
+            on_device.setdefault(d, self._lane(rid, model).params)
+        sharding = NamedSharding(mesh, PartitionSpec())
+        return jax.tree.map(
+            lambda *leaves: jax.make_array_from_single_device_arrays(
+                leaves[0].shape, sharding, list(leaves)),
+            *(on_device[d] for d in mesh.devices.flat))
 
     # -- admission ----------------------------------------------------------
     def submit(self, req: ZooRequest) -> bool:
@@ -1150,15 +1173,21 @@ class FleetServer:
         row batch is committed to the ``("data",)`` axis with
         ``jax.device_put`` + ``NamedSharding``
         (:func:`~repro.distributed.sharding.shard_wave_rows`, which pads
-        non-divisible batches with zero rows) and the model's forward
-        runs once over the sharded array.  The per-layer kernels are the
-        same compiled pallas calls the per-replica lanes run — rows are
-        independent in every one of them, so each served row stays
-        **bitwise equal** to the single-device unbatched forward (the
-        probe that rules out whole-forward ``jax.jit`` here: re-fusing
-        the graph breaks that bit-exactness).  Same ``isfinite`` guard
-        and never-wedge discipline as the per-replica executor."""
+        non-divisible batches with zero rows), the parameters are
+        replicated on the mesh, and the model's forward runs under
+        ``shard_map`` over ``("data",)``: each device runs the kernels on
+        its own rows (a Pallas TPU kernel cannot be partitioned by the
+        compiler).  The map is applied eagerly, one op at a time, so the
+        per-layer kernels are the same pallas calls the per-replica lanes
+        run — rows are independent in every one of them, so each served
+        row stays **bitwise equal** to the single-device unbatched
+        forward (a whole-forward ``jax.jit`` would re-fuse the graph and
+        break that bit-exactness on the interpreted kernels).  Same
+        ``isfinite`` guard and never-wedge discipline as the per-replica
+        executor."""
+        import jax
         import jax.numpy as jnp
+        from jax.sharding import PartitionSpec
 
         from repro.distributed.sharding import shard_wave_rows
         from repro.models import cnn
@@ -1170,9 +1199,14 @@ class FleetServer:
             x = jnp.stack([jnp.asarray(r.image, m.server.dtype)
                            for r in a.requests])
             xs, rows = shard_wave_rows(x, mesh)
-            logits = np.asarray(
-                cnn.cnn_forward(m.spec.net, m.params, xs,
-                                eng=m.server.engine))[:rows]
+            forward = jax.shard_map(
+                lambda p, xv: cnn.cnn_forward(m.spec.net, p, xv,
+                                              eng=m.server.engine),
+                mesh=mesh, in_specs=(PartitionSpec(),
+                                     PartitionSpec("data")),
+                out_specs=PartitionSpec("data"), check_vma=False)
+            params = self._mesh_params(a.model, a.shards, mesh)
+            logits = np.asarray(forward(params, xs))[:rows]
         except Exception as e:          # noqa: BLE001 — never wedge
             for r in a.requests:
                 if r.uid in deliver:

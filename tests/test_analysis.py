@@ -327,6 +327,17 @@ def test_cli_verifies_named_net(capsys):
     assert "0 findings" in out
 
 
+def test_cli_verifies_zoo_variants_and_names_the_unplannable_one(capsys):
+    """Both AlexNet variants verify at their micro-batch; full-width
+    VGG-16, which the planner refuses on the chip, is named with its
+    layer as a warning rather than aborting the run."""
+    from repro.analysis.__main__ import main
+    assert main(["--all-zoo-variants", "--skip-determinism-lint"]) == 0
+    out = capsys.readouterr().out
+    assert "[alexnet@b8] OK" in out and "[alexnet-int8@b" in out
+    assert "[residency] vgg16/conv1: cannot be served on this chip" in out
+
+
 def test_cli_requires_a_target():
     from repro.analysis.__main__ import main
     with pytest.raises(SystemExit):

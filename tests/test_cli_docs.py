@@ -4,7 +4,10 @@ The docs PR contract: ``benchmarks/run.py`` and ``python -m
 repro.analysis`` must have accurate, working ``--help`` (no import
 crashes, the documented flags present), and every markdown link in
 README/docs/ROADMAP must resolve (tools/linkcheck.py, the CI ``docs``
-job).  These run the real entry points in subprocesses.
+job).  ``chip_smoke.py`` must refuse to run without a TPU or without the
+package beside it, and the entry points' compilation cache must land
+where the environment says, else at one fixed in-checkout path.  These
+run the real entry points in subprocesses.
 """
 from __future__ import annotations
 
@@ -84,3 +87,72 @@ def test_docs_tree_exists_and_linked_from_readme(doc):
     assert os.path.exists(os.path.join(REPO, "docs", doc))
     readme = open(os.path.join(REPO, "README.md"), encoding="utf-8").read()
     assert f"docs/{doc}" in readme
+
+
+def _run_bare(argv, cwd, **env_extra):
+    """Run ``argv`` in ``cwd`` without the repo's ``src`` on the path and
+    without a compilation-cache directory from the caller's environment."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("PYTHONPATH", "JAX_COMPILATION_CACHE_DIR")}
+    env["JAX_PLATFORMS"] = "cpu"
+    env.update(env_extra)
+    return subprocess.run(argv, cwd=cwd, env=env, capture_output=True,
+                          text=True, timeout=120)
+
+
+def _ok_line(stdout: str) -> bool:
+    return '"ok": true' in (stdout.strip().splitlines() or [""])[-1]
+
+
+def test_chip_smoke_fails_without_a_tpu(tmp_path):
+    """On a CPU-only machine the smoke run exits non-zero and prints no
+    result line: it never falls back to timing the CPU."""
+    p = _run_bare([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                  REPO, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    assert p.returncode != 0
+    assert not _ok_line(p.stdout)
+    assert "no TPU attached" in p.stderr
+    assert f"compile_cache {tmp_path}" in p.stdout
+
+
+def test_chip_smoke_fails_outside_the_repo(tmp_path):
+    """The script alone, without the package beside it, fails too."""
+    alone = tmp_path / "chip_smoke.py"
+    alone.write_text(open(os.path.join(REPO, "chip_smoke.py"),
+                          encoding="utf-8").read())
+    p = _run_bare([sys.executable, str(alone)], tmp_path)
+    assert p.returncode != 0
+    assert not _ok_line(p.stdout)
+    assert "not beside this script" in p.stderr
+
+
+_CACHE_PROBE = (
+    "import jax, repro.serve.zoo, repro.serve.fleet\n"
+    "print(jax.config.jax_compilation_cache_dir)\n"
+    "from repro.launch.compile_cache import enable_compile_cache\n"
+    "print(enable_compile_cache())\n"
+    "print(jax.config.jax_compilation_cache_dir)\n")
+
+
+def test_compile_cache_defaults_to_one_ignored_in_checkout_path():
+    """Importing the library leaves the cache off; an entry point turns it
+    on at ``<checkout>/.jax_cache``, which git ignores."""
+    p = _run_bare([sys.executable, "-c", _CACHE_PROBE], REPO,
+                  PYTHONPATH=os.path.join(REPO, "src"))
+    assert p.returncode == 0, p.stderr
+    before, chosen, after = p.stdout.split()
+    want = os.path.join(REPO, ".jax_cache")
+    assert before == "None"
+    assert chosen == after == want
+    ignored = open(os.path.join(REPO, ".gitignore"), encoding="utf-8").read()
+    assert ".jax_cache/" in ignored.split()
+
+
+def test_compile_cache_uses_the_environment_directory(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` is the cache, and nothing in the
+    program names another directory."""
+    p = _run_bare([sys.executable, "-c", _CACHE_PROBE], REPO,
+                  PYTHONPATH=os.path.join(REPO, "src"),
+                  JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.split() == [str(tmp_path)] * 3

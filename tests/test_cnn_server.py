@@ -65,7 +65,7 @@ def test_server_outputs_bitwise_equal_unbatched(alexnet_params):
     for r in reqs:
         srv.submit(r)
     srv.run()
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     for r in reqs:
         single = cnn.cnn_forward("alexnet", alexnet_params,
                                  jnp.asarray(r.image)[None], eng=eng)
@@ -74,21 +74,22 @@ def test_server_outputs_bitwise_equal_unbatched(alexnet_params):
 
 def test_server_microbatch_is_planner_preferred(alexnet_params):
     """The admission size IS the planner's resident batch tile: a VMEM
-    budget that cannot hold 64 samples shrinks the micro-batch to what
-    one weight pass can amortize."""
+    budget that cannot hold 512 samples shrinks the micro-batch to what
+    one weight pass can amortize.  (The budget must still fit the conv
+    stack, which the server plans when it is built.)"""
     roomy = CNNServer("alexnet", alexnet_params, in_res=RES,
-                      width_mult=WIDTH, max_batch=64)
-    assert roomy.microbatch == 64
-    tight_eng = Engine(backend="pallas", interpret=True,
-                       policy=DispatchPolicy(vmem_budget=200 * 1024))
+                      width_mult=WIDTH, max_batch=512)
+    assert roomy.microbatch == 512
+    tight_eng = Engine(backend="pallas",
+                       policy=DispatchPolicy(vmem_budget=1536 * 1024))
     tight = CNNServer("alexnet", alexnet_params, in_res=RES,
-                      width_mult=WIDTH, max_batch=64, engine=tight_eng)
-    assert tight.microbatch < 64
+                      width_mult=WIDTH, max_batch=512, engine=tight_eng)
+    assert tight.microbatch < 512
     # and it matches the plan of the dominant FC layer exactly
     k, n = max(((p["w"].shape) for s, p in
                 zip(cnn.NETWORKS["alexnet"][0], alexnet_params)
                 if s.kind == "fc"), key=lambda s: s[0] * s[1])
-    plan = tight_eng.policy.plan_fc(64, n, k, act_bytes=4, weight_bytes=4,
+    plan = tight_eng.policy.plan_fc(512, n, k, act_bytes=4, weight_bytes=4,
                                     regime="sa_fc")
     assert tight.microbatch == plan.bb
 
@@ -96,8 +97,8 @@ def test_server_microbatch_is_planner_preferred(alexnet_params):
 def test_server_drains_queue_in_waves(alexnet_params):
     """More requests than one micro-batch: the queue drains in
     planner-sized waves, preserving order and per-request identity."""
-    eng = Engine(backend="pallas", interpret=True,
-                 policy=DispatchPolicy(vmem_budget=200 * 1024))
+    eng = Engine(backend="pallas",
+                 policy=DispatchPolicy(vmem_budget=2 * 1024 * 1024))
     srv = CNNServer("alexnet", alexnet_params, in_res=RES, width_mult=WIDTH,
                     max_batch=4, engine=eng)
     srv.microbatch = 2                      # force small waves for the test
@@ -142,7 +143,7 @@ def test_server_final_wave_smaller_than_planner_microbatch(alexnet_params):
     done = srv.run()
     assert len(done) == 3
     assert [w.batch for w in srv.waves] == [3]
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     single = cnn.cnn_forward("alexnet", alexnet_params,
                              jnp.asarray(reqs[0].image)[None], eng=eng)
     np.testing.assert_array_equal(np.asarray(single)[0], reqs[0].logits)
@@ -187,3 +188,16 @@ def test_server_preferred_microbatch_is_planner_pinned(alexnet_params):
     assert srv.microbatch == srv.preferred_microbatch == 8
     srv.microbatch = 2
     assert srv.preferred_microbatch == 8    # the planner's answer persists
+
+
+def test_server_refuses_an_unplannable_conv_stack_when_built(alexnet_params):
+    """A conv stack no tiling fits raises PlanError naming the layer when
+    the server is built — never later, inside a wave a zoo would
+    quarantine."""
+    from repro.core.dataflow import PlanError
+    eng = Engine(backend="pallas",
+                 policy=DispatchPolicy(vmem_budget=512 * 1024))
+    with pytest.raises(PlanError) as ei:
+        CNNServer("alexnet", alexnet_params, in_res=RES, width_mult=WIDTH,
+                  max_batch=8, engine=eng)
+    assert ei.value.op == "conv1"

@@ -164,7 +164,7 @@ def test_qtensor_matmul_matches_dequant_oracle(backend):
     w = jax.random.normal(jax.random.PRNGKey(2), (256, 128), jnp.float32) * 0.1
     b = jax.random.normal(jax.random.PRNGKey(3), (128,), jnp.float32)
     qt = quant.quantize(w)
-    eng = Engine(backend=backend, interpret=True)
+    eng = Engine(backend=backend)
     with eng.tracing() as tr:
         y = eng.matmul(x, qt, b, act="relu", name="q")
     oracle = ref.matmul_bias_act(x, quant.dequantize(qt, jnp.float32), b,
@@ -181,16 +181,16 @@ def test_qtensor_reaches_pallas_kernel_undequantized(monkeypatch):
     seen = {}
     real = E._pallas_matmul
 
-    def spy(x2d, w, bias, act, regime, interpret, **kw):
+    def spy(x2d, w, bias, act, regime, **kw):
         seen["w_dtype"] = w.dtype
         seen["w_scale"] = kw.get("w_scale") is not None
-        return real(x2d, w, bias, act, regime, interpret, **kw)
+        return real(x2d, w, bias, act, regime, **kw)
 
     monkeypatch.setattr(E, "_pallas_matmul", spy)
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 256), jnp.float32)
     qt = quant.quantize(
         jax.random.normal(jax.random.PRNGKey(2), (256, 128)) * 0.1)
-    Engine(backend="pallas", interpret=True).matmul(x, qt)
+    Engine(backend="pallas").matmul(x, qt)
     assert seen["w_dtype"] == jnp.int8
     assert seen["w_scale"] is True
 
@@ -207,7 +207,7 @@ def test_qtensor_pallas_grad_flows_through_int8():
     b = jax.random.normal(jax.random.PRNGKey(2), (128,), jnp.float32)
     qt = quant.quantize(w)
     wd = quant.dequantize(qt, jnp.float32)
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     gx, gb = jax.grad(lambda a, c: eng.matmul(a, qt, c, act="relu").sum(),
                       argnums=(0, 1))(x, b)
     gx_r, gb_r = jax.grad(
@@ -248,7 +248,7 @@ def test_biasless_pallas_vjp_structurally_clean():
     no sentinel bias tangent — and matches the oracle."""
     x = jax.random.normal(jax.random.PRNGKey(0), (32, 64), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 48), jnp.float32) * 0.1
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     gx, gw = jax.grad(lambda a, b: eng.matmul(a, b, act="relu").sum(),
                       argnums=(0, 1))(x, w)
     gx2, gw2 = jax.grad(
@@ -263,7 +263,7 @@ def test_bias_pallas_vjp_matches_oracle():
     x = jax.random.normal(jax.random.PRNGKey(0), (32, 64), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 48), jnp.float32) * 0.1
     b = jax.random.normal(jax.random.PRNGKey(2), (48,), jnp.float32)
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     grads = jax.grad(lambda a, c, d: eng.matmul(a, c, d, act="relu").sum(),
                      argnums=(0, 1, 2))(x, w, b)
     oracle = jax.grad(
@@ -280,7 +280,7 @@ def test_out_dtype_cast_exactly_once(backend):
     x = jax.random.normal(jax.random.PRNGKey(0), (32, 256)).astype(jnp.bfloat16)
     w = (jax.random.normal(jax.random.PRNGKey(1), (256, 128)) * 0.1
          ).astype(jnp.bfloat16)
-    eng = Engine(backend=backend, interpret=True)
+    eng = Engine(backend=backend)
     y = eng.matmul(x, w, out_dtype=jnp.float32)
     assert y.dtype == jnp.float32
     exact = jnp.matmul(x, w, preferred_element_type=jnp.float32)
@@ -359,3 +359,31 @@ def test_terms_from_schedule_consistent():
     assert t.flops_per_chip == sum(p.flops for p in sched.values())
     assert t.hbm_bytes_per_chip > 0
     assert t.memory_s() > 0 and t.compute_s() > 0
+
+
+# ---------------------------------------------------------------------------
+# the platform decides: interpret mode and the planner's chip
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("backend,interpreted", [("cpu", True),
+                                                 ("tpu", False)])
+def test_platform_decides_interpret_mode(monkeypatch, backend, interpreted):
+    from repro.kernels.geometry import interpret_mode
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert interpret_mode() is interpreted
+
+
+def test_engine_plans_for_the_attached_chip(monkeypatch):
+    """Off the chip the planner targets the modeled v5e; on a TPU it looks
+    the chip up by ``device_kind``, and an unknown kind is an error."""
+    from repro.core.accelerator import TPU_V5E, chip_for
+
+    assert Engine().policy.chip is TPU_V5E
+    assert chip_for("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v4"):
+        chip_for("TPU v4")
+    # a TPU backend whose device kind has no constants (here: the CPU's)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="no planner constants"):
+        Engine(backend="pallas")
+    assert Engine(backend="pallas", chip=TPU_V5E).policy.chip is TPU_V5E

@@ -201,7 +201,7 @@ def test_sa_fc_executes_plan_tiles_verbatim(monkeypatch):
 # engine + schedule: the FCPlan rides the dispatch end to end
 # ---------------------------------------------------------------------------
 def test_engine_fc_dispatch_carries_fc_plan():
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     x, w = _rand(0, (8, 2048)), _rand(1, (2048, 1024)) * 0.1
     with eng.tracing() as tr:
         y = eng.matmul(x, w, act="relu", name="fc1")
@@ -223,7 +223,7 @@ def test_engine_forced_sa_conv_keeps_matmul_plan():
 def test_engine_fc_grad_flows_through_batch_tiled_kernel():
     """The custom VJP still delivers (dx, dw, db) through the batch-tiled
     forward."""
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     x = _rand(0, (8, 256))
     w = _rand(1, (256, 128)) * 0.1
     b = _rand(2, (128,))
@@ -252,7 +252,7 @@ def test_fc_backward_dx_batch_tiled_under_budget(monkeypatch):
 
     monkeypatch.setattr(E, "sa_fc_matmul", spy)
     budget = 600 * 1024
-    eng = Engine(backend="pallas", interpret=True,
+    eng = Engine(backend="pallas",
                  policy=DispatchPolicy(vmem_budget=budget))
     x = _rand(0, (256, 512))
     w = _rand(1, (512, 384)) * 0.1
@@ -282,7 +282,7 @@ def test_cnn_schedule_fc_entries_are_fc_plans_and_hit():
     params = cnn.init_cnn("alexnet", jax.random.PRNGKey(0), in_res=67,
                           width_mult=0.125)
     x = _rand(1, (4, 67, 67, 3))
-    eng = Engine(backend="pallas", interpret=True).with_schedule(sched)
+    eng = Engine(backend="pallas").with_schedule(sched)
     with eng.tracing() as tr:
         y = cnn.cnn_forward("alexnet", params, x, eng=eng)
     y_ref = cnn.cnn_forward("alexnet", params, x, backend="xla")

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.core import quant
-from repro.core.dataflow import (MONOTONE_ACTS, PoolSpec, plan_conv)
+from repro.core.dataflow import MONOTONE_ACTS, PlanError, PoolSpec, plan_conv
 from repro.core.engine import DispatchPolicy, Engine
 from repro.core.perf_model import pallas_conv_traffic
 from repro.core.schedule import LayerSchedule, clear_schedule_cache
@@ -54,7 +54,7 @@ def test_fused_equals_unfused_exact(window, conv_stride, act, wdtype):
     f = _rand(1, (3, 3, 6, 24), 0.2)
     w = quant.quantize(f) if wdtype == "int8" else f
     b = _rand(2, (24,))
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     with eng.tracing() as tr:
         fused = eng.conv2d(x, w, b, stride=conv_stride, act=act,
                            pool=PoolSpec(window, pool_stride), name="c")
@@ -73,7 +73,7 @@ def test_fused_matches_xla_oracle():
     x = _rand(0, (2, 15, 15, 8))
     f = _rand(1, (3, 3, 8, 32), 0.2)
     b = _rand(2, (32,))
-    pal = Engine(backend="pallas", interpret=True)
+    pal = Engine(backend="pallas")
     xla = Engine(backend="xla")
     got = pal.conv2d(x, f, b, act="relu", pool=PoolSpec(3, 2))
     want = xla.conv2d(x, f, b, act="relu", pool=PoolSpec(3, 2))
@@ -92,7 +92,7 @@ def test_plan_declines_non_tiling_pool_and_engine_falls_back():
     x = _rand(0, (2, 14, 14, 6))              # oh = 12, (12-3) % 2 == 1
     f = _rand(1, (3, 3, 6, 16), 0.2)
     b = _rand(2, (16,))
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     with eng.tracing() as tr:
         got = eng.conv2d(x, f, b, act="relu", pool=PoolSpec(3, 2), name="c")
     assert not tr[0].conv_plan.fuse_pool
@@ -110,7 +110,7 @@ def test_plan_declines_non_monotone_act():
                      pool=PoolSpec(3, 2), act="silu")
     assert not plan.fuse_pool
     x, f = _rand(0, (2, 15, 15, 8)), _rand(1, (3, 3, 8, 32), 0.2)
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     got = eng.conv2d(x, f, act="silu", pool=PoolSpec(3, 2))
     want = ref.maxpool2d(ref.apply_act(ref.conv2d(x, f), "silu"),
                          window=3, stride=2)
@@ -118,17 +118,25 @@ def test_plan_declines_non_monotone_act():
 
 
 def test_plan_declines_on_vmem_budget_overflow():
-    """A budget below even the minimum slab working set: the plan falls
-    back to the minimal unfused schedule and says so (fuse_pool=False)."""
-    plan = plan_conv(1, 21, 21, 64, 3, 3, 128, bytes_in=4, bytes_w=4,
-                     vmem_budget=64 * 1024, pool=PoolSpec(3, 2), act="relu")
-    assert not plan.fuse_pool and plan.pool_window == 0
-    eng = Engine(backend="pallas", interpret=True,
-                 policy=DispatchPolicy(vmem_budget=64 * 1024))
+    """A budget below even the minimum slab working set: the planner
+    declines the op at schedule time with a PlanError naming the layer —
+    never an over-budget plan for the chip's compiler to refuse.  At a
+    budget that fits, the same op runs fused and matches the oracle."""
+    with pytest.raises(PlanError) as ei:
+        plan_conv(1, 21, 21, 64, 3, 3, 128, bytes_in=4, bytes_w=4,
+                  vmem_budget=64 * 1024, pool=PoolSpec(3, 2), act="relu")
+    assert ei.value.vmem_budget == 64 * 1024
     x, f = _rand(0, (1, 21, 21, 64)), _rand(1, (3, 3, 64, 128), 0.1)
+    eng = Engine(backend="pallas",
+                 policy=DispatchPolicy(vmem_budget=64 * 1024))
+    with pytest.raises(PlanError) as ei:
+        eng.conv2d(x, f, act="relu", pool=PoolSpec(3, 2), name="c")
+    assert ei.value.op == "c"
+    eng = Engine(backend="pallas",
+                 policy=DispatchPolicy(vmem_budget=4 * 1024 * 1024))
     with eng.tracing() as tr:
         got = eng.conv2d(x, f, act="relu", pool=PoolSpec(3, 2), name="c")
-    assert not tr[0].conv_plan.fuse_pool and tr[1].regime == "pool"
+    assert tr[0].conv_plan.fuse_pool
     want = ref.maxpool2d(ref.apply_act(ref.conv2d(x, f), "relu"),
                          window=3, stride=2)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
@@ -160,10 +168,11 @@ def test_pooled_output_block_keeps_tap_fusion_alive():
     an accelerator-class VMEM budget, the pooled output block credited by
     ``fuse_pool`` is what keeps AlexNet conv1's 11x11 patch tile inside
     the budget — the fused plan contracts all 121 taps in one MXU pass
-    while the unfused plan must stream them.  Pin the flip so planner
-    drift that moves the window shows up here, not as a silent perf
-    regression."""
-    for co, budget in ((24, 6160384), (96, 7864320)):   # w=0.25 / w=1.0
+    per output row while the unfused plan must stream them.  Pin the flip
+    so planner drift that moves the window shows up here, not as a silent
+    perf regression.  (Both widths pad their channels to one 128-lane
+    tile, so one budget serves both.)"""
+    for co, budget in ((24, 9043968), (96, 9043968)):   # w=0.25 / w=1.0
         fused = plan_conv(1, 227, 227, 3, 11, 11, co, stride=4,
                           bytes_in=4, bytes_w=4, vmem_budget=budget,
                           pool=PoolSpec(3, 2), act="relu")
@@ -199,7 +208,7 @@ def test_schedule_and_roofline_carry_fused_traffic():
 def test_standalone_pool_dispatches_through_engine():
     x = _rand(0, (2, 8, 8, 20))
     for backend in ("pallas", "xla"):
-        eng = Engine(backend=backend, interpret=True)
+        eng = Engine(backend=backend)
         with eng.tracing() as tr:
             got = eng.pool(x, window=2, stride=2, name="pool1")
         assert len(tr) == 1 and tr[0].regime == "pool"

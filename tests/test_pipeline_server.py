@@ -41,7 +41,7 @@ def test_stage_composition_bitwise_equals_forward(alexnet_params):
     kernels, bitwise-equal logits."""
     x = jax.random.normal(jax.random.PRNGKey(1), (2, RES, RES, 3),
                           jnp.float32)
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     full = cnn.cnn_forward("alexnet", alexnet_params, x, eng=eng)
     feats = cnn.cnn_conv_stage("alexnet", alexnet_params, x, eng=eng)
     split = cnn.cnn_fc_stage("alexnet", alexnet_params, feats, eng=eng)
@@ -92,7 +92,7 @@ def test_pipelined_bitwise_equal_sequential_and_unbatched(alexnet_params):
                       sorted(done_s, key=lambda r: r.uid)):
         assert rp.uid == rs.uid
         np.testing.assert_array_equal(rp.logits, rs.logits)
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     one = cnn.cnn_forward("alexnet", alexnet_params,
                           jnp.asarray(reqs_p[0].image)[None], eng=eng)
     np.testing.assert_array_equal(np.asarray(one)[0], done_p[0].logits)
@@ -135,7 +135,7 @@ def test_vgg16_end_to_end_through_engine_schedule():
                           width_mult=WIDTH)
     sched = LayerSchedule.compile_cnn("vgg16", batch=1, in_res=32,
                                       width_mult=WIDTH)
-    eng = Engine(backend="pallas", interpret=True).with_schedule(sched)
+    eng = Engine(backend="pallas").with_schedule(sched)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 32, 3),
                           jnp.float32)
     with eng.tracing() as tr:
@@ -168,7 +168,7 @@ def test_vgg16_pipelined_server_parity():
         srv.submit(r)
     done = srv.run()
     assert len(done) == 2 and len(srv.waves) == 2     # overlapped waves
-    eng = Engine(backend="pallas", interpret=True)
+    eng = Engine(backend="pallas")
     one = cnn.cnn_forward("vgg16", params,
                           jnp.asarray(reqs[0].image)[None], eng=eng)
     np.testing.assert_array_equal(np.asarray(one)[0], done[0].logits)

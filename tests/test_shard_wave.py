@@ -309,6 +309,27 @@ def test_executed_sharded_wave_bitwise_equals_single_device():
     _assert_bitwise(rep, models, 6)
 
 
+def test_mesh_params_reuse_the_lanes_device_copies():
+    """A cooperative wave's mesh-replicated parameters are assembled from
+    the participant lanes' committed per-device copies: every shard is a
+    lane's own buffer, so no second copy of the weights lands in HBM."""
+    import jax
+
+    fleet = FleetServer(zoo_models(), n_replicas=4, policy=FIFOPolicy(),
+                        shard_waves=True)
+    rids = fleet.replica_ids
+    mesh = fleet.shard_mesh(rids)
+    params = fleet._mesh_params("alexnet-int8", rids, mesh)
+    lanes = [fleet._lane(rid, "alexnet-int8") for rid in rids]
+    lane_bufs = {leaf.unsafe_buffer_pointer()
+                 for lane in lanes for leaf in jax.tree.leaves(lane.params)}
+    for leaf in jax.tree.leaves(params):
+        assert leaf.sharding.is_fully_replicated
+        assert len(leaf.addressable_shards) == mesh.devices.size
+        for shard in leaf.addressable_shards:
+            assert shard.data.unsafe_buffer_pointer() in lane_bufs
+
+
 def test_executed_midwave_kill_resharded_retry_bitwise():
     """Satellite invariant: the re-sharded retry after a mid-wave kill
     is still bitwise-equal on the survivor mesh."""

@@ -14,38 +14,29 @@ from repro.distributed.elastic import MeshPlan, degrade_sequence, replan
 from repro.serve.errors import InsufficientReplicasError, ServeError
 
 
-def _abstract_mesh(sizes, names):
-    """jax changed AbstractMesh's signature across versions:
-    (shape_tuple of (name, size) pairs) vs (axis_sizes, axis_names)."""
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(sizes, names)
-
-
 # -- dp_axes / dp_size / tp_size ---------------------------------------------
 
 def test_dp_axes_selects_pod_and_data_in_order():
-    assert SH.dp_axes(_abstract_mesh((4, 8), ("data", "model"))) \
+    assert SH.dp_axes(AbstractMesh((4, 8), ("data", "model"))) \
         == ("data",)
-    assert SH.dp_axes(_abstract_mesh((2, 4, 8),
+    assert SH.dp_axes(AbstractMesh((2, 4, 8),
                                      ("pod", "data", "model"))) \
         == ("pod", "data")
     # a model-only mesh has no data-parallel axes at all
-    assert SH.dp_axes(_abstract_mesh((8,), ("model",))) == ()
+    assert SH.dp_axes(AbstractMesh((8,), ("model",))) == ()
 
 
 def test_dp_size_multiplies_every_dp_axis():
-    assert SH.dp_size(_abstract_mesh((4, 8), ("data", "model"))) == 4
-    assert SH.dp_size(_abstract_mesh((2, 4, 8),
+    assert SH.dp_size(AbstractMesh((4, 8), ("data", "model"))) == 4
+    assert SH.dp_size(AbstractMesh((2, 4, 8),
                                      ("pod", "data", "model"))) == 8
     # no dp axes -> the empty product, 1
-    assert SH.dp_size(_abstract_mesh((8,), ("model",))) == 1
+    assert SH.dp_size(AbstractMesh((8,), ("model",))) == 1
 
 
 def test_tp_size_defaults_to_one_without_model_axis():
-    assert SH.tp_size(_abstract_mesh((4, 8), ("data", "model"))) == 8
-    assert SH.tp_size(_abstract_mesh((4,), ("data",))) == 1
+    assert SH.tp_size(AbstractMesh((4, 8), ("data", "model"))) == 8
+    assert SH.tp_size(AbstractMesh((4,), ("data",))) == 1
 
 
 # -- elastic.replan edges ----------------------------------------------------

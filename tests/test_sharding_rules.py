@@ -11,17 +11,8 @@ from repro.configs.registry import all_lm_configs
 from repro.distributed import sharding as SH
 from repro.models import transformer as T
 
-def _abstract_mesh(sizes, names):
-    """jax changed AbstractMesh's signature across versions:
-    (shape_tuple of (name, size) pairs) vs (axis_sizes, axis_names)."""
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(sizes, names)
-
-
-MESH1 = _abstract_mesh((16, 16), ("data", "model"))
-MESH2 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs(cfg, mesh):

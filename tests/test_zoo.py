@@ -65,6 +65,24 @@ def test_zoo_registry_unknown_name_raises():
         get_zoo_model("resnet50")
 
 
+def test_build_zoo_refuses_full_width_vgg16(monkeypatch):
+    """VGG-16's 224x224 stem needs spatial tiling the conv kernel lacks:
+    building the zoo raises PlanError naming conv1, before any request is
+    admitted — never a wave quarantined at serve time.  Parameter shapes
+    stand in for the 0.5 GB of seeded weights; only shapes are planned."""
+    import jax
+
+    from repro.core.dataflow import PlanError
+    from repro.models import cnn
+
+    init = cnn.init_cnn
+    monkeypatch.setattr(cnn, "init_cnn", lambda *a, **kw: jax.eval_shape(
+        lambda: init(*a, **kw)))
+    with pytest.raises(PlanError) as ei:
+        build_zoo(("vgg16",), width_mult=1.0)
+    assert ei.value.op == "conv1"
+
+
 # -- compiled-schedule registry ----------------------------------------------
 
 def test_schedule_registry_keys_and_lookup():
