@@ -33,7 +33,14 @@ per request.  This server is the CNN analogue of
 Every wave's :class:`~repro.core.engine.DispatchTrace` is kept on the
 :class:`WaveReport`, with each record tagged by the pipeline stage and
 wave that dispatched it (``stage='conv'|'fc'``, ``wave=i``) — the
-serving-side twin of the stage-split schedule tables.
+serving-side twin of the stage-split schedule tables.  The server keeps
+the reports of its most recent :data:`RECENT_WAVES` waves.
+
+Each wave's host work is marked with :mod:`repro.serve.telemetry` spans
+(ident = the wave index): ``cnn.wave`` (one :meth:`CNNServer.step_wave`,
+counting its rows as ``cnn.rows``), ``cnn.upload`` (the image stack),
+``cnn.conv_dispatch`` / ``cnn.fc_dispatch`` (the host enqueueing each
+stage's kernels) and ``cnn.logits_wait`` (the wave's one barrier).
 """
 from __future__ import annotations
 
@@ -44,6 +51,10 @@ import numpy as np
 
 from repro.core.engine import DispatchTrace, Engine
 from repro.core.schedule import LayerSchedule
+from repro.serve import telemetry
+
+#: the wave reports a server keeps (``CNNServer.waves``): the most recent
+RECENT_WAVES = 256
 
 
 @dataclasses.dataclass
@@ -59,16 +70,22 @@ class CNNRequest:
 class WaveReport:
     """What one coalesced dispatch did: who rode it, how it resolved.
 
-    ``trace`` is the wave's full dispatch picture (conv stage then FC
-    stage, every record stage/wave-tagged); ``conv_trace``/``fc_trace``
-    are the per-stage views the pipeline hands between arrays."""
+    ``conv_trace``/``fc_trace`` are the per-stage views the pipeline
+    hands between arrays; ``trace`` joins them into the wave's full
+    dispatch picture (conv stage then FC stage, every record
+    stage/wave-tagged) when it is read."""
     uids: tuple[int, ...]
     batch: int
     schedule_hits: int
-    trace: DispatchTrace
+    conv_trace: DispatchTrace
+    fc_trace: DispatchTrace
     wave: int = 0
-    conv_trace: DispatchTrace | None = None
-    fc_trace: DispatchTrace | None = None
+
+    @property
+    def trace(self) -> DispatchTrace:
+        joined = DispatchTrace()
+        joined.records = [*self.conv_trace, *self.fc_trace]
+        return joined
 
     @property
     def fc_records(self):
@@ -129,7 +146,7 @@ class CNNServer:
             width_mult=width_mult, dtype=self.dtype,
             policy=self.engine.policy, params=params)
         self.queue: list[CNNRequest] = []
-        self.waves: list[WaveReport] = []
+        self.waves: list[WaveReport] = []        # the RECENT_WAVES latest
         self._wave_counter = 0
         self._uids: set = set()
         self._inflight: _StageBuffer | None = None
@@ -206,10 +223,12 @@ class CNNServer:
         features to the stage buffer — no blocking here, so the next
         stage can be issued while this one runs."""
         from repro.models import cnn
-        x = jnp.stack([jnp.asarray(r.image, self.dtype) for r in wave])
+        with telemetry.span("cnn.upload", wave_idx):
+            x = jnp.stack([jnp.asarray(r.image, self.dtype) for r in wave])
         conv_sched, _ = self._stage_schedules(len(wave))
         eng = self.engine.with_schedule(conv_sched)
-        with eng.tracing() as tr, eng.tagging(stage="conv", wave=wave_idx):
+        with eng.tracing() as tr, eng.tagging(stage="conv", wave=wave_idx), \
+                telemetry.span("cnn.conv_dispatch", wave_idx):
             feats = cnn.cnn_conv_stage(self.net, self.params, x, eng=eng)
         return _StageBuffer(wave_idx, list(wave), feats, tr)
 
@@ -219,22 +238,23 @@ class CNNServer:
         from repro.models import cnn
         _, fc_sched = self._stage_schedules(len(buf.requests))
         eng = self.engine.with_schedule(fc_sched)
-        with eng.tracing() as tr, eng.tagging(stage="fc", wave=buf.wave):
+        with eng.tracing() as tr, eng.tagging(stage="fc", wave=buf.wave), \
+                telemetry.span("cnn.fc_dispatch", buf.wave):
             logits = cnn.cnn_fc_stage(self.net, self.params, buf.feats,
                                       eng=eng)
-        logits = np.asarray(logits)                   # the pipeline barrier
+        with telemetry.span("cnn.logits_wait", buf.wave):
+            logits = np.asarray(logits)               # the pipeline barrier
         for i, r in enumerate(buf.requests):
             r.logits = logits[i]
             r.done = True
-        combined = DispatchTrace()
-        for rec in list(buf.conv_trace) + list(tr):
-            combined.append(rec)
+        if len(self.waves) >= RECENT_WAVES:
+            del self.waves[0]
         self.waves.append(WaveReport(
             uids=tuple(r.uid for r in buf.requests),
             batch=len(buf.requests),
-            schedule_hits=sum(r.schedule == "hit" for r in combined),
-            trace=combined, wave=buf.wave,
-            conv_trace=buf.conv_trace, fc_trace=tr))
+            schedule_hits=sum(r.schedule == "hit"
+                              for t in (buf.conv_trace, tr) for r in t),
+            conv_trace=buf.conv_trace, fc_trace=tr, wave=buf.wave))
         return buf.requests
 
     def step_wave(self) -> list[CNNRequest]:
@@ -249,6 +269,12 @@ class CNNServer:
         requests are pushed back to the head of the queue before the
         exception propagates, so the caller can retry, cancel, or
         quarantine them — the queue never silently wedges."""
+        if self._inflight is None and not self.queue:
+            return []
+        with telemetry.span("cnn.wave", self._wave_counter):
+            return self._step_wave()
+
+    def _step_wave(self) -> list[CNNRequest]:
         finished: list[CNNRequest] = []
         if self._inflight is not None:
             buf, self._inflight = self._inflight, None
@@ -261,6 +287,7 @@ class CNNServer:
             return finished
         wave = self.queue[:self.microbatch]
         self.queue = self.queue[len(wave):]
+        telemetry.count("cnn.rows", len(wave))
         try:
             buf = self._conv_stage_dispatch(self._wave_counter, wave)
             self._wave_counter += 1

@@ -83,6 +83,7 @@ import numpy as np
 from repro.core.perf_model import WaveCost
 from repro.distributed.elastic import replan, reshard_wave
 from repro.distributed.fault_tolerance import HeartbeatTracker, StepMonitor
+from repro.serve import telemetry
 from repro.serve.cnn_server import CNNRequest, CNNServer
 from repro.serve.errors import (CorruptOutputError, InsufficientReplicasError,
                                 ReplicaLostError, RequestShedError,
@@ -421,6 +422,7 @@ class FleetServer:
         self._uids: set = set()
         self._exec_uid = 0
         self._attempt_idx = 0
+        self._serve_calls = 0      # the telemetry ident of serve() calls
 
     # -- devices / execution lanes (never consulted by the scheduler) -------
     def devices(self) -> tuple:
@@ -1184,7 +1186,13 @@ class FleetServer:
         forward (a whole-forward ``jax.jit`` would re-fuse the graph and
         break that bit-exactness on the interpreted kernels).  Same
         ``isfinite`` guard and never-wedge discipline as the per-replica
-        executor."""
+        executor.  Its host work is the span ``fleet.sharded_wave``."""
+        # ident: the serve() call this wave belongs to
+        with telemetry.span("fleet.sharded_wave", self._serve_calls - 1):
+            self._run_sharded(a, events)
+
+    def _run_sharded(self, a: FleetWaveAttempt,
+                     events: list[FleetEvent]) -> None:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec
@@ -1241,7 +1249,14 @@ class FleetServer:
         """Drain every queue: schedule (modeled time, device-count
         independent), execute on replica lanes (``execute=False`` for
         modeled-only analysis), account.  Every admitted request ends in
-        exactly one terminal status."""
+        exactly one terminal status.  The call is the span
+        ``fleet.serve``, its index the span's ident."""
+        call = self._serve_calls
+        self._serve_calls += 1
+        with telemetry.span("fleet.serve", call):
+            return self._serve(execute)
+
+    def _serve(self, execute: bool) -> FleetReport:
         queued = [r for q in self.tenants.values() for r in q]
         for q in self.tenants.values():
             q.clear()

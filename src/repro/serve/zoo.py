@@ -72,6 +72,14 @@ default admission the schedule is bit-identical to the healthy path.
 Fault *injection* is seeded and wave-granular
 (:mod:`repro.serve.faults`), so chaos runs are pure functions of their
 seed and gated like everything else (``BENCH_chaos.json``).
+
+``serve()`` marks its host work with :mod:`repro.serve.telemetry` spans
+(ident = the call's index): ``zoo.serve`` (the whole call) holding
+``zoo.schedule`` (the modeled-time plan), ``zoo.execute`` (the executor
+loop, with one ``zoo.guard`` per attempt around its rows' download and
+``isfinite`` guard, counting refused rows as ``zoo.guard_rejects``) and
+``zoo.account``.  The spans observe the schedule; nothing reads them
+back into it.
 """
 from __future__ import annotations
 
@@ -87,6 +95,7 @@ from repro.core.perf_model import (ShardedWaveCost, WaveCost,
                                    sharded_wave_cost, zoo_wave_cost)
 from repro.core.schedule import ScheduleRegistry
 from repro.distributed.fault_tolerance import HeartbeatTracker, StepMonitor
+from repro.serve import telemetry
 from repro.serve.cnn_server import CNNRequest, CNNServer
 from repro.serve.errors import (CorruptOutputError, PlanError,
                                 RequestShedError, ServeError,
@@ -624,6 +633,7 @@ class ModelZooServer:
         self._uids: set = set()
         self._exec_uid = 0
         self._attempt_idx = 0
+        self._serve_calls = 0      # the telemetry ident of serve() calls
 
     def add_model(self, m: ZooModel) -> None:
         """Register one more compiled variant (elastic scale-up — valid
@@ -987,7 +997,7 @@ class ModelZooServer:
 
     # -- execution (real kernels, bitwise per-request logits) ---------------
     def _execute(self, attempts: list[WaveAttempt],
-                 events: list[FaultEvent]) -> None:
+                 events: list[FaultEvent], call: int) -> None:
         """Run every scheduled attempt through its model's ``CNNServer``.
         Corrupt attempts execute for real, then the chaos layer
         overwrites the faulted rows at the flush boundary; the per-wave
@@ -995,9 +1005,8 @@ class ModelZooServer:
         must agree with the modeled schedule (and also catches *genuine*
         non-finite outputs, quarantining instead of serving garbage).
         Unexpected executor exceptions quarantine the attempt's
-        undelivered requests instead of wedging the drain."""
-        import jax.numpy as jnp
-
+        undelivered requests instead of wedging the drain.  ``call`` is
+        the ``serve()`` call's index, the ident of its spans."""
         for a in attempts:
             if a.faults is not None and a.faults.kind == "dispatch":
                 try:
@@ -1031,37 +1040,48 @@ class ModelZooServer:
             corrupt_rows = frozenset(a.faults.corrupt_rows) \
                 if a.faults is not None and a.faults.kind == "corrupt" \
                 else frozenset()
-            deliver = set(a.deliver)
-            for row, (r, eu) in enumerate(zip(a.requests, exec_uids)):
-                done = completed.get(eu)
-                if done is None:        # executor lost a row: typed, loud
-                    if r.uid in deliver:
-                        self._quarantine(r, ServeError(
-                            "executor returned no completion for the "
-                            "request's wave row", uid=r.uid,
-                            model=a.model))
-                        events.append(FaultEvent(
-                            -1.0, a.index, a.model, "quarantine",
-                            "executor lost a wave row", uids=(r.uid,)))
-                    continue
-                logits = np.asarray(done.logits)
-                if row in corrupt_rows:
-                    logits = FaultInjector.corrupt_array(logits)
-                if not bool(jnp.isfinite(jnp.asarray(logits)).all()):
-                    if r.uid in deliver:
-                        # genuine (un-injected) corruption: the guard
-                        # refuses to serve garbage even when the modeled
-                        # schedule expected a clean row
-                        self._quarantine(r, CorruptOutputError(
-                            "non-finite logits at the integrity guard",
-                            uid=r.uid, model=a.model))
-                        events.append(FaultEvent(
-                            -1.0, a.index, a.model, "quarantine",
-                            "integrity guard: genuine non-finite logits",
-                            uids=(r.uid,)))
-                    continue
+            with telemetry.span("zoo.guard", call):
+                self._guard(a, exec_uids, completed, corrupt_rows, events)
+
+    def _guard(self, a: WaveAttempt, exec_uids: list[int],
+               completed: dict[int, CNNRequest],
+               corrupt_rows: frozenset, events: list[FaultEvent]) -> None:
+        """Deliver one executed attempt's rows through the ``isfinite``
+        integrity guard."""
+        import jax.numpy as jnp
+
+        deliver = set(a.deliver)
+        for row, (r, eu) in enumerate(zip(a.requests, exec_uids)):
+            done = completed.get(eu)
+            if done is None:        # executor lost a row: typed, loud
                 if r.uid in deliver:
-                    r.logits, r.done = logits, True
+                    self._quarantine(r, ServeError(
+                        "executor returned no completion for the "
+                        "request's wave row", uid=r.uid,
+                        model=a.model))
+                    events.append(FaultEvent(
+                        -1.0, a.index, a.model, "quarantine",
+                        "executor lost a wave row", uids=(r.uid,)))
+                continue
+            logits = np.asarray(done.logits)
+            if row in corrupt_rows:
+                logits = FaultInjector.corrupt_array(logits)
+            if not bool(jnp.isfinite(jnp.asarray(logits)).all()):
+                telemetry.count("zoo.guard_rejects")
+                if r.uid in deliver:
+                    # genuine (un-injected) corruption: the guard
+                    # refuses to serve garbage even when the modeled
+                    # schedule expected a clean row
+                    self._quarantine(r, CorruptOutputError(
+                        "non-finite logits at the integrity guard",
+                        uid=r.uid, model=a.model))
+                    events.append(FaultEvent(
+                        -1.0, a.index, a.model, "quarantine",
+                        "integrity guard: genuine non-finite logits",
+                        uids=(r.uid,)))
+                continue
+            if r.uid in deliver:
+                r.logits, r.done = logits, True
 
     # -- accounting ---------------------------------------------------------
     @staticmethod
@@ -1090,6 +1110,12 @@ class ModelZooServer:
         execution-independent by construction), account.  Returns the
         :class:`ZooReport`; the admitted requests are completed in
         place, each in exactly one terminal status."""
+        call = self._serve_calls
+        self._serve_calls += 1
+        with telemetry.span("zoo.serve", call):
+            return self._serve(execute, call)
+
+    def _serve(self, execute: bool, call: int) -> ZooReport:
         queued = [r for q in self.tenants.values() for r in q]
         for q in self.tenants.values():
             q.clear()
@@ -1106,11 +1132,19 @@ class ModelZooServer:
                                      "stale deadline at submit",
                                      uids=(r.uid,)))
         if queued:
-            decisions, attempts, sched_events, health = \
-                self._schedule(queued)
+            with telemetry.span("zoo.schedule", call):
+                decisions, attempts, sched_events, health = \
+                    self._schedule(queued)
             events.extend(sched_events)
         if execute:
-            self._execute(attempts, events)
+            with telemetry.span("zoo.execute", call):
+                self._execute(attempts, events, call)
+        with telemetry.span("zoo.account", call):
+            return self._account(requests, decisions, events, health)
+
+    def _account(self, requests: list[ZooRequest],
+                 decisions: list[WaveDecision], events: list[FaultEvent],
+                 health: dict[str, ModelHealth]) -> ZooReport:
         # the zero-unaccounted guarantee, enforced defensively: anything
         # the scheduler somehow left non-terminal becomes a typed error
         # result rather than a silent drop
