@@ -18,9 +18,9 @@ instead of a silent drop or a wedged queue:
   cannot be met);
 * :class:`StaleDeadlineError` — a :class:`RequestShedError` for the
   degenerate case: the deadline was already in the past at arrival;
-* :class:`CorruptOutputError` — the per-wave ``jnp.isfinite`` integrity
-  guard rejected the request's logits (NaN/Inf) and the retry budget is
-  spent;
+* :class:`CorruptOutputError` — the integrity guard
+  (:func:`all_finite`, on the host copy of the wave's logits) rejected
+  the request's logits (NaN/Inf) and the retry budget is spent;
 * :class:`ReplicaLostError` — the replica holding the request died (or
   every replica did) and the fleet could not re-place it within the
   retry budget: the replica-level analogue of a wave failure;
@@ -35,11 +35,14 @@ covers every failure cause one ``except`` ladder needs.
 """
 from __future__ import annotations
 
+import numpy as np
+
 from repro.core.dataflow import PlanError
 
 __all__ = ["ServeError", "WaveTimeoutError", "RequestShedError",
            "StaleDeadlineError", "CorruptOutputError",
-           "ReplicaLostError", "InsufficientReplicasError", "PlanError"]
+           "ReplicaLostError", "InsufficientReplicasError", "PlanError",
+           "all_finite"]
 
 
 class ServeError(RuntimeError):
@@ -81,8 +84,20 @@ class StaleDeadlineError(RequestShedError):
 
 
 class CorruptOutputError(ServeError):
-    """The wave-level ``isfinite`` integrity guard found NaN/Inf in this
+    """The integrity guard (:func:`all_finite`) found NaN/Inf in this
     request's logits; serving them would return garbage with a 200."""
+
+
+def all_finite(logits: np.ndarray) -> np.bool_ | np.ndarray:
+    """The integrity guard's decision: whether a logits row holds no NaN
+    or Inf, or, for a wave ``(rows, classes)``, whether each row does.
+
+    The check runs on the host, on the NumPy logits the wave executor
+    already downloaded (``float32``, or ``ml_dtypes.bfloat16``, for
+    which ``np.isfinite`` is registered).  Finiteness is a property of
+    the bytes, so the decision is the one ``jnp.isfinite`` makes on the
+    device; making it here costs no upload, dispatch or sync."""
+    return np.isfinite(logits).all(axis=-1)
 
 
 class ReplicaLostError(ServeError):

@@ -17,8 +17,9 @@ Fault kinds (wave-granular, matching the serving plane's failure modes):
   :class:`~repro.distributed.fault_tolerance.StepMonitor` straggler
   verdict; hard ``k`` is aborted at the timeout and retried;
 * ``corrupt`` — NaN/Inf overwrite a deterministic subset of the wave's
-  logit rows at the flush boundary, exercising the per-wave
-  ``jnp.isfinite`` integrity guard;
+  logit rows at the flush boundary, exercising the per-wave integrity
+  guard (:func:`~repro.serve.errors.all_finite` on the host copy of the
+  logits);
 * ``dispatch`` — the wave raises a transient
   :class:`~repro.core.dataflow.PlanError` at dispatch before occupying
   either array.

@@ -88,7 +88,7 @@ from repro.serve.cnn_server import CNNRequest, CNNServer
 from repro.serve.errors import (CorruptOutputError, InsufficientReplicasError,
                                 ReplicaLostError, RequestShedError,
                                 ServeError, StaleDeadlineError,
-                                WaveTimeoutError)
+                                WaveTimeoutError, all_finite)
 from repro.serve.faults import ReplicaFaultInjector, ReplicaFaults
 from repro.serve.zoo import (AdmissionConfig, FIFOPolicy, ModelZooServer,
                              RecoveryConfig, SchedulingPolicy, TenantStats,
@@ -375,8 +375,9 @@ class FleetServer:
     a deterministic modeled-time schedule first (device-count
     independent), then real execution of every scheduled wave on its
     replica's lane (per-model ``CNNServer``s pinned round-robin over
-    ``jax.devices()``), with the same ``isfinite`` integrity guard and
-    bitwise-parity contract."""
+    ``jax.devices()``), with the same host-side integrity guard
+    (:func:`~repro.serve.errors.all_finite`) and bitwise-parity
+    contract."""
 
     def __init__(self, models: Sequence[ZooModel], *,
                  n_replicas: int = 2,
@@ -1097,12 +1098,11 @@ class FleetServer:
     def _execute(self, attempts: list[FleetWaveAttempt],
                  events: list[FleetEvent]) -> None:
         """Run every completed attempt through its replica's lane — the
-        zoo executor lifted per replica, with the same ``isfinite``
+        zoo executor lifted per replica, with the same host-side
         integrity guard and never-wedge discipline.  Images are placed
         on the replica's device; on CPU host devices the kernels are
         bit-identical across devices, preserving the parity contract."""
         import jax
-        import jax.numpy as jnp
 
         for a in attempts:
             if not a.execute:
@@ -1154,7 +1154,7 @@ class FleetServer:
                             attempt=a.index, model=a.model))
                     continue
                 logits = np.asarray(done.logits)
-                if not bool(jnp.isfinite(jnp.asarray(logits)).all()):
+                if not all_finite(logits):
                     if r.uid in deliver:
                         r.status = "quarantined"
                         r.error = CorruptOutputError(
@@ -1185,8 +1185,9 @@ class FleetServer:
         row stays **bitwise equal** to the single-device unbatched
         forward (a whole-forward ``jax.jit`` would re-fuse the graph and
         break that bit-exactness on the interpreted kernels).  Same
-        ``isfinite`` guard and never-wedge discipline as the per-replica
-        executor.  Its host work is the span ``fleet.sharded_wave``."""
+        host-side integrity guard and never-wedge discipline as the
+        per-replica executor.  Its host work is the span
+        ``fleet.sharded_wave``."""
         # ident: the serve() call this wave belongs to
         with telemetry.span("fleet.sharded_wave", self._serve_calls - 1):
             self._run_sharded(a, events)
@@ -1230,7 +1231,7 @@ class FleetServer:
             return
         for i, r in enumerate(a.requests):
             row = logits[i]
-            if not bool(np.isfinite(row).all()):
+            if not all_finite(row):
                 if r.uid in deliver:
                     r.status = "quarantined"
                     r.error = CorruptOutputError(

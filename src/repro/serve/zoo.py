@@ -55,7 +55,8 @@ plus:
   after ``max_retries`` they are **quarantined** as typed error results
   (:mod:`repro.serve.errors`) — never silently dropped, never wedging
   the queue;
-* a per-wave ``isfinite`` **integrity guard**: non-finite logits become
+* a per-wave **integrity guard** on the host copy of the logits
+  (:func:`~repro.serve.errors.all_finite`): non-finite logits become
   per-request :class:`~repro.serve.errors.CorruptOutputError` results
   instead of served garbage;
 * **admission control** (:class:`AdmissionConfig`): bounded per-tenant
@@ -76,9 +77,9 @@ seed and gated like everything else (``BENCH_chaos.json``).
 ``serve()`` marks its host work with :mod:`repro.serve.telemetry` spans
 (ident = the call's index): ``zoo.serve`` (the whole call) holding
 ``zoo.schedule`` (the modeled-time plan), ``zoo.execute`` (the executor
-loop, with one ``zoo.guard`` per attempt around its rows' download and
-``isfinite`` guard, counting refused rows as ``zoo.guard_rejects``) and
-``zoo.account``.  The spans observe the schedule; nothing reads them
+loop, with one ``zoo.guard`` per attempt around its rows' host-side
+finiteness check, counting the rows it checked as ``zoo.guard_rows`` and
+those it refused as ``zoo.guard_rejects``) and ``zoo.account``.  The spans observe the schedule; nothing reads them
 back into it.
 """
 from __future__ import annotations
@@ -99,7 +100,8 @@ from repro.serve import telemetry
 from repro.serve.cnn_server import CNNRequest, CNNServer
 from repro.serve.errors import (CorruptOutputError, PlanError,
                                 RequestShedError, ServeError,
-                                StaleDeadlineError, WaveTimeoutError)
+                                StaleDeadlineError, WaveTimeoutError,
+                                all_finite)
 from repro.serve.faults import FaultInjector, WaveFaults
 
 
@@ -1001,7 +1003,7 @@ class ModelZooServer:
         """Run every scheduled attempt through its model's ``CNNServer``.
         Corrupt attempts execute for real, then the chaos layer
         overwrites the faulted rows at the flush boundary; the per-wave
-        ``isfinite`` integrity guard then decides what is servable — it
+        integrity guard then decides what is servable — it
         must agree with the modeled schedule (and also catches *genuine*
         non-finite outputs, quarantining instead of serving garbage).
         Unexpected executor exceptions quarantine the attempt's
@@ -1046,11 +1048,12 @@ class ModelZooServer:
     def _guard(self, a: WaveAttempt, exec_uids: list[int],
                completed: dict[int, CNNRequest],
                corrupt_rows: frozenset, events: list[FaultEvent]) -> None:
-        """Deliver one executed attempt's rows through the ``isfinite``
-        integrity guard."""
-        import jax.numpy as jnp
-
+        """Deliver one executed attempt's rows through the integrity
+        guard: :func:`~repro.serve.errors.all_finite` on each row's host
+        copy of its logits, with no device call."""
         deliver = set(a.deliver)
+        telemetry.count("zoo.guard_rows",
+                        sum(eu in completed for eu in exec_uids))
         for row, (r, eu) in enumerate(zip(a.requests, exec_uids)):
             done = completed.get(eu)
             if done is None:        # executor lost a row: typed, loud
@@ -1066,7 +1069,7 @@ class ModelZooServer:
             logits = np.asarray(done.logits)
             if row in corrupt_rows:
                 logits = FaultInjector.corrupt_array(logits)
-            if not bool(jnp.isfinite(jnp.asarray(logits)).all()):
+            if not all_finite(logits):
                 telemetry.count("zoo.guard_rejects")
                 if r.uid in deliver:
                     # genuine (un-injected) corruption: the guard
