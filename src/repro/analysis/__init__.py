@@ -23,7 +23,13 @@ Invariants checked, by pass:
     * symbolically evaluating every operand's index map over the whole
       grid visits a contiguous, Cartesian-complete set of block indices
       whose blocks cover the operand's full logical extent (no coverage
-      gap, no silently clamped tail).
+      gap, no silently clamped tail); an element-indexed operand (a conv
+      band's overlapping input window) instead starts at 0, leaves no
+      gap between windows and reaches the extent;
+    * a conv planned in row bands: the bands tile the output rows, each
+      input window holds its band plus the ``p' - 1`` halo rows in
+      phase space and starts one band below the last, and a fused pool
+      stays inside one band (window == stride, band a multiple of it).
 
 ``residency`` — VMEM residency sanitizer
     * the resident working set re-derived from the block specs alone

@@ -37,8 +37,8 @@ def _verify_zoo_variants(max_batch: int) -> list[AnalysisReport]:
     int8 variant quantizes its abstract tree so the schedule keys carry
     the real 1-byte weight stream.
 
-    A variant the planner refuses (a layer no tiling fits on the chip,
-    e.g. VGG-16's 224x224 stem) has no schedule to verify: it is reported
+    A variant the planner refuses (a layer not even a one-row band fits
+    on the chip) has no schedule to verify: it is reported
     as a warning naming the layer, and the variants that plan are still
     verified."""
     import jax

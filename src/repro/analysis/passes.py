@@ -12,7 +12,9 @@ Sec. V traffic model) independently of the planner's own arithmetic.
 * :func:`check_coverage` — grid x block coverage of every operand (no
   gap, no silent clamp), SUBLANE/LANE alignment, the ``MAX_TILE`` cap,
   and plan-vs-kernel tile agreement (the planner's tiles must be exactly
-  what the kernel's normalization executes).
+  what the kernel's normalization executes); for a conv planned in row
+  bands, that the bands tile the map, each input window carries the
+  ``p' - 1`` halo rows, and a fused pool stays inside one band.
 * :func:`check_residency` — VMEM working set re-derived from the block
   specs alone (double-buffered inputs, fp32 accumulator scratch, the
   planner's output-tile conventions) must equal the plan's
@@ -160,6 +162,9 @@ def check_coverage(ctx: OpContext) -> list[Finding]:
         visited = {spec.index_map(*pt) for pt in _grid_points(ctx.geom)}
         per_dim = [sorted({v[d] for v in visited})
                    for d in range(len(spec.block))]
+        if spec.element:
+            out.extend(_element_coverage(ctx.op, spec, per_dim, extent))
+            continue
         for d, vals in enumerate(per_dim):
             if vals != list(range(len(vals))):
                 err(f"operand {spec.name!r} dim {d}: visited block "
@@ -175,6 +180,60 @@ def check_coverage(ctx: OpContext) -> list[Finding]:
             if covered < ext:
                 err(f"operand {spec.name!r} dim {d}: grid covers "
                     f"{covered} elements < extent {ext} — silent clamp")
+    if ctx.kind == "conv" and p.bands > 1:
+        out.extend(_band_findings(ctx))
+    return out
+
+
+def _element_coverage(op: str, spec, per_dim: list[list[int]],
+                      extent: tuple[int, ...]) -> list[Finding]:
+    """Coverage of an element-indexed spec (overlapping windows): along
+    each dim the windows start at 0, leave no gap between them, and
+    reach the extent."""
+    out = []
+    for d, (starts, blk, ext) in enumerate(zip(per_dim, spec.block,
+                                               extent)):
+        gaps = [b for a, b in zip(starts, starts[1:]) if b - a > blk]
+        if starts[0] != 0 or gaps:
+            out.append(Finding(
+                "coverage", op, f"operand {spec.name!r} dim {d}: windows "
+                f"of {blk} starting at {starts} leave a coverage gap"))
+        if starts[-1] + blk < ext:
+            out.append(Finding(
+                "coverage", op, f"operand {spec.name!r} dim {d}: windows "
+                f"end at {starts[-1] + blk} < extent {ext} — silent clamp"))
+    return out
+
+
+def _band_findings(ctx: OpContext) -> list[Finding]:
+    """A banded conv: the band count covers the map, each band's input
+    window is its rows plus the ``p' - 1`` halo rows in phase space and
+    starts one band below the last, and a fused pool's windows lie
+    inside one band (window == stride, band a multiple of it)."""
+    out: list[Finding] = []
+
+    def err(msg: str) -> None:
+        out.append(Finding("coverage", ctx.op, msg))
+
+    p, k, g = ctx.plan, ctx.key, ctx.geom
+    oh = (k.h - k.p) // k.stride + 1
+    pp = g.input("w").block[0]
+    x = g.input("x")
+    if p.bands != math.ceil(oh / p.band) or p.band < 1:
+        err(f"{p.bands} bands of {p.band} rows do not tile the {oh} "
+            "output rows")
+    if x.block[1] != p.band + pp - 1:
+        err(f"band input window holds {x.block[1]} rows; a band of "
+            f"{p.band} output rows reads {p.band + pp - 1} (halo p'-1 = "
+            f"{pp - 1} rows in phase space)")
+    step = x.index_map(0, 1, 0, 0)[1] - x.index_map(0, 0, 0, 0)[1]
+    if step != p.band:
+        err(f"band input windows start {step} rows apart, not one band "
+            f"({p.band} rows)")
+    if p.fuse_pool and (p.pool_window != p.pool_stride
+                        or p.band % p.pool_stride):
+        err(f"fused pool {p.pool_window}/{p.pool_stride} straddles the "
+            f"edges of {p.band}-row bands")
     return out
 
 
@@ -331,10 +390,21 @@ def _conv_traffic(ctx: OpContext, *, pooled: bool) -> int:
     gi, gj = math.ceil(k.ci / p.bi), math.ceil(k.co / p.bj)
     cip, cop = gi * p.bi, gj * p.bj
     x_passes = gj if gi > 1 else 1
-    w_passes = k.batch if gi * gj > 1 else 1
+    w_passes = k.batch * p.bands if gi * gj > 1 else 1
     total = (k.batch * k.h * k.w * cip * bi_b * x_passes
              + k.p * k.q * cip * cop * bw * w_passes
              + k.batch * poh * pow_ * cop * 4)
+    if p.bands > 1:
+        # the input rows every band window reads beyond the map's own
+        # (halos, and the zero rows under a ragged last band), and the
+        # output rows written past the map
+        pp = -(-k.p // k.stride)
+        ww = (k.w - k.q) // k.stride + -(-k.q // k.stride)
+        window_rows = p.bands * (p.band + pp - 1)
+        total += ((window_rows - (oh + pp - 1)) * k.batch * ww
+                  * k.stride * k.stride * cip * bi_b * x_passes)
+        band_out = p.band // p.pool_stride if pooled else p.band
+        total += k.batch * (p.bands * band_out - poh) * pow_ * cop * 4
     if cip != k.ci:
         total += k.batch * k.h * k.w * (k.ci + cip) * bi_b
     if cip != k.ci or cop != k.co:

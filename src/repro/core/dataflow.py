@@ -554,6 +554,16 @@ class ConvPlan:
     (``fuse_pool=False``, engine falls back to conv -> standalone pool)
     for non-monotone activations, pools whose windows don't tile the OFM,
     and budgets that can't hold even the minimum fused working set.
+
+    ``band``/``bands`` tile the map spatially when no whole-map slab fits
+    the budget: the grid gains a band axis, ``(batch, bands, co/bj,
+    ci/bi)``, and each step holds the input rows of one band's ``band``
+    output rows: its own and the ``p' - 1`` halo rows it shares with the
+    next band, in phase space (:func:`conv_phase_dims`).  ``band == 0`` (one band) is the whole map.
+    The last band may run past the map (a ragged band over zero rows; its
+    rows are dropped).  A banded plan fuses a pool only when its window
+    equals its stride and ``band`` is a multiple of it, so every pool
+    window lies inside one band.
     """
     case: int                       # 1..4 (buffer-fit scenario analog)
     regime: str                     # 'sa_conv' | 'sa_fc' (policy-forced)
@@ -569,13 +579,18 @@ class ConvPlan:
     fuse_pool: bool = False         # pooled flush epilogue committed?
     pool_window: int = 0            # maxpool window (0 when not fused)
     pool_stride: int = 0
+    band: int = 0                   # output rows per band (0: whole map)
+    bands: int = 1
 
     @property
     def arithmetic_intensity(self) -> float:
         return self.flops / max(1, self.hbm_bytes)
 
-    def grid(self, batch: int, ci: int, co: int) -> tuple[int, int, int]:
-        return (batch, math.ceil(co / self.bj), math.ceil(ci / self.bi))
+    def grid(self, batch: int, ci: int, co: int) -> tuple[int, ...]:
+        tiles = (math.ceil(co / self.bj), math.ceil(ci / self.bi))
+        if self.bands > 1:
+            return (batch, self.bands, *tiles)
+        return (batch, *tiles)
 
 
 def classify_conv_regime(batch: int, h: int, w: int, ci: int,
@@ -631,6 +646,20 @@ def conv_phase_dims(h: int, w: int, p: int, q: int,
     ow = (w - q) // stride + 1
     pp, qq = -(-p // stride), -(-q // stride)
     return oh + pp - 1, ow + qq - 1, pp, qq
+
+
+def band_input_bytes(batch: int, h: int, w: int, ci: int, p: int, q: int,
+                     *, stride: int, band: int, bytes_in: int
+                     ) -> tuple[int, int]:
+    """``(slab, halo)`` input bytes of a conv run in bands of ``band``
+    output rows (0: one band): ``slab`` is the phase-space input map once
+    (the compulsory fetch), ``halo`` the rows fetched a second time
+    because each band also reads the first ``p' - 1`` rows of the next."""
+    hh, ww, pp, _ = conv_phase_dims(h, w, p, q, stride)
+    oh = hh - pp + 1
+    row = batch * ww * stride * stride * ci * bytes_in
+    bands = -(-oh // band) if band else 1
+    return hh * row, (bands - 1) * (pp - 1) * row
 
 
 def conv_vmem_bytes(hh: int, ww: int, pp: int, qq: int, pbi: int, bj: int,
@@ -723,33 +752,44 @@ def plan_conv(batch: int, h: int, w: int, ci: int,
 
     hh, ww, pp, qq = conv_phase_dims(h, w, p, q, stride)
 
-    def vmem(bi: int, bj: int, fused: bool) -> int:
-        return conv_vmem_bytes(hh, ww, pp, qq, stride * stride * bi, bj,
-                               oh=oh, ow=ow, ooh=poh, oow=pow_,
+    def vmem(bi: int, bj: int, fused: bool, rows: int, out_rows: int) -> int:
+        # one band of ``rows`` output rows (the whole map: rows == oh)
+        return conv_vmem_bytes(rows + pp - 1, ww, pp, qq,
+                               stride * stride * bi, bj,
+                               oh=rows, ow=ow, ooh=out_rows, oow=pow_,
                                fuse_taps=fused, bytes_in=bytes_in,
                                bytes_w=bw, bytes_out=bytes_out)
 
-    def fuse(bi: int, bj: int) -> bool:
-        return (oh * ow * p * q * bi <= TAP_FUSE_ELEMS
-                and vmem(bi, bj, True) <= budget)
+    def fuse(bi: int, bj: int, rows: int, out_rows: int) -> bool:
+        return (rows * ow * p * q * bi <= TAP_FUSE_ELEMS
+                and vmem(bi, bj, True, rows, out_rows) <= budget)
 
     def grids(bi: int, bj: int) -> tuple[int, int]:
         return math.ceil(ci / bi), math.ceil(co / bj)
 
-    def traffic(bi: int, bj: int) -> int:
+    def traffic(bi: int, bj: int, bands: int, rows: int,
+                out_rows: int) -> int:
         gi, gj = grids(bi, bj)
         cip, cop = gi * bi, gj * bj
         # Pallas only re-DMAs a block when its index-map output changes:
         # with a single CI tile the slab index is constant across the CO
-        # loop (one fetch per sample); likewise the filter re-streams per
-        # sample only when the (j, k) sweep actually revisits tiles.
+        # loop (one fetch per sample and band); likewise the filter
+        # re-streams per sample and band only when the (j, k) sweep
+        # actually revisits tiles.
         # With fuse_pool the output term is the POOLED map (poh == oh and
         # pow_ == ow otherwise): the full OFM never crosses HBM.
         x_passes = gj if gi > 1 else 1
-        w_passes = batch if gi * gj > 1 else 1
+        w_passes = batch * bands if gi * gj > 1 else 1
         total = (batch * h * w * cip * bytes_in * x_passes
                  + p * q * cip * cop * bw * w_passes
                  + batch * poh * pow_ * cop * bytes_out)
+        if bands > 1:
+            # every band's halo rows, and the zero rows and dropped output
+            # rows of a ragged last band
+            xrow = batch * ww * stride * stride * cip * bytes_in
+            total += (bands * (rows + pp - 1) - hh) * xrow * x_passes
+            total += batch * (bands * out_rows - poh) * pow_ * cop \
+                * bytes_out
         # Tiles that don't divide the channel counts force materialized
         # zero-padded copies (and an output slice-back) around the kernel
         # — real HBM bytes, charged so plan == execution and the search
@@ -772,34 +812,57 @@ def plan_conv(batch: int, h: int, w: int, ci: int,
             return 3                 # CO resident, contraction partitioned
         return 4                     # fully tiled
 
-    best = None
-    for bi in _channel_tiles(ci):
-        for bj in _channel_tiles(co):
-            fused = fuse(bi, bj)
-            if vmem(bi, bj, fused) > budget:
-                continue
-            key = (traffic(bi, bj), case(bi, bj), not fused, -(bi * bj))
-            if best is None or key < best[0]:
-                best = (key, bi, bj, fused)
+    def tiling(bands: int, rows: int, out_rows: int):
+        best = None
+        for bi in _channel_tiles(ci):
+            for bj in _channel_tiles(co):
+                fused = fuse(bi, bj, rows, out_rows)
+                if vmem(bi, bj, fused, rows, out_rows) > budget:
+                    continue
+                key = (traffic(bi, bj, bands, rows, out_rows), case(bi, bj),
+                       not fused, -(bi * bj))
+                if best is None or key < best[0]:
+                    best = (key, bi, bj, fused)
+        return best
+
+    bands, rows = 1, oh
+    best = tiling(1, oh, poh)
     if best is None:
-        # Not even the minimum (h, w, bi) slab fits: there is no spatial
-        # tiling yet (a huge-resolution input), and declining a pool
-        # fusion would only grow the output block.  The op cannot run on
-        # the chip, so say so here, at schedule time, not in the compiler.
+        # No whole-map slab fits: band the output rows.  A fused pool
+        # stays only when its windows cannot straddle a band edge (window
+        # == stride, bands a multiple of it); the fewest bands that fit
+        # win, each as short as that count allows.
+        fuse_pool = fuse_pool and pool.window == pool.stride
+        poh, pow_ = pool.out(oh, ow) if fuse_pool else (oh, ow)
+        step = pool.stride if fuse_pool else 1
+        for count in range(2, oh + 1):
+            r = _round_up(-(-oh // count), step)
+            if r >= rows:
+                continue
+            bands, rows = -(-oh // r), r
+            best = tiling(bands, rows, rows // step)
+            if best is not None or rows == step:
+                break
+    if best is None:
+        # Not even a band of the fewest rows fits (a huge-resolution or
+        # huge-channel input).  The op cannot run on the chip, so say so
+        # here, at schedule time, not in the compiler.
         bi, bj = _channel_tiles(ci)[0], _channel_tiles(co)[0]
         raise PlanError(
-            "VMEM budget too small for the minimum SA-CONV input slab "
-            f"({vmem(bi, bj, False)} bytes; the conv needs spatial "
-            "tiling)", op="plan_conv",
+            "VMEM budget too small for the minimum SA-CONV input band "
+            f"({vmem(bi, bj, False, rows, rows // step)} bytes for "
+            f"{rows} output rows)", op="plan_conv",
             shape=(batch, h, w, ci, p, q, co, stride), vmem_budget=budget)
     _, bi, bj, fused = best
-    final_case = case(bi, bj)
-    return ConvPlan(final_case, regime, bi, bj, fuse_taps=fused,
-                    hbm_bytes=traffic(bi, bj), flops=flops,
-                    vmem_bytes=vmem(bi, bj, fused), m=m, n=n, k=k,
-                    fuse_pool=fuse_pool,
+    out_rows = rows // step if bands > 1 else poh
+    return ConvPlan(case(bi, bj), regime, bi, bj, fuse_taps=fused,
+                    hbm_bytes=traffic(bi, bj, bands, rows, out_rows),
+                    flops=flops,
+                    vmem_bytes=vmem(bi, bj, fused, rows, out_rows),
+                    m=m, n=n, k=k, fuse_pool=fuse_pool,
                     pool_window=pool.window if fuse_pool else 0,
-                    pool_stride=pool.stride if fuse_pool else 0)
+                    pool_stride=pool.stride if fuse_pool else 0,
+                    band=rows if bands > 1 else 0, bands=bands)
 
 
 def compulsory_conv_bytes(batch: int, h: int, w: int, ci: int,

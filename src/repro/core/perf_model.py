@@ -44,7 +44,7 @@ from collections.abc import Iterable
 
 from repro.core.accelerator import ENERGY_PJ, MPNA_PAPER, MPNAConfig, \
     SystolicArray, TPU_V5E, TPUChip
-from repro.core.dataflow import (ConvPlan, FCPlan, PlanError, PoolSpec,
+from repro.core.dataflow import (ConvPlan, FCPlan, PoolSpec,
                                  compulsory_bytes, compulsory_conv_bytes,
                                  im2col_bytes, plan_conv, plan_fc,
                                  pool_roundtrip_bytes)
@@ -885,18 +885,9 @@ def pallas_conv_traffic(net: str, *, batch: int = 1,
         pool = PoolSpec(ps.kernel, ps.stride) \
             if (ps is not None and fuse_pool) else None
         def priced(**extra):
-            try:
-                return plan_conv(batch, hp, hp, ch, s.kernel, s.kernel,
-                                 s.out_ch, vmem_budget=vmem_budget,
-                                 chip=chip, **extra, **kw)
-            except PlanError:
-                # no tiling fits the chip (the layer needs spatial tiling
-                # — VGG-16 at 224x224): price its traffic at the tiling an
-                # unbounded on-chip store would pick, so the variant's
-                # modeled cost stays defined.  Executing it still raises.
-                return plan_conv(batch, hp, hp, ch, s.kernel, s.kernel,
-                                 s.out_ch, vmem_budget=1 << 62, chip=chip,
-                                 **extra, **kw)
+            return plan_conv(batch, hp, hp, ch, s.kernel, s.kernel,
+                             s.out_ch, vmem_budget=vmem_budget, chip=chip,
+                             **extra, **kw)
 
         plan = priced(pool=pool, act=s.act)
         unfused = plan.hbm_bytes

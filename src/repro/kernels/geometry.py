@@ -22,6 +22,7 @@ flag it.
 """
 from __future__ import annotations
 
+import functools
 import math
 from collections.abc import Callable
 from dataclasses import dataclass
@@ -68,10 +69,14 @@ def _round_up(x: int, m: int) -> int:
 @dataclass(frozen=True)
 class BlockSpecInfo:
     """One operand's block spec: named, so verifier diagnostics can say
-    *which* operand's coverage or residency is wrong."""
+    *which* operand's coverage or residency is wrong.  ``element`` marks
+    a spec whose index map returns element offsets instead of block
+    indices (``pl.Element`` on every dim): overlapping windows, such as a
+    conv band and its halo rows."""
     name: str
     block: tuple[int, ...]
     index_map: IndexMap
+    element: bool = False
 
     @property
     def elems(self) -> int:
@@ -141,6 +146,24 @@ def _im_conv_row(n_: int, j: int, k_: int) -> tuple[int, int]:
 
 def _im_conv_out(n_: int, j: int, k_: int) -> tuple[int, int, int, int]:
     return (n_, 0, 0, j)
+
+
+def _im_band_x(rows: int, pbi: int, n_: int, b: int, j: int,
+               k_: int) -> tuple[int, int, int, int]:
+    """Element offsets of band ``b``'s input window: its first row."""
+    return (n_, b * rows, 0, k_ * pbi)
+
+
+def _im_band_f(n_: int, b: int, j: int, k_: int) -> tuple[int, int, int, int]:
+    return (0, 0, k_, j)
+
+
+def _im_band_row(n_: int, b: int, j: int, k_: int) -> tuple[int, int]:
+    return (0, j)
+
+
+def _im_band_out(n_: int, b: int, j: int, k_: int) -> tuple[int, int, int, int]:
+    return (n_, b, 0, j)
 
 
 # -- geometry builders ------------------------------------------------------
@@ -222,7 +245,15 @@ def conv_geometry(batch: int, h: int, w: int, ci: int,
     ``(p', q', stride**2 * bi, bj)``.  The fp32 accumulator is one
     ``(bj / lb, oh, ow, lb)`` scratch block, split into ``lb = min(bj,
     128)``-lane blocks: the pooled epilogue's strided row loads need a
-    base memref one lane tile wide."""
+    base memref one lane tile wide.
+
+    A banded plan (``plan.bands > 1``) adds a band axis after the batch,
+    grid ``(batch, bands, co-tiles, ci-tiles)``: the input block is the
+    band's ``plan.band`` rows plus ``p' - 1`` halo rows, an overlapping
+    window indexed by element offsets over the input zero-padded to
+    ``bands * band + p' - 1`` rows; the accumulator and the output block
+    hold one band's (pooled) rows, and ``out_shape`` the rows of every
+    band, the ragged last band's extra rows included."""
     oh = (h - p) // stride + 1
     ow = (w - q) // stride + 1
     ooh, oow = oh, ow
@@ -233,16 +264,35 @@ def conv_geometry(batch: int, h: int, w: int, ci: int,
     bi, bj = plan.bi, plan.bj
     gi, gj = _cdiv(ci, bi), _cdiv(co, bj)
     pbi = stride * stride * bi
-    inputs = [BlockSpecInfo("x", (1, hh, ww, pbi), _im_conv_x),
-              BlockSpecInfo("w", (pp, qq, pbi, bj), _im_conv_f)]
+    lb = min(bj, LANE)
+    if plan.bands > 1:
+        rows = plan.band
+        out_rows = rows // plan.pool_stride if plan.fuse_pool else rows
+        # the compiler takes an element window only over whole lane
+        # tiles: a narrow slab (the RGB stem, one channel tile) is padded
+        # to one, which its VMEM layout holds anyway
+        xl = _round_up(pbi, LANE)
+        x = BlockSpecInfo("x", (1, rows + pp - 1, ww, xl),
+                          functools.partial(_im_band_x, rows, xl),
+                          element=True)
+        im_f, im_row, im_out = _im_band_f, _im_band_row, _im_band_out
+        grid = (batch, plan.bands, gj, gi)
+        oh, ooh, out_shape_rows = rows, out_rows, plan.bands * out_rows
+    else:
+        x = BlockSpecInfo("x", (1, hh, ww, pbi), _im_conv_x)
+        im_f, im_row, im_out = _im_conv_f, _im_conv_row, _im_conv_out
+        grid = (batch, gj, gi)
+        out_shape_rows = ooh
+    inputs = [x, BlockSpecInfo("w", (pp, qq, pbi, bj), im_f)]
     if has_scale:
-        inputs.append(BlockSpecInfo("scale", (1, bj), _im_conv_row))
+        inputs.append(BlockSpecInfo("scale", (1, bj), im_row))
     if has_bias:
-        inputs.append(BlockSpecInfo("bias", (1, bj), _im_conv_row))
+        inputs.append(BlockSpecInfo("bias", (1, bj), im_row))
     return KernelGeometry(
-        kernel="sa_conv_implicit", grid=(batch, gj, gi),
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        kernel="sa_conv_implicit", grid=grid,
+        dimension_semantics=("parallel",) * (len(grid) - 1)
+        + ("arbitrary",),
         inputs=tuple(inputs),
-        out=BlockSpecInfo("out", (1, ooh, oow, bj), _im_conv_out),
-        out_shape=(batch, ooh, oow, gj * bj),
-        scratch=((bj // min(bj, LANE), oh, ow, min(bj, LANE)),))
+        out=BlockSpecInfo("out", (1, ooh, oow, bj), im_out),
+        out_shape=(batch, out_shape_rows, oow, gj * bj),
+        scratch=((bj // lb, oh, ow, lb),))

@@ -29,7 +29,11 @@ Paper mapping (Fig. 5 loop nest + Fig. 7B/C):
 
 Grid order is (batch, co-tiles, ci-tiles) with the contraction innermost
 ("arbitrary") so the accumulator never spills — the output-stationary
-schedule the paper uses for CONV.  Block shapes come from
+schedule the paper uses for CONV.  A map whose whole slab overflows VMEM
+runs in row bands, grid (batch, bands, co-tiles, ci-tiles): each step
+holds one band's input rows plus its halo and walks the band's output
+rows with the same row loop, so a band changes which rows are resident,
+never how a row is computed.  Block shapes come from
 :func:`repro.core.dataflow.plan_conv`; the executed tiles ARE the planned
 tiles (no clamping between plan and execution).
 """
@@ -50,13 +54,15 @@ from repro.kernels.geometry import (conv_geometry, interpret_mode,
 
 def _implicit_conv_kernel(x_ref, f_ref, *rest, oh: int, ow: int,
                           act: str, has_bias: bool, has_scale: bool,
-                          fuse_taps: bool, pool_window: int = 0,
+                          fuse_taps: bool, k_axis: int, pool_window: int = 0,
                           pool_stride: int = 0):
+    # oh: the output rows of the resident slab (one band's when banded);
+    # k_axis: the grid's innermost (input-channel) axis
     rest = list(rest)
     s_ref = rest.pop(0) if has_scale else None
     b_ref = rest.pop(0) if has_bias else None
     o_ref, acc_ref = rest
-    kk = pl.program_id(2)
+    kk = pl.program_id(k_axis)
     p, q, bi, bj = f_ref.shape
     nb, _, _, lb = acc_ref.shape          # bj split into lb-lane blocks
 
@@ -72,10 +78,13 @@ def _implicit_conv_kernel(x_ref, f_ref, *rest, oh: int, ow: int,
         # sublane tile need not divide bi)
         w_tile = f_ref[...].astype(cdt).reshape(p * q * bi, bj)
 
+    # a banded slab may be wider than the channel tile (lane padding)
+    chans = slice(None) if x_ref.shape[3] == bi else pl.ds(0, bi)
+
     def row(r, carry):
         # The address generator: the P*Q shifted views of the resident
         # slab that feed output row r — never a patch matrix in HBM.
-        views = [x_ref[0, r + dp, pl.ds(dq, ow), :]
+        views = [x_ref[0, r + dp, pl.ds(dq, ow), chans]
                  for dp in range(p) for dq in range(q)]
         if fuse_taps:
             # one (ow, p*q*bi) patch row, one MXU pass; the planner
@@ -105,7 +114,7 @@ def _implicit_conv_kernel(x_ref, f_ref, *rest, oh: int, ow: int,
             t = t + b_ref[:, lanes].astype(jnp.float32)
         return t
 
-    @pl.when(kk == pl.num_programs(2) - 1)
+    @pl.when(kk == pl.num_programs(k_axis) - 1)
     def _flush():
         if pool_window:
             # The pooling-&-activation unit sits right after accumulation
@@ -114,7 +123,8 @@ def _implicit_conv_kernel(x_ref, f_ref, *rest, oh: int, ow: int,
             # the POOLED block is emitted — the full OFM never leaves
             # VMEM, and the activation runs once per *pooled* element (the
             # paper's operator reordering, monotone acts only — the
-            # planner guarantees it).
+            # planner guarantees it).  A banded plan pools within its
+            # band: the planner fuses there only windows that tile it.
             poh, pow_ = o_ref.shape[1], o_ref.shape[2]
 
             def pooled_row(i, carry):
@@ -206,7 +216,7 @@ def sa_conv_implicit(x: jax.Array, f: jax.Array,
     # static verifier (repro.analysis) checks is what gets launched.
     geom = conv_geometry(batch, h, w, ci, p, q, co, stride=stride,
                          plan=plan, has_scale=has_scale, has_bias=has_bias)
-    _, gj, gi = geom.grid
+    *_, gj, gi = geom.grid
     bi, bj = plan.bi, plan.bj
     xp = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, gi * bi - ci))) \
         if gi * bi != ci else x
@@ -214,6 +224,14 @@ def sa_conv_implicit(x: jax.Array, f: jax.Array,
         if (gi * bi != ci or gj * bj != co) else f
     if stride > 1:
         xp, fp = _phase_split(xp, fp, stride, bi)
+    if plan.bands > 1:
+        # a ragged last band reads zero rows past the map (its outputs
+        # are dropped below), and a narrow slab is padded to its block's
+        # lane tile: every window must lie inside the operand
+        xb = geom.input("x").block
+        rows = (plan.bands - 1) * plan.band + xb[1]
+        xp = jnp.pad(xp, ((0, 0), (0, max(0, rows - xp.shape[1])), (0, 0),
+                          (0, gi * xb[3] - xp.shape[3])))
 
     args = [xp, fp]
     if has_scale:
@@ -223,14 +241,17 @@ def sa_conv_implicit(x: jax.Array, f: jax.Array,
         args.append(jnp.pad(bias, (0, gj * bj - co)).reshape(1, gj * bj))
 
     out = pl.pallas_call(
-        functools.partial(_implicit_conv_kernel, oh=oh, ow=ow,
-                          act=act, has_bias=has_bias, has_scale=has_scale,
-                          fuse_taps=plan.fuse_taps,
+        functools.partial(_implicit_conv_kernel, oh=geom.scratch[0][1],
+                          ow=ow, act=act, has_bias=has_bias,
+                          has_scale=has_scale, fuse_taps=plan.fuse_taps,
+                          k_axis=len(geom.grid) - 1,
                           pool_window=plan.pool_window if plan.fuse_pool
                           else 0,
                           pool_stride=plan.pool_stride),
         grid=geom.grid,
-        in_specs=[pl.BlockSpec(s.block, s.index_map) for s in geom.inputs],
+        in_specs=[pl.BlockSpec(tuple(map(pl.Element, s.block))
+                               if s.element else s.block, s.index_map)
+                  for s in geom.inputs],
         out_specs=pl.BlockSpec(geom.out.block, geom.out.index_map),
         out_shape=jax.ShapeDtypeStruct(geom.out_shape, out_dtype),
         scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in geom.scratch],
@@ -239,4 +260,6 @@ def sa_conv_implicit(x: jax.Array, f: jax.Array,
             vmem_limit_bytes=vmem_limit),
         interpret=interpret_mode(),
     )(*args)
+    if plan.bands > 1:                  # drop the ragged band's extra rows
+        out = out[:, :oh // plan.pool_stride if plan.fuse_pool else oh]
     return out[..., :co]

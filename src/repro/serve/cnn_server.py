@@ -40,7 +40,10 @@ Each wave's host work is marked with :mod:`repro.serve.telemetry` spans
 (ident = the wave index): ``cnn.wave`` (one :meth:`CNNServer.step_wave`,
 counting its rows as ``cnn.rows``), ``cnn.upload`` (the image stack),
 ``cnn.conv_dispatch`` / ``cnn.fc_dispatch`` (the host enqueueing each
-stage's kernels) and ``cnn.logits_wait`` (the wave's one barrier).
+stage's kernels; the conv dispatch counts each conv's row bands, input
+slab bytes and halo bytes as ``sa_conv.bands``, ``sa_conv.slab_bytes``
+and ``sa_conv.halo_bytes``) and ``cnn.logits_wait`` (the wave's one
+barrier).
 """
 from __future__ import annotations
 
@@ -49,6 +52,7 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dataflow import band_input_bytes
 from repro.core.engine import DispatchTrace, Engine
 from repro.core.schedule import LayerSchedule
 from repro.serve import telemetry
@@ -104,6 +108,23 @@ class _StageBuffer:
     conv_trace: DispatchTrace
 
 
+def _count_bands(trace: DispatchTrace) -> None:
+    """Count, per dispatched conv, the row bands its plan ran in
+    (``sa_conv.bands``), its compulsory input slab bytes
+    (``sa_conv.slab_bytes``) and the input bytes fetched a second time as
+    band halos (``sa_conv.halo_bytes``)."""
+    for r in trace:
+        if r.conv_plan is None:
+            continue
+        batch, h, w, ci, p, q, _, stride = r.conv_shape
+        slab, halo = band_input_bytes(
+            batch, h, w, ci, p, q, stride=stride, band=r.conv_plan.band,
+            bytes_in=np.dtype(r.dtype).itemsize)
+        telemetry.count("sa_conv.bands", r.conv_plan.bands)
+        telemetry.count("sa_conv.slab_bytes", slab)
+        telemetry.count("sa_conv.halo_bytes", halo)
+
+
 class CNNServer:
     """Admit single images, dispatch planner-sized micro-batches through
     the dual-array two-stage pipeline.
@@ -138,8 +159,8 @@ class CNNServer:
             else Engine(backend="pallas")
         self._planner_microbatch = self._preferred_microbatch()
         self.microbatch = self._planner_microbatch
-        # Plan the conv stack now: a layer no tiling fits on the chip (VGG-16's
-        # 224x224 stem) raises PlanError naming it here, not inside a served
+        # Plan the conv stack now: a layer not even a band of rows fits on
+        # the chip raises PlanError naming it here, not inside a served
         # wave.  Conv VMEM does not depend on the batch, so batch 1 suffices.
         LayerSchedule.compile_cnn(
             net, stage="conv", batch=1, in_res=self.in_res, in_ch=in_ch,
@@ -230,6 +251,7 @@ class CNNServer:
         with eng.tracing() as tr, eng.tagging(stage="conv", wave=wave_idx), \
                 telemetry.span("cnn.conv_dispatch", wave_idx):
             feats = cnn.cnn_conv_stage(self.net, self.params, x, eng=eng)
+            _count_bands(tr)
         return _StageBuffer(wave_idx, list(wave), feats, tr)
 
     def _fc_stage_complete(self, buf: _StageBuffer) -> list[CNNRequest]:

@@ -328,14 +328,32 @@ def test_cli_verifies_named_net(capsys):
 
 
 def test_cli_verifies_zoo_variants_and_names_the_unplannable_one(capsys):
-    """Both AlexNet variants verify at their micro-batch; full-width
-    VGG-16, which the planner refuses on the chip, is named with its
-    layer as a warning rather than aborting the run."""
+    """Every zoo variant verifies at its micro-batch with no finding:
+    full-width VGG-16 plans its 224x224 stem in row bands, so no variant
+    is left unplannable on the chip."""
     from repro.analysis.__main__ import main
     assert main(["--all-zoo-variants", "--skip-determinism-lint"]) == 0
     out = capsys.readouterr().out
     assert "[alexnet@b8] OK" in out and "[alexnet-int8@b" in out
+    assert "[vgg16@b8] OK" in out
+    assert "cannot be served" not in out
+
+
+def test_cli_names_a_variant_no_band_fits_as_a_warning(capsys, monkeypatch):
+    """Under a budget too small for even a one-row band of a stem, each
+    variant is named with its layer as a warning rather than aborting
+    the run."""
+    import dataclasses
+
+    from repro.analysis.__main__ import main
+    from repro.core import engine
+    from repro.core.accelerator import TPU_V5E
+    small = dataclasses.replace(TPU_V5E, vmem_budget=256 * 1024)
+    monkeypatch.setattr(engine, "current_chip", lambda: small)
+    assert main(["--all-zoo-variants", "--skip-determinism-lint"]) == 0
+    out = capsys.readouterr().out
     assert "[residency] vgg16/conv1: cannot be served on this chip" in out
+    assert "[residency] alexnet/conv1: cannot be served on this chip" in out
 
 
 def test_cli_requires_a_target():

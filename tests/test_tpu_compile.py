@@ -219,13 +219,59 @@ def test_sharded_wave_compiles_on_four_chip_mesh(topo, on_tpu):
     assert compiled.output_shardings.spec == PartitionSpec("data")
 
 
+def _vgg_convs():
+    """(name, spec, input (h, w, c), following pool) per VGG-16 conv."""
+    spec, _ = cnn.NETWORKS["vgg16"]
+    stats = {l.name: l for l in cnn.network_stats("vgg16")}
+    out, n = {}, 0
+    for i, s in enumerate(spec):
+        if s.kind == "conv":
+            n += 1
+            nxt = spec[i + 1]
+            pool = PoolSpec(nxt.kernel, nxt.stride) \
+                if nxt.kind == "pool" else None
+            out[f"conv{n}"] = (s, stats[f"conv{n}"].ifm, pool)
+    return out
+
+
+VGG_CONVS = _vgg_convs()
+
+
+@pytest.mark.parametrize("name", sorted(VGG_CONVS, key=lambda n: int(n[4:])))
+def test_vgg16_conv_compiles_for_v5e(name, one_chip, on_tpu):
+    """Full-width VGG-16 at 224x224, fp32, the zoo's wave of 8: conv1 in
+    two row bands (its input windows overlap by the halo), conv2 at the
+    edge of the budget, the rest on whole slabs — every one one
+    ``sa_conv_implicit`` kernel contracting at fp32."""
+    eng = on_tpu
+    s, (h, w, c), pool = VGG_CONVS[name]
+
+    def S(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    with eng.tracing() as tr:
+        lowered = jax.jit(lambda x, b, f: eng.conv2d(
+            x, f, b, stride=s.stride, pad=s.pad, act=s.act, pool=pool,
+            name=name)).lower(S((8, h, w, c)), S((s.out_ch,)),
+                              S((s.kernel, s.kernel, c, s.out_ch)))
+    plan = tr[0].conv_plan
+    assert plan.bands == (2 if name == "conv1" else 1)
+    compiled = lowered.compile()
+    _assert_compiled(compiled)
+    assert compiled.as_text().count("sa_conv_implicit") >= 1
+    matmuls = _mosaic_matmuls(lowered)
+    assert matmuls
+    assert all("contract_precision<fp32>" in op for op in matmuls), matmuls
+
+
 def test_vgg16_full_width_fails_at_schedule_time():
-    """VGG-16's 224x224 stem needs spatial tiling of the input slab,
-    which the conv kernel does not have: under the compiler's own VMEM
-    accounting the planner refuses it — loudly, naming the layer, when
-    the schedule is compiled — instead of the chip's compiler refusing it
-    (or a serving lane quarantining every request)."""
+    """Where even a one-row band of VGG-16's 224x224 stem overflows the
+    budget, under the compiler's own VMEM accounting, the planner refuses
+    it — loudly, naming the layer, when the schedule is compiled —
+    instead of the chip's compiler refusing it (or a serving lane
+    quarantining every request)."""
+    from repro.core.engine import DispatchPolicy
     with pytest.raises(PlanError) as ei:
-        LayerSchedule.compile_cnn("vgg16", batch=1,
-                                  policy=Engine(chip=TPU_V5E).policy)
+        LayerSchedule.compile_cnn("vgg16", batch=1, policy=DispatchPolicy(
+            chip=TPU_V5E, vmem_budget=1024 * 1024))
     assert ei.value.op == "conv1"

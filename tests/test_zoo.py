@@ -66,21 +66,44 @@ def test_zoo_registry_unknown_name_raises():
 
 
 def test_build_zoo_refuses_full_width_vgg16(monkeypatch):
-    """VGG-16's 224x224 stem needs spatial tiling the conv kernel lacks:
-    building the zoo raises PlanError naming conv1, before any request is
-    admitted — never a wave quarantined at serve time.  Parameter shapes
-    stand in for the 0.5 GB of seeded weights; only shapes are planned."""
+    """Under a VMEM budget that cannot hold even a one-row band of
+    VGG-16's 224x224 stem, building the zoo raises PlanError naming
+    conv1, before any request is admitted — never a wave quarantined at
+    serve time.  Parameter shapes stand in for the 0.5 GB of seeded
+    weights; only shapes are planned."""
     import jax
 
     from repro.core.dataflow import PlanError
+    from repro.core.engine import DispatchPolicy, Engine
     from repro.models import cnn
 
     init = cnn.init_cnn
     monkeypatch.setattr(cnn, "init_cnn", lambda *a, **kw: jax.eval_shape(
         lambda: init(*a, **kw)))
+    eng = Engine(backend="pallas",
+                 policy=DispatchPolicy(vmem_budget=1024 * 1024))
     with pytest.raises(PlanError) as ei:
-        build_zoo(("vgg16",), width_mult=1.0)
+        build_zoo(("vgg16",), width_mult=1.0, engine=eng)
     assert ei.value.op == "conv1"
+
+
+def test_build_zoo_builds_full_width_vgg16_with_a_banded_stem(monkeypatch):
+    """At the chip's budget full-width VGG-16 builds: conv1 (226x226x3
+    padded, 64 out) runs in two bands of 112 rows, conv2-13 on whole
+    slabs, and the wave is the admission cap of 8."""
+    import jax
+
+    from repro.models import cnn
+
+    init = cnn.init_cnn
+    monkeypatch.setattr(cnn, "init_cnn", lambda *a, **kw: jax.eval_shape(
+        lambda: init(*a, **kw)))
+    model, = build_zoo(("vgg16",), width_mult=1.0)
+    assert model.server.in_res == 224 and model.microbatch == 8
+    conv, _ = model.server._stage_schedules(8)
+    bands = {k.name: (p.band, p.bands) for k, p in conv.conv_entries.items()}
+    assert bands.pop("conv1") == (112, 2)
+    assert set(bands.values()) == {(0, 1)} and len(bands) == 12
 
 
 # -- compiled-schedule registry ----------------------------------------------
