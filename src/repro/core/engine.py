@@ -571,6 +571,18 @@ class Engine:
     # internal alias
     _record = record
 
+    def replay(self, records) -> None:
+        """Append records a compiled program's trace made to the live
+        trace (no-op when not tracing), tagged like :meth:`record` tags —
+        so every call of the program accounts for its dispatches."""
+        if self.trace is None:
+            return
+        tags = getattr(self._trace_tls, "tags", None)
+        for r in records:
+            if tags is not None:
+                r = dataclasses.replace(r, stage=tags[0], wave=tags[1])
+            self.trace.append(r)
+
     # -- planning -----------------------------------------------------------
     def plan_for(self, name: str, m: int, n: int, k: int, *,
                  dtype, weight_dtype) -> tuple[Any, str]:

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 # --------------------------------------------------------------------------
@@ -102,10 +103,12 @@ def conv2d(x: jax.Array, f: jax.Array, *, stride: int = 1,
 
 
 def maxpool2d(x: jax.Array, *, window: int = 2, stride: int = 2) -> jax.Array:
-    lo = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) \
+    lo = -np.inf if jnp.issubdtype(x.dtype, jnp.floating) \
         else jnp.iinfo(x.dtype).min
+    # a host constant, so that under jit too the window reduces by the
+    # differentiable max reducer
     return jax.lax.reduce_window(
-        x, jnp.asarray(lo, x.dtype), jax.lax.max,
+        x, np.array(lo, x.dtype), jax.lax.max,
         (1, window, window, 1), (1, stride, stride, 1), "VALID")
 
 

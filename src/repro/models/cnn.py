@@ -7,11 +7,18 @@ patch extraction inside the kernel, no materialized im2col), every FC on
 SA-FC when memory-bound, and every conv+maxpool pair as one fused dispatch
 whose pooling-&-activation stage rides the accumulator-flush epilogue —
 i.e. the complete MPNA operator set with the Fig. 7 pipeline intact.
+
+Each stage (the conv stack, the classifier head) is traced once per
+input shape through the engine into one ``jax.jit`` program, with the
+parameters as its arguments; every later call of that shape is one
+program call, and the engine dispatch records of the trace are replayed
+into the caller's trace.  :mod:`repro.serve.telemetry` counts
+``cnn.stage_calls`` per call and ``cnn.stage_builds`` per trace.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from collections.abc import Sequence
+from collections.abc import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +26,7 @@ import jax.numpy as jnp
 from repro.core import engine
 from repro.core.dataflow import PoolSpec
 from repro.models.layers import dense_init
+from repro.serve import telemetry
 
 
 @dataclass(frozen=True)
@@ -203,19 +211,13 @@ def conv_stage_len(name: str) -> int:
     return len(spec)
 
 
-def cnn_conv_stage(name: str, params: list, x: jax.Array, *,
-                   backend: str = "pallas",
-                   eng: engine.Engine | None = None) -> jax.Array:
-    """The SA-CONV stage of the dual-array pipeline: the conv+fused-pool
-    stack, ``(N, H, W, C) -> (N, features)`` flattened for the classifier
-    head.  Dispatch-for-dispatch identical to the CONV prefix of
-    :func:`cnn_forward` (same op names ``conv1..``/``pool1..``, same fused
-    conv+pool pairing), so a compiled conv-stage schedule resolves every
-    layer by lookup and the composition with :func:`cnn_fc_stage` is
-    bitwise the full forward."""
+# ---------------------------------------------------------------------------
+# the stages as compiled programs
+# ---------------------------------------------------------------------------
+def _conv_layers(name: str, params: list, x: jax.Array,
+                 eng: engine.Engine) -> jax.Array:
+    """The conv+fused-pool stack, one engine dispatch per layer."""
     spec, _ = NETWORKS[name]
-    if eng is None:
-        eng = engine.current().with_(backend=backend)
     end = conv_stage_len(name)
     ci = pi = 0
     i = 0
@@ -242,23 +244,104 @@ def cnn_conv_stage(name: str, params: list, x: jax.Array, *,
     return x.reshape(x.shape[0], -1)
 
 
-def cnn_fc_stage(name: str, params: list, feats: jax.Array, *,
-                 backend: str = "pallas",
-                 eng: engine.Engine | None = None) -> jax.Array:
-    """The SA-FC stage of the dual-array pipeline: the classifier head,
-    ``(N, features) -> logits``.  Consumes the hand-off buffer
-    :func:`cnn_conv_stage` produces; op names ``fc1..`` match the FC
-    suffix of :func:`cnn_forward` exactly, so the batch-amortized FCPlans
-    resolve from a compiled fc-stage schedule unchanged."""
+def _fc_layers(name: str, params: list, feats: jax.Array,
+               eng: engine.Engine) -> jax.Array:
+    """The classifier head, one engine dispatch per layer."""
     spec, _ = NETWORKS[name]
-    if eng is None:
-        eng = engine.current().with_(backend=backend)
     start = conv_stage_len(name)
     x = feats
     for fi, (s, p) in enumerate(zip(spec[start:], params[start:]), start=1):
         x = x.reshape(x.shape[0], -1)
         x = eng.matmul(x, p["w"], p["b"], act=s.act, name=f"fc{fi}")
     return x
+
+
+@dataclass
+class _StageProgram:
+    """One stage traced once through the engine into one ``jax.jit``
+    program.  ``records`` are the dispatches that trace made; ``config``
+    holds the engine's policy and schedule, whose identities key it."""
+    fn: Callable
+    config: tuple
+    records: tuple[engine.DispatchRecord, ...] = ()
+
+
+#: the stage programs built so far, by :func:`_program_key`
+_PROGRAMS: dict[tuple, _StageProgram] = {}
+
+
+def _program_key(layers: Callable, name: str, params: list, x,
+                 eng: engine.Engine) -> tuple:
+    """What a stage program depends on: the stage and network, the
+    input's and the parameters' shapes and dtypes, and the engine's
+    backend, policy and schedule (by identity: the program holds them,
+    so an identity is never reused while its key is live)."""
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    return (layers, name, tuple(x.shape), x.dtype, tree,
+            tuple((p.shape, p.dtype) for p in leaves),
+            eng.backend, id(eng.policy), id(eng.schedule))
+
+
+def _build(layers: Callable, name: str, eng: engine.Engine
+           ) -> _StageProgram:
+    backend, policy, schedule = eng.backend, eng.policy, eng.schedule
+
+    def traced(params, x):
+        # runs once per trace of the program, not once per call
+        telemetry.count("cnn.stage_builds")
+        rec = engine.Engine(backend=backend, policy=policy,
+                            schedule=schedule, trace=engine.DispatchTrace())
+        out = layers(name, params, x, rec)
+        prog.records = tuple(rec.trace)
+        return out
+
+    prog = _StageProgram(jax.jit(traced), (policy, schedule))
+    return prog
+
+
+def _run_stage(layers: Callable, name: str, params: list, x,
+               eng: engine.Engine) -> jax.Array:
+    """Run one stage as its compiled program — built on the first call of
+    its shape — and replay the dispatches its trace made into ``eng``'s
+    live trace.  The parameters are arguments of the program, never
+    constants in it."""
+    key = _program_key(layers, name, params, x, eng)
+    prog = _PROGRAMS.get(key)
+    if prog is None:
+        prog = _PROGRAMS[key] = _build(layers, name, eng)
+    telemetry.count("cnn.stage_calls")
+    out = prog.fn(params, x)
+    eng.replay(prog.records)
+    return out
+
+
+def cnn_conv_stage(name: str, params: list, x: jax.Array, *,
+                   backend: str = "pallas",
+                   eng: engine.Engine | None = None) -> jax.Array:
+    """The SA-CONV stage of the dual-array pipeline: the conv+fused-pool
+    stack, ``(N, H, W, C) -> (N, features)`` flattened for the classifier
+    head, run as one compiled program per input shape.  Dispatch-for-
+    dispatch identical to the CONV prefix of :func:`cnn_forward` (same op
+    names ``conv1..``/``pool1..``, same fused conv+pool pairing), so a
+    compiled conv-stage schedule resolves every layer by lookup and the
+    composition with :func:`cnn_fc_stage` is bitwise the full forward."""
+    if eng is None:
+        eng = engine.current().with_(backend=backend)
+    return _run_stage(_conv_layers, name, params, x, eng)
+
+
+def cnn_fc_stage(name: str, params: list, feats: jax.Array, *,
+                 backend: str = "pallas",
+                 eng: engine.Engine | None = None) -> jax.Array:
+    """The SA-FC stage of the dual-array pipeline: the classifier head,
+    ``(N, features) -> logits``, run as one compiled program per input
+    shape.  Consumes the hand-off buffer :func:`cnn_conv_stage`
+    produces; op names ``fc1..`` match the FC suffix of
+    :func:`cnn_forward` exactly, so the batch-amortized FCPlans resolve
+    from a compiled fc-stage schedule unchanged."""
+    if eng is None:
+        eng = engine.current().with_(backend=backend)
+    return _run_stage(_fc_layers, name, params, feats, eng)
 
 
 def cnn_forward(name: str, params: list, x: jax.Array, *,
@@ -285,8 +368,9 @@ def cnn_forward(name: str, params: list, x: jax.Array, *,
     the trace/schedule.
 
     The forward IS the composition of the two pipeline stages
-    (:func:`cnn_conv_stage` -> :func:`cnn_fc_stage`) — the dual-array
-    serving pipeline overlaps them across waves without changing any
+    (:func:`cnn_conv_stage` -> :func:`cnn_fc_stage`), each one compiled
+    program per input shape — the same programs the dual-array serving
+    pipeline runs and overlaps across waves, so serving changes no
     per-request math."""
     if eng is None:
         eng = engine.current().with_(backend=backend)

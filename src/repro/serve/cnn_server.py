@@ -17,7 +17,10 @@ per request.  This server is the CNN analogue of
   stage-split :meth:`~repro.core.schedule.LayerSchedule.compile_cnn`
   schedules: the SA-CONV stage (conv+fused-pool stack -> flattened
   features, the stage hand-off buffer) and the SA-FC stage (classifier
-  head on the buffered features);
+  head on the buffered features), each one compiled program per wave
+  size (:func:`~repro.models.cnn.cnn_conv_stage`,
+  :func:`~repro.models.cnn.cnn_fc_stage`): a wave makes one call per
+  stage, not one dispatch per layer;
 * **dual-array pipelining** (the paper's joint execution: both arrays
   busy at once): wave *i*'s FC head is dispatched and completed while
   wave *i+1*'s conv stack is already in flight — the conv stage of the
@@ -39,11 +42,12 @@ the reports of its most recent :data:`RECENT_WAVES` waves.
 Each wave's host work is marked with :mod:`repro.serve.telemetry` spans
 (ident = the wave index): ``cnn.wave`` (one :meth:`CNNServer.step_wave`,
 counting its rows as ``cnn.rows``), ``cnn.upload`` (the image stack),
-``cnn.conv_dispatch`` / ``cnn.fc_dispatch`` (the host enqueueing each
-stage's kernels; the conv dispatch counts each conv's row bands, input
-slab bytes and halo bytes as ``sa_conv.bands``, ``sa_conv.slab_bytes``
-and ``sa_conv.halo_bytes``) and ``cnn.logits_wait`` (the wave's one
-barrier).
+``cnn.conv_dispatch`` / ``cnn.fc_dispatch`` (the host calling each
+stage's program, counted as ``cnn.stage_calls``, and tracing it where
+that wave size is new, counted as ``cnn.stage_builds``; the conv
+dispatch counts each conv's row bands, input slab bytes and halo bytes
+as ``sa_conv.bands``, ``sa_conv.slab_bytes`` and ``sa_conv.halo_bytes``)
+and ``cnn.logits_wait`` (the wave's one barrier).
 """
 from __future__ import annotations
 
@@ -166,6 +170,7 @@ class CNNServer:
             net, stage="conv", batch=1, in_res=self.in_res, in_ch=in_ch,
             width_mult=width_mult, dtype=self.dtype,
             policy=self.engine.policy, params=params)
+        self._schedules: dict[int, tuple[LayerSchedule, LayerSchedule]] = {}
         self.queue: list[CNNRequest] = []
         self.waves: list[WaveReport] = []        # the RECENT_WAVES latest
         self._wave_counter = 0
@@ -216,10 +221,15 @@ class CNNServer:
 
     def _stage_schedules(self, batch: int
                          ) -> tuple[LayerSchedule, LayerSchedule]:
-        return LayerSchedule.compile_cnn_stages(
-            self.net, batch=batch, in_res=self.in_res, in_ch=self.in_ch,
-            width_mult=self.width_mult, dtype=self.dtype,
-            policy=self.engine.policy, params=self.params)
+        """The stage schedules of a ``batch``-row wave, looked up once per
+        row count: the memo's key fingerprints every parameter."""
+        pair = self._schedules.get(batch)
+        if pair is None:
+            pair = self._schedules[batch] = LayerSchedule.compile_cnn_stages(
+                self.net, batch=batch, in_res=self.in_res, in_ch=self.in_ch,
+                width_mult=self.width_mult, dtype=self.dtype,
+                policy=self.engine.policy, params=self.params)
+        return pair
 
     # -- serving ------------------------------------------------------------
     def submit(self, req: CNNRequest) -> None:

@@ -1179,15 +1179,15 @@ class FleetServer:
         replicated on the mesh, and the model's forward runs under
         ``shard_map`` over ``("data",)``: each device runs the kernels on
         its own rows (a Pallas TPU kernel cannot be partitioned by the
-        compiler).  The map is applied eagerly, one op at a time, so the
-        per-layer kernels are the same pallas calls the per-replica lanes
-        run — rows are independent in every one of them, so each served
+        compiler).  Inside the map the forward runs the same compiled
+        stage programs the per-replica lanes run, at the per-device row
+        count; rows are independent in every kernel, and a whole stage
+        under one ``jax.jit`` computes each row bitwise as the batch-1
+        forward does (``tests/test_stage_program.py``), so each served
         row stays **bitwise equal** to the single-device unbatched
-        forward (a whole-forward ``jax.jit`` would re-fuse the graph and
-        break that bit-exactness on the interpreted kernels).  Same
-        host-side integrity guard and never-wedge discipline as the
-        per-replica executor.  Its host work is the span
-        ``fleet.sharded_wave``."""
+        forward.  Same host-side integrity guard and never-wedge
+        discipline as the per-replica executor.  Its host work is the
+        span ``fleet.sharded_wave``."""
         # ident: the serve() call this wave belongs to
         with telemetry.span("fleet.sharded_wave", self._serve_calls - 1):
             self._run_sharded(a, events)

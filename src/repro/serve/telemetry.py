@@ -23,7 +23,10 @@ part of, so a reader never reports a partial window.
 A counter is keyed by its name and by the name of the innermost span
 open on the counting thread (``None`` outside any span).  ``compile``
 counts the backend compiles JAX reports through ``jax.monitoring``, so it
-says which step compiled.
+says which step compiled.  ``cnn.stage_calls`` counts the calls of a
+CNN stage's compiled program and ``cnn.stage_builds`` the traces that
+built one (:mod:`repro.models.cnn`): after warm-up a wave adds one call
+per stage and no build.
 
 The spans observe the serving path; no scheduling decision reads them.
 There is one recorder per process, so that the layers need no handle

@@ -4,8 +4,9 @@ The CPU runs the Pallas kernels in the interpreter, which accepts block
 shapes, strided slices and working sets the chip's compiler refuses.
 These tests lower AlexNet's conv1-conv5 (with their fused pools) and
 fc1-fc3 at full width, fp32 and int8, at batch 1 and at the zoo's planner
-micro-batch, its pools as standalone kernels, and one ``data=4``
-cooperative wave on a described 2x2 mesh,
+micro-batch, its conv and FC stages as the programs a zoo wave runs, its
+pools as standalone kernels, and one ``data=4`` cooperative wave on a
+described 2x2 mesh,
 through the TPU compiler — no chip needed, nothing executed.  Each
 compiled program must carry a ``tpu_custom_call``: an interpreted
 lowering cannot pass.
@@ -174,6 +175,40 @@ def test_alexnet_layer_compiles_for_v5e(name, dtype, batch, one_chip,
     matmuls = _mosaic_matmuls(lowered)
     assert matmuls
     assert all("contract_precision<fp32>" in op for op in matmuls), matmuls
+
+
+#: stage -> (the roofline reader's pattern for its kernel, as the trace
+#: names the custom call, and how many of them the stage holds)
+STAGE_KERNELS = {"conv": (r"^%sa_conv_implicit(\.\d+)? = ", 5),
+                 "fc": (r"^%sa_fc_matmul(\.\d+)? = ", 3)}
+
+
+@pytest.mark.parametrize("stage,dtype", [(st, dt) for st in STAGE_KERNELS
+                                         for dt in ("fp32", "int8")])
+def test_alexnet_stage_program_compiles_for_v5e(stage, dtype, one_chip,
+                                                on_tpu):
+    """Each stage of a zoo wave as the one program the server calls: its
+    custom calls are the stage's SA-CONV (or SA-FC) kernels, named so the
+    benchmark's roofline readers still find every one of them."""
+    eng = on_tpu
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        _params(dtype == "int8"))
+    if stage == "conv":
+        fn, shape = cnn.cnn_conv_stage, (MICRO, 227, 227, 3)
+    else:
+        fn, shape = cnn.cnn_fc_stage, (MICRO, LAYERS["fc1"][1][2])
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    with eng.tracing() as tr:
+        compiled = jax.jit(lambda p, v: fn("alexnet", p, v, eng=eng)
+                           ).lower(params, x).compile()
+    _assert_compiled(compiled)
+    pattern, n = STAGE_KERNELS[stage]
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == n == len(tr)
+    assert all(re.match(pattern, c) for c in calls), calls
 
 
 #: conv name -> (its output map (h, w, c), the maxpool that follows it)
