@@ -141,10 +141,6 @@ RECOVERY = {
     "heartbeat_timeout_s": 2.0e-4,
 }
 
-#: Fleet shape shared by every configuration.
-FLEET = {"mesh_model_parallel": 1, "mesh_global_batch": 64,
-         "mesh_pod_size": 64}
-
 #: generate-mode knob (benchmarks/check_bench.py): the modeled fleet
 #: schedule, statuses, event log and accounting are
 #: execution-independent, so the regression gate regenerates with
@@ -192,15 +188,14 @@ def build_fleet(*, n_replicas: int, chaos: dict | bool = False,
     return FleetServer(
         _models(), n_replicas=n_replicas, policy=FIFOPolicy(),
         placement=PLACEMENTS[placement](), faults=faults,
-        recovery=RecoveryConfig(**RECOVERY), shard_waves=shard_waves,
-        **FLEET)
+        recovery=RecoveryConfig(**RECOVERY), shard_waves=shard_waves)
 
 
 def run_config(trace: list[dict], *, n_replicas: int,
                chaos: dict | bool = False,
                placement: str = "least-loaded",
                shard_waves: bool = False, execute: bool = False):
-    """One full fleet drain; returns the FleetReport."""
+    """One full fleet drain; returns the ZooReport."""
     from repro.serve.zoo import ZooRequest
 
     fleet = build_fleet(n_replicas=n_replicas, chaos=chaos,
@@ -533,11 +528,17 @@ def emit(out_path: str = "BENCH_sharded.json", *, tier: str = "fast"
     }
 
     import jax
+
+    from repro.serve import fleet
     results = {"bench": "fleet_serve", "tier": tier,
                "backend": "pallas-interpret-cpu",
-               "fleet": FLEET | {"replicas": [1, 2, 4],
-                                 "placement": "least-loaded",
-                                 "policy": "fifo"},
+               # the elastic mesh every replan proposes: the plane's
+               # constants
+               "fleet": {"mesh_model_parallel": fleet.MESH_MODEL_PARALLEL,
+                         "mesh_global_batch": fleet.MESH_GLOBAL_BATCH,
+                         "mesh_pod_size": fleet.MESH_POD_SIZE,
+                         "replicas": [1, 2, 4],
+                         "placement": "least-loaded", "policy": "fifo"},
                "chaos": CHAOS | {
                    "stall_factors": list(CHAOS["stall_factors"]),
                    "kills": [list(k) for k in CHAOS["kills"]],

@@ -25,16 +25,17 @@ Fault kinds (wave-granular, matching the serving plane's failure modes):
   either array.
 
 The injector never touches the scheduler's clock or queues itself — the
-:class:`~repro.serve.zoo.ModelZooServer` consults it once per wave
-attempt and applies its own recovery policy (retry with capped backoff,
-quarantine, degrade), so the same seeded fault trace can be replayed
-against different recovery configurations.
+serving plane (:class:`~repro.serve.fleet.ModelZooServer`) consults it
+once per wave attempt through the :class:`FaultPlane` interface and
+applies its own recovery policy (retry with capped backoff, quarantine,
+degrade), so the same seeded fault trace can be replayed against
+different recovery configurations.
 
 Replica-granular chaos (fleet level)
 ------------------------------------
 :class:`ReplicaChaosConfig` / :class:`ReplicaFaultInjector` lift the
-same discipline one level up, to the sharded fleet
-(:class:`~repro.serve.fleet.FleetServer`):
+same discipline one level up, to the replicas of a serving plane with
+``n_replicas > 1``; their verdicts blame the replica, not the model:
 
 * ``kills`` — a replica dies at a configured modeled instant: its
   queued waves drain to surviving peers and its in-flight wave fails
@@ -58,13 +59,48 @@ as pinnable as a wave-level one.
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import numpy as np
 
 from repro.core.dataflow import PlanError
 
-__all__ = ["ChaosConfig", "WaveFaults", "FaultInjector",
+__all__ = ["FaultPlane", "ChaosConfig", "WaveFaults", "FaultInjector",
            "ReplicaChaosConfig", "ReplicaFaults", "ReplicaFaultInjector"]
+
+
+class FaultPlane:
+    """What the serving plane asks of a chaos source, at either level.
+
+    ``verdict(replica_index, attempt, batch)`` is the fault of one wave
+    attempt; its ``blames`` says what is at fault: ``"model"`` (wave
+    chaos: the verdict feeds the model's health) or ``"replica"`` (it
+    feeds only retry and the replica's straggler monitor).  The plane's
+    own ``blames`` says which of the two it injects: only a plane that
+    blames models arms the per-model heartbeat, so a model starved by
+    scheduling alone is never declared failed.  Replica deaths and
+    heartbeat partitions are plan lookups; a plane that injects none
+    keeps the defaults below."""
+
+    blames: ClassVar[str]
+
+    def verdict(self, replica_index: int, attempt: int, batch: int):
+        raise NotImplementedError
+
+    def kill_time(self, replica_id: str) -> float | None:
+        """When (if ever) this replica dies, in modeled seconds."""
+        return None
+
+    def partition_windows(self, replica_id: str
+                          ) -> tuple[tuple[float, float], ...]:
+        """This replica's heartbeat-drop windows, in config order."""
+        return ()
+
+    def partitioned(self, replica_id: str, t_s: float) -> bool:
+        """Whether a heartbeat from this replica at ``t_s`` is dropped
+        (windows are half-open: ``start <= t < end``)."""
+        return any(s <= t_s < e
+                   for s, e in self.partition_windows(replica_id))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +145,7 @@ class WaveFaults:
     kind: str                               # "none"|"stall"|"corrupt"|"dispatch"
     stall_factor: float = 1.0
     corrupt_rows: tuple[int, ...] = ()
+    blames: ClassVar[str] = "model"
 
     @property
     def is_clean(self) -> bool:
@@ -118,7 +155,7 @@ class WaveFaults:
 _CLEAN = WaveFaults(attempt=-1, kind="none")
 
 
-class FaultInjector:
+class FaultInjector(FaultPlane):
     """Derives each wave attempt's fault from ``(seed, attempt)`` alone.
 
     ``wave_faults(attempt, batch)`` is the scheduler-side oracle (modeled
@@ -126,6 +163,8 @@ class FaultInjector:
     realizations of the same decisions — both sides consult the same
     attempt index, so the modeled schedule and the real kernels always
     agree on which waves misbehave."""
+
+    blames: ClassVar[str] = "model"
 
     def __init__(self, config: ChaosConfig) -> None:
         self.config = config
@@ -156,6 +195,11 @@ class FaultInjector:
                               stall_factor=float(factor))
         return dataclasses.replace(_CLEAN, attempt=attempt)
 
+    def verdict(self, replica_index: int, attempt: int,
+                batch: int) -> WaveFaults:
+        """The plane's view: wave chaos ignores the replica."""
+        return self.wave_faults(attempt, batch)
+
     # -- execution-side realizations ----------------------------------------
     @staticmethod
     def corrupt_array(logits: np.ndarray) -> np.ndarray:
@@ -169,10 +213,11 @@ class FaultInjector:
 
     @staticmethod
     def dispatch_error(attempt: int, model: str) -> PlanError:
-        """The transient dispatch failure a faulted wave raises — a real
-        :class:`~repro.core.dataflow.PlanError`, so the server's recovery
-        path is exercised against the same exception type the planner
-        itself throws."""
+        """The transient dispatch failure a ``dispatch`` verdict stands
+        for — a real :class:`~repro.core.dataflow.PlanError`, the type the
+        planner itself throws.  The serving plane retries or quarantines
+        such an attempt on its modeled schedule and never runs its
+        kernels."""
         return PlanError("chaos: injected transient dispatch failure",
                          op=f"zoo.wave[{model}]@attempt{attempt}")
 
@@ -226,17 +271,20 @@ class ReplicaFaults:
     attempt: int
     kind: str                               # "none" | "stall"
     stall_factor: float = 1.0
+    blames: ClassVar[str] = "replica"
 
     @property
     def is_clean(self) -> bool:
         return self.kind == "none"
 
 
-class ReplicaFaultInjector:
+class ReplicaFaultInjector(FaultPlane):
     """Derives fleet-level faults from the chaos plan: kill/partition
     lookups are pure config reads, and the per-attempt stall verdict is a
     pure function of ``(seed, replica_index, attempt)`` — so the fleet
     scheduler's whole event log replays bit-for-bit."""
+
+    blames: ClassVar[str] = "replica"
 
     def __init__(self, config: ReplicaChaosConfig) -> None:
         self.config = config
@@ -259,18 +307,15 @@ class ReplicaFaultInjector:
                                  stall_factor=float(factor))
         return ReplicaFaults(replica_index, attempt, "none")
 
+    def verdict(self, replica_index: int, attempt: int,
+                batch: int) -> ReplicaFaults:
+        """The plane's view: a device stall has no rows to corrupt."""
+        return self.wave_faults(replica_index, attempt)
+
     def kill_time(self, replica_id: str) -> float | None:
-        """When (if ever) this replica dies, in modeled seconds."""
         return self._kills.get(replica_id)
 
     def partition_windows(self, replica_id: str
                           ) -> tuple[tuple[float, float], ...]:
-        """This replica's heartbeat-drop windows, in config order."""
         return tuple((s, e) for rid, s, e in self.config.partitions
                      if rid == replica_id)
-
-    def partitioned(self, replica_id: str, t_s: float) -> bool:
-        """Whether a heartbeat from this replica at ``t_s`` is dropped
-        (windows are half-open: ``start <= t < end``)."""
-        return any(s <= t_s < e
-                   for s, e in self.partition_windows(replica_id))

@@ -1,86 +1,77 @@
-"""Replica-sharded serving fleet — N data-parallel copies of the model
-zoo behind one admission plane, surviving dead devices.
+"""The serving plane: one scheduler, one executor and one integrity guard
+for a model zoo on one chip or replicated over several.
 
 MPNA's thesis is that many parallel arrays plus the right dataflow beat
-one big array; this module is the fleet-scale analogue: N **replicas**
-(each a full dual-array pipeline holding every zoo model) split the
-scheduled wave stream via a pluggable :class:`PlacementPolicy`, and a
-**per-replica health plane** keeps the fleet serving when replicas die.
+one big array; this module carries it to serving.  :class:`ModelZooServer`
+holds the zoo's compiled variants (:class:`~repro.serve.zoo.ZooModel`)
+on ``n_replicas`` **replicas** — each a full dual-array pipeline holding
+every model — and drains the admitted request stream through them.  A
+zoo on one chip is the one-replica case; ``FleetServer`` names the same
+class.
 
-Architecture
-------------
-* Each replica is an independent modeled dual-array pipeline (its own
-  ``conv_free``/``fc_free`` clocks — the per-replica twin of the
-  :class:`~repro.serve.zoo.ModelZooServer` scheduler) plus, at execution
-  time, its own per-model :class:`~repro.serve.cnn_server.CNNServer`
-  lane pinned to a JAX device (``jax.devices()`` round-robin; run CPU CI
-  with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to get a
-  real multi-device mesh).  **The modeled schedule never reads the
-  device count** — placement is over the configured logical replicas —
-  so the decision/event logs are bit-identical whether the host exposes
-  1 device or 8.
-* Admission (bounded tenant queues, stale deadlines, predictive
-  shedding) reuses the zoo's :class:`~repro.serve.zoo.AdmissionConfig`
-  semantics; placed requests are stamped with their replica.
-* The health plane drives the seed-era primitives per replica: a
-  :class:`~repro.distributed.fault_tolerance.HeartbeatTracker` on the
-  modeled clock (a partitioned replica's beats are dropped, so the
-  failure detector suspects it), a
-  :class:`~repro.distributed.fault_tolerance.StepMonitor` per replica
-  (transient device stalls trip the straggler verdict), and
-  :meth:`~repro.distributed.fault_tolerance.HeartbeatTracker.deregister`
-  drains a **dead** replica from liveness for good.
-* On replica death (:class:`~repro.serve.faults.ReplicaChaosConfig`
-  ``kills``): queued waves **drain to surviving peers**, the in-flight
-  wave fails and re-enters via retry + capped backoff (the zoo's
-  :class:`~repro.serve.zoo.RecoveryConfig` machinery, reused verbatim),
-  and :func:`~repro.distributed.elastic.replan` proposes the shrunk
-  data-parallel mesh (an event in the log, like every transition).  A
-  suspected (partitioned) replica drains its queue too, and **rejoins**
-  when its heartbeats return.  When *no* replica survives, remaining
-  requests are quarantined with typed
-  :class:`~repro.serve.errors.ReplicaLostError` results — the fleet
-  reports, it never wedges.
+``serve()`` first runs a deterministic **modeled-time** schedule, then
+executes every scheduled wave on the real kernels:
 
-* **Cooperative sharded waves** (``shard_waves=True``): when a model's
-  queue exceeds its planner micro-batch, the scheduler cuts ONE wave of
-  up to ``data x bb`` rows and executes it across every free healthy
-  replica — rows committed to the ``("data",)`` mesh via
-  ``jax.device_put`` + ``NamedSharding``
-  (:func:`~repro.distributed.sharding.shard_wave_rows`), priced by
-  :func:`~repro.core.perf_model.sharded_wave_cost` (one broadcast-fed
-  FC weight stream instead of per-replica HBM streams).  A participant
-  dying mid-wave aborts the wave (``shard_abort``), re-deals its rows
-  over the survivors (``reshard``,
-  :func:`~repro.distributed.elastic.reshard_wave` — the retry path
-  honors the pinned assignment), and retries with the usual backoff;
-  below two usable replicas the lane degrades to the per-replica path
-  with a typed ``shard_fallback`` event, never an error.
+* **Admission** at ``max(arrival, earliest conv_free of a usable
+  replica)``: bounded per-tenant queues, predictive shedding (or an int8
+  reroute) priced by the scheduler's own cost model, and routing away
+  from a *failed* variant to its int8 sibling.  A
+  :class:`PlacementPolicy` puts each admitted, retried or drained request
+  on a replica's queue.
+* **The loop**: whenever a replica's SA-CONV array frees, the zoo's
+  :class:`~repro.serve.zoo.SchedulingPolicy` picks which model's wave it
+  dispatches; wave *i*'s SA-FC stage overlaps wave *i+1*'s SA-CONV stage
+  (:func:`~repro.core.perf_model.zoo_wave_cost` prices both).  With
+  ``shard_waves=True`` a model's backlog past one micro-batch becomes ONE
+  cooperative wave of up to ``data x bb`` rows over every free usable
+  replica (:func:`~repro.core.perf_model.sharded_wave_cost`).
+* **Faults** come through one interface
+  (:class:`~repro.serve.faults.FaultPlane`): a verdict per wave attempt,
+  replica deaths and heartbeat partitions.  A verdict that blames the
+  model (stall, corrupt rows, failed dispatch) feeds that model's
+  :class:`~repro.serve.zoo.ModelHealth` and straggler monitor; one that
+  blames the replica feeds only retry and the replica's straggler
+  monitor.  Failed attempts retry with capped backoff and quarantine past
+  the budget as typed errors.  A dead replica's queue drains to its
+  peers, its in-flight wave retries elsewhere (a cooperative wave aborts
+  and re-shards its rows over the survivors,
+  :func:`~repro.distributed.elastic.reshard_wave`), and
+  :func:`~repro.distributed.elastic.replan` logs the shrunk mesh; a
+  partitioned replica is suspected, drained, and rejoins when its
+  heartbeats return.
+* **Execution**: each wave runs on its replica's lane, a
+  :class:`~repro.serve.cnn_server.CNNServer` per (replica, model).  On
+  the device that holds ``ZooModel.params`` the lane is
+  ``ZooModel.server`` itself; other devices get a copy committed to
+  them.  :meth:`ModelZooServer._guard` delivers every executed row
+  through the host-side integrity guard
+  (:func:`~repro.serve.errors.all_finite`).
 
-Public API: :class:`FleetServer` (``submit`` / ``serve`` /
-``pending_count``; knobs: ``n_replicas``, ``policy``, ``placement``,
-``faults``, ``admission``, ``recovery``, ``shard_waves``,
-``devices``), the :class:`PlacementPolicy` hierarchy (``PLACEMENTS``),
-and the report types :class:`FleetReport` / :class:`FleetWaveDecision`
-/ :class:`FleetEvent` / :class:`ReplicaStats`.
+Invariants: every admitted request ends as exactly one of served / shed /
+quarantined; a served request's logits are **bitwise equal** to its
+serving model's single-device unbatched forward, whichever replica,
+retry or cooperative wave served it; the modeled schedule is a pure
+function of (trace, configs, chaos plan) and never reads the device
+count.
 
-Invariants: every admitted request ends as exactly one of served /
-shed / quarantined (zero unaccounted); a served request's logits are
-**bitwise equal** to its model's single-device unbatched forward, no
-matter which replica, how many retries, or whether the wave was
-sharded over ``data=4``; the whole modeled schedule is a pure function
-of (trace, configs, chaos plan) — it never reads the device count —
-and is gated by ``BENCH_sharded.json``.
+``serve()`` marks its host work with :mod:`repro.serve.telemetry` spans
+(ident = the call's index): ``zoo.serve`` holding ``zoo.schedule``,
+``zoo.execute`` (each wave's ``cnn.wave`` and one ``zoo.guard`` per
+executed attempt, counting ``zoo.guard_rows`` checked and
+``zoo.guard_rejects`` refused; a cooperative wave is
+``fleet.sharded_wave``) and ``zoo.account``.
 """
 from __future__ import annotations
 
 import dataclasses
 import heapq
+import itertools
 from collections.abc import Callable, Sequence
 
 import numpy as np
 
 from repro.core.perf_model import WaveCost
+from repro.core.schedule import ScheduleRegistry
 from repro.distributed.elastic import replan, reshard_wave
 from repro.distributed.fault_tolerance import HeartbeatTracker, StepMonitor
 from repro.serve import telemetry
@@ -89,14 +80,26 @@ from repro.serve.errors import (CorruptOutputError, InsufficientReplicasError,
                                 ReplicaLostError, RequestShedError,
                                 ServeError, StaleDeadlineError,
                                 WaveTimeoutError, all_finite)
-from repro.serve.faults import ReplicaFaultInjector, ReplicaFaults
-from repro.serve.zoo import (AdmissionConfig, FIFOPolicy, ModelZooServer,
-                             RecoveryConfig, SchedulingPolicy, TenantStats,
-                             ZooModel, ZooRequest)
+from repro.serve.faults import FaultInjector, FaultPlane
+from repro.serve.zoo import (AdmissionConfig, FaultEvent, FIFOPolicy,
+                             ModelHealth, RecoveryConfig, ReplicaStats,
+                             SchedulingPolicy, TenantStats, WaveAttempt,
+                             WaveDecision, ZooModel, ZooReport, ZooRequest)
 
 __all__ = ["PlacementPolicy", "LeastLoadedPlacement", "RoundRobinPlacement",
-           "PLACEMENTS", "ReplicaView", "FleetWaveDecision", "FleetEvent",
-           "ReplicaStats", "FleetReport", "FleetServer"]
+           "PLACEMENTS", "ReplicaView", "ModelZooServer", "FleetServer"]
+
+#: The mesh :func:`~repro.distributed.elastic.replan` proposes after a
+#: replica transition: weights unsharded, and the data degree dividing
+#: this global batch within a pod of this many chips.
+MESH_MODEL_PARALLEL = 1
+MESH_GLOBAL_BATCH = 64
+MESH_POD_SIZE = 64
+
+#: Executor-side request uids, unique in the process: a model's own
+#: server is the lane of every plane that serves the model, and a server
+#: refuses a uid it has seen.
+_EXEC_UIDS = itertools.count()
 
 
 # ---------------------------------------------------------------------------
@@ -119,8 +122,9 @@ class ReplicaView:
 class PlacementPolicy:
     """Picks the replica an admitted/drained/retried request lands on.
     ``place`` sees the candidate :class:`ReplicaView` list (sorted by
-    replica index; only live, non-suspect replicas unless none exist)
-    and must return one of their ``rid``s, deterministically."""
+    replica index; only live, non-suspect replicas unless none exist;
+    never fewer than two — a lone candidate needs no choice) and must
+    return one of their ``rid``s, deterministically."""
 
     name = "base"
 
@@ -146,8 +150,9 @@ class LeastLoadedPlacement(PlacementPolicy):
 class RoundRobinPlacement(PlacementPolicy):
     """Strict rotation over the candidate replicas — the baseline the
     load-aware policy is compared against.  The rotation counter only
-    advances on placement, so the assignment sequence is deterministic
-    for a given trace."""
+    advances on a placement it is asked to decide (two or more
+    candidates), so the assignment sequence is deterministic for a given
+    trace."""
 
     name = "round-robin"
 
@@ -166,178 +171,9 @@ PLACEMENTS: dict[str, Callable[[], PlacementPolicy]] = {
 }
 
 
-# ---------------------------------------------------------------------------
-# fleet-level logs: decisions, events, per-replica accounting
-# ---------------------------------------------------------------------------
-@dataclasses.dataclass(frozen=True)
-class FleetWaveDecision:
-    """One fleet scheduling decision: at modeled ``t_s``, ``replica``
-    dispatched ``model``'s wave of ``batch`` requests at the modeled
-    stage occupancies below.  ``fault`` annotates what fleet chaos did
-    to the attempt (``replica_dead`` = the replica died mid-wave).
-    ``shards`` is empty for a per-replica wave; for a cooperative
-    sharded wave it lists every participating replica (``replica`` is
-    the root whose queue the wave was cut from) and ``conv_s``/``fc_s``
-    are the sharded stage terms (per-shard conv, broadcast-fed FC)."""
-    index: int
-    t_s: float
-    replica: str
-    model: str
-    uids: tuple[int, ...]
-    batch: int
-    conv_s: float
-    fc_s: float
-    fault: str = "none"        # none|stall|timeout|replica_dead
-    stall_factor: float = 1.0
-    shards: tuple[str, ...] = ()
-
-    @property
-    def total_s(self) -> float:
-        return self.conv_s + self.fc_s
-
-    @property
-    def sharded(self) -> bool:
-        return bool(self.shards)
-
-
-@dataclasses.dataclass(frozen=True)
-class FleetEvent:
-    """One fleet robustness event in modeled time.  ``kind`` is one of:
-    ``kill`` (a replica died), ``replica_dead`` (its in-flight wave was
-    lost), ``drain`` (a queued request moved to a peer), ``suspect`` /
-    ``rejoin`` (failure-detector transitions), ``replan`` /
-    ``replan_failed`` (elastic mesh proposals), ``retry`` /
-    ``quarantine`` / ``shed`` (per-request outcomes), ``stall`` /
-    ``timeout`` (wave-level device faults), ``shard_abort`` /
-    ``reshard`` / ``shard_fallback`` (cooperative-wave lifecycle: a
-    participant died mid-wave, the wave's rows were re-dealt over the
-    survivors, or the mesh fell below data=2 and the wave dropped to
-    the per-replica lane)."""
-    t_s: float
-    replica: str
-    kind: str
-    detail: str
-    uids: tuple[int, ...] = ()
-    attempt: int = -1
-    model: str = ""
-
-
-@dataclasses.dataclass(frozen=True)
-class ReplicaStats:
-    """Per-replica accounting for one drain: waves dispatched, requests
-    served, modeled busy seconds, requests drained *away* from it, and
-    its final state (``alive`` | ``suspect`` | ``dead``)."""
-    replica: str
-    waves: int
-    served: int
-    busy_s: float
-    drained_away: int
-    state: str
-
-
-@dataclasses.dataclass(frozen=True)
-class FleetReport:
-    """Everything one :meth:`FleetServer.serve` drain produced — the
-    fleet twin of :class:`~repro.serve.zoo.ZooReport`, with the decision
-    log carrying replica assignments, the event log carrying the fleet
-    fault plane, and ``mesh_plans`` the elastic replan history
-    ``(t_s, data_degree, wasted_chips, why)``."""
-    placement: str
-    policy: str
-    n_replicas: int
-    requests: tuple[ZooRequest, ...]
-    decisions: tuple[FleetWaveDecision, ...]
-    events: tuple[FleetEvent, ...]
-    makespan_s: float
-    per_replica: tuple[ReplicaStats, ...]
-    per_tenant: tuple[TenantStats, ...]
-    mesh_plans: tuple[tuple[float, int, int, str], ...]
-
-    @property
-    def served(self) -> tuple[ZooRequest, ...]:
-        return tuple(r for r in self.requests if r.status == "served")
-
-    @property
-    def shed(self) -> tuple[ZooRequest, ...]:
-        return tuple(r for r in self.requests if r.status == "shed")
-
-    @property
-    def quarantined(self) -> tuple[ZooRequest, ...]:
-        return tuple(r for r in self.requests
-                     if r.status == "quarantined")
-
-    @property
-    def unaccounted(self) -> tuple[ZooRequest, ...]:
-        """Admitted requests in no terminal state — ALWAYS empty (the
-        zero-unaccounted guarantee, fleet edition)."""
-        terminal = ("served", "shed", "quarantined")
-        return tuple(r for r in self.requests if r.status not in terminal)
-
-    @property
-    def throughput_rps(self) -> float:
-        return len(self.served) / self.makespan_s if self.makespan_s \
-            else 0.0
-
-    @property
-    def retry_count(self) -> int:
-        return sum(r.retries for r in self.requests)
-
-    @property
-    def drained_uids(self) -> tuple[int, ...]:
-        """Requests that were moved off a dying/suspect replica (queued
-        drains plus in-flight ``replica_dead`` losses), in event order —
-        the 'drain to surviving peers' audit trail."""
-        out: list[int] = []
-        for e in self.events:
-            if e.kind in ("drain", "replica_dead"):
-                out.extend(u for u in e.uids if u not in out)
-        return tuple(out)
-
-    @property
-    def mean_latency_s(self) -> float:
-        lats = [r.latency_s for r in self.served]
-        return float(np.mean(lats)) if lats else 0.0
-
-    def summary(self) -> str:
-        lines = [f"[fleet:{self.placement}/{self.policy}] "
-                 f"{self.n_replicas} replicas, {len(self.requests)} "
-                 f"requests in {len(self.decisions)} waves, makespan "
-                 f"{self.makespan_s * 1e3:.3f} ms, served "
-                 f"{len(self.served)} shed {len(self.shed)} quarantined "
-                 f"{len(self.quarantined)}, retries {self.retry_count}, "
-                 f"drained {len(self.drained_uids)}"]
-        for s in self.per_replica:
-            lines.append(f"  {s.replica}[{s.state}]: waves={s.waves} "
-                         f"served={s.served} busy "
-                         f"{s.busy_s * 1e3:.3f} ms "
-                         f"drained-away={s.drained_away}")
-        for t_s, data, wasted, why in self.mesh_plans:
-            lines.append(f"  mesh@{t_s * 1e3:.3f}ms: data={data} "
-                         f"wasted={wasted} ({why})")
-        return "\n".join(lines)
-
-
-@dataclasses.dataclass
-class FleetWaveAttempt:
-    """One scheduled fleet wave attempt, as handed to the executor:
-    which replica lane runs it, which uids it actually serves
-    (``deliver``), and whether its kernels run at all (``execute=False``
-    for timeout aborts and waves lost to a dying replica)."""
-    index: int
-    replica: str
-    model: str
-    requests: list[ZooRequest]
-    faults: ReplicaFaults | None
-    deliver: tuple[int, ...]
-    execute: bool = True
-    shards: tuple[str, ...] = ()   # participants of a cooperative wave
-
-
-# ---------------------------------------------------------------------------
-# per-replica modeled state (scheduler-internal)
-# ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class _ReplicaState:
+    """One replica's modeled pipeline during a drain (scheduler-internal)."""
     rid: str
     index: int
     alive: bool = True
@@ -363,43 +199,33 @@ class _ReplicaState:
         return "suspect" if self.suspect else "alive"
 
 
-class FleetServer:
-    """N data-parallel replicas of the model zoo behind one admission
-    plane: scheduled waves are placed on replicas by a pluggable
-    :class:`PlacementPolicy`, within a replica the zoo's
-    :class:`~repro.serve.zoo.SchedulingPolicy` picks which model's wave
-    dispatches, and a per-replica health plane (heartbeats, straggler
-    monitor, drain + elastic replan) survives replica-granular chaos.
+class ModelZooServer:
+    """Hold several compiled models on ``n_replicas`` replicas, admit a
+    mixed tagged request stream into per-tenant queues, and schedule
+    dual-array waves with a pluggable policy priced by the planner's own
+    cost model (see the module docstring).
 
-    ``serve()`` mirrors :meth:`~repro.serve.zoo.ModelZooServer.serve`:
-    a deterministic modeled-time schedule first (device-count
-    independent), then real execution of every scheduled wave on its
-    replica's lane (per-model ``CNNServer``s pinned round-robin over
-    ``jax.devices()``), with the same host-side integrity guard
-    (:func:`~repro.serve.errors.all_finite`) and bitwise-parity
-    contract."""
+    ``faults`` plugs in a seeded :class:`~repro.serve.faults.FaultPlane`
+    (wave chaos or replica chaos); ``admission``/``recovery`` configure
+    shedding, retry, health and degraded-mode policy.  With
+    ``faults=None`` and the default configs the schedule is the healthy
+    path.  ``devices`` (default ``jax.devices()``) are what replica lanes
+    round-robin over; the modeled schedule never reads them."""
 
     def __init__(self, models: Sequence[ZooModel], *,
-                 n_replicas: int = 2,
+                 n_replicas: int = 1,
                  policy: SchedulingPolicy | None = None,
                  placement: PlacementPolicy | None = None,
-                 faults: ReplicaFaultInjector | None = None,
+                 registry: ScheduleRegistry | None = None,
+                 faults: FaultPlane | None = None,
                  admission: AdmissionConfig | None = None,
                  recovery: RecoveryConfig | None = None,
                  devices: Sequence | None = None,
-                 shard_waves: bool = False,
-                 mesh_model_parallel: int = 1,
-                 mesh_global_batch: int = 64,
-                 mesh_pod_size: int = 64) -> None:
+                 shard_waves: bool = False) -> None:
         if not models:
-            raise ValueError("a fleet needs at least one model")
+            raise ValueError("a zoo needs at least one model")
         if n_replicas < 1:
             raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
-        self.models: dict[str, ZooModel] = {}
-        for m in models:
-            if m.name in self.models:
-                raise ValueError(f"duplicate fleet model {m.name!r}")
-            self.models[m.name] = m
         self.n_replicas = n_replicas
         self.replica_ids = tuple(f"r{i}" for i in range(n_replicas))
         self.policy = policy if policy is not None else FIFOPolicy()
@@ -411,58 +237,70 @@ class FleetServer:
         self.recovery = recovery if recovery is not None \
             else RecoveryConfig()
         self.shard_waves = shard_waves
-        self.mesh_model_parallel = mesh_model_parallel
-        self.mesh_global_batch = mesh_global_batch
-        self.mesh_pod_size = mesh_pod_size
-        self._given_devices = tuple(devices) if devices is not None \
-            else None
-        self._device_list: tuple | None = None
-        self._lanes: dict[str, dict[str, CNNServer]] | None = None
+        # the compiled-schedule registry: one (net, dtype, batch) entry
+        # per model variant at its steady-state wave size
+        self.registry = registry if registry is not None \
+            else ScheduleRegistry()
+        self.models: dict[str, ZooModel] = {}
+        self._fallbacks: dict[str, str | None] = {}
+        for m in models:
+            self.add_model(m)
+        self._devices = tuple(devices) if devices is not None else None
+        self._lanes: dict[str, dict[str, CNNServer]] = {}
         self.tenants: dict[str, list[ZooRequest]] = {}
         self._rejected: list[ZooRequest] = []
         self._uids: set = set()
-        self._exec_uid = 0
         self._attempt_idx = 0
         self._serve_calls = 0      # the telemetry ident of serve() calls
+
+    def add_model(self, m: ZooModel) -> None:
+        """Register one more compiled variant (elastic scale-up — valid
+        between drains too).  Registers its stage schedules and refreshes
+        the degraded-fallback routing table."""
+        if m.name in self.models:
+            raise ValueError(f"duplicate zoo model {m.name!r}")
+        self.models[m.name] = m
+        srv = m.server
+        self.registry.register(
+            m.spec.net, dtype_tag=m.spec.weight_dtype,
+            batch=srv.microbatch, in_res=srv.in_res, in_ch=srv.in_ch,
+            width_mult=srv.width_mult, dtype=srv.dtype,
+            policy=srv.engine.policy, params=srv.params)
+        # degraded-mode routing: fp32 variant -> int8 sibling of the SAME
+        # net at the SAME serving resolution (images are interchangeable)
+        self._fallbacks = {
+            name: next((cand for cand, c in self.models.items()
+                        if cand != name and zm.spec.weight_dtype != "int8"
+                        and c.spec.net == zm.spec.net
+                        and c.spec.weight_dtype == "int8"
+                        and c.server.in_res == zm.server.in_res), None)
+            for name, zm in self.models.items()}
 
     # -- devices / execution lanes (never consulted by the scheduler) -------
     def devices(self) -> tuple:
         """The JAX devices replica lanes round-robin over.  Lazy: the
-        modeled schedule never needs them, so modeled-only fleets never
+        modeled schedule never needs them, so modeled-only drains never
         touch jax."""
-        if self._device_list is None:
-            if self._given_devices is not None:
-                self._device_list = self._given_devices
-            else:
-                import jax
-                self._device_list = tuple(jax.devices())
-        return self._device_list
+        if self._devices is None:
+            import jax
+            self._devices = tuple(jax.devices())
+        return self._devices
 
     def replica_device(self, index: int):
         devs = self.devices()
         return devs[index % len(devs)]
 
     def mesh(self):
-        """A ``jax.sharding.Mesh`` over the fleet's **distinct** replica
-        devices on one ``"data"`` axis — the mesh
-        :func:`~repro.distributed.elastic.replan` proposals shrink.
-        With fewer host devices than replicas the mesh is narrower than
-        the logical fleet (replicas share devices); the modeled schedule
-        is unaffected either way."""
-        from jax.sharding import Mesh
-        distinct = []
-        for i in range(self.n_replicas):
-            d = self.replica_device(i)
-            if d not in distinct:
-                distinct.append(d)
-        return Mesh(np.array(distinct), axis_names=("data",))
+        """A ``jax.sharding.Mesh`` over the replicas' **distinct** devices
+        on one ``"data"`` axis — the mesh
+        :func:`~repro.distributed.elastic.replan` proposals shrink."""
+        return self.shard_mesh(self.replica_ids)
 
     def shard_mesh(self, rids: Sequence[str]):
         """The ``("data",)`` mesh a cooperative wave executes over: the
-        **distinct** devices of the given (healthy) participant
-        replicas.  With fewer host devices than participants the mesh is
-        narrower than the cooperative wave's logical ``data`` degree —
-        as with :meth:`mesh`, the modeled schedule never reads it."""
+        **distinct** devices of the given replicas.  With fewer host
+        devices than replicas the mesh is narrower than the logical
+        ``data`` degree; the modeled schedule never reads it."""
         from jax.sharding import Mesh
         distinct = []
         for rid in rids:
@@ -472,19 +310,21 @@ class FleetServer:
         return Mesh(np.array(distinct), axis_names=("data",))
 
     def _lane(self, rid: str, model: str) -> CNNServer:
-        """The replica's execution lane for one model: a ``CNNServer``
-        whose parameters are committed to the replica's own device, so a
-        wave never copies weights between devices."""
+        """The replica's execution lane for one model: ``ZooModel.server``
+        on the device that holds the model's parameters, elsewhere a
+        ``CNNServer`` whose parameters are committed to the replica's own
+        device, so a wave never copies weights between devices."""
         import jax
 
-        if self._lanes is None:
-            self._lanes = {}
         lane = self._lanes.setdefault(rid, {})
         srv = lane.get(model)
         if srv is None:
             m = self.models[model]
             device = self.replica_device(self.replica_ids.index(rid))
-            srv = lane[model] = CNNServer(
+            leaf = jax.tree.leaves(m.params)[0]
+            home = next(iter(leaf.devices())) \
+                if isinstance(leaf, jax.Array) else jax.devices()[0]
+            srv = lane[model] = m.server if device == home else CNNServer(
                 m.spec.net, jax.device_put(m.params, device),
                 in_res=m.server.in_res, width_mult=m.server.width_mult,
                 max_batch=m.server.max_batch)
@@ -509,19 +349,22 @@ class FleetServer:
 
     # -- admission ----------------------------------------------------------
     def submit(self, req: ZooRequest) -> bool:
-        """Admit one tagged request — the zoo's submit contract: unknown
-        models and duplicate uids raise; a stale deadline is shed with a
-        typed result and ``False`` returns."""
+        """Admit one tagged request into its tenant's queue; returns
+        ``True`` if queued.  Unknown model names and duplicate uids raise
+        (caller bugs, the registry's lookup contract).  A deadline
+        already in the past at arrival is a *policy* rejection: the
+        request is shed immediately with a typed
+        :class:`~repro.serve.errors.StaleDeadlineError` result (it still
+        appears, accounted, in the next report) and ``False`` returns."""
         if req.model not in self.models:
-            raise KeyError(f"unknown fleet model {req.model!r}; "
+            raise KeyError(f"unknown zoo model {req.model!r}; "
                            f"serving: {tuple(self.models)}")
         if req.uid in self._uids:
             raise ValueError(f"duplicate request uid {req.uid}: uids are "
-                             "unique per fleet lifetime")
+                             "unique per zoo lifetime")
         self._uids.add(req.uid)
         if req.deadline_s is not None and req.deadline_s <= req.arrival_s:
-            req.status = "shed"
-            req.error = StaleDeadlineError(
+            req.status, req.error = "shed", StaleDeadlineError(
                 f"deadline {req.deadline_s:.6f}s already past at arrival "
                 f"{req.arrival_s:.6f}s", uid=req.uid, model=req.model)
             self._rejected.append(req)
@@ -554,19 +397,34 @@ class FleetServer:
                             max(0.0, st.conv_free - now))
                 for st in states]
 
+    def _route(self, req: ZooRequest,
+               health: dict[str, ModelHealth]) -> tuple[str, str | None]:
+        """Health-based routing: a request for a *failed* variant drains
+        to its int8 sibling when eligible.  Returns (route, reason)."""
+        primary = req.model
+        if health[primary].state != "failed":
+            return primary, None
+        alt = self._fallbacks.get(primary)
+        if (alt is not None and self.recovery.allow_degraded
+                and req.allow_degraded
+                and health[alt].state != "failed"):
+            return alt, f"{primary} failed -> int8 fallback {alt}"
+        return primary, None
+
     def _backoff(self, retries: int) -> float:
         rec = self.recovery
         return min(rec.backoff_cap_s,
                    rec.backoff_s * rec.backoff_mult ** (retries - 1))
 
     # -- scheduling (deterministic modeled time, device-count independent) --
-    def _schedule(self, requests: list[ZooRequest]
-                  ) -> tuple[list[FleetWaveDecision],
-                             list[FleetWaveAttempt], list[FleetEvent],
-                             dict[str, _ReplicaState],
-                             list[tuple[float, int, int, str]]]:
-        adm, rec = self.admission, self.recovery
-        inj = self.faults
+    def _schedule(self, requests: list[ZooRequest]):
+        """The modeled-time simulation: admit, place, pick waves whenever
+        a replica's SA-CONV array frees, consult the fault plane once per
+        wave attempt, and drive retry / quarantine / health / drain /
+        degradation off the outcomes.  Stamps every request's terminal
+        status; a pure function of the request list, the configs and the
+        chaos plan."""
+        adm, rec, inj = self.admission, self.recovery, self.faults
         undisp = sorted(requests, key=lambda r: (r.arrival_s, r.uid))
         states: dict[str, _ReplicaState] = {
             rid: _ReplicaState(rid, idx,
@@ -575,16 +433,31 @@ class FleetServer:
         tenant_depth: dict[str, int] = {}
         resharded: dict[int, str] = {}   # uid -> survivor pinned by reshard
         retry_heap: list[tuple[float, int, ZooRequest]] = []
-        decisions: list[FleetWaveDecision] = []
-        attempts: list[FleetWaveAttempt] = []
-        events: list[FleetEvent] = []
-        mesh_plans: list[tuple[float, int, int, str]] = []
-        beats = HeartbeatTracker(list(self.replica_ids),
-                                 timeout=rec.heartbeat_timeout_s, now=0.0)
-        monitors = {rid: StepMonitor(factor=rec.straggler_factor,
-                                     warmup=rec.straggler_warmup,
-                                     window=rec.straggler_window)
-                    for rid in self.replica_ids}
+        decisions: list[WaveDecision] = []
+        attempts: list[WaveAttempt] = []
+        events: list[FaultEvent] = []
+        health = {m: ModelHealth(m) for m in self.models}
+
+        monitors: dict[str, StepMonitor] = {}   # by the model or replica
+
+        def straggler(blamed: str, attempt: int, stall: float) -> bool:
+            """Feed the blamed model's or replica's straggler monitor."""
+            mon = monitors.get(blamed)
+            if mon is None:
+                mon = monitors[blamed] = StepMonitor(
+                    factor=rec.straggler_factor,
+                    warmup=rec.straggler_warmup,
+                    window=rec.straggler_window)
+            return mon.observe(attempt, stall) == "straggler"
+        # a model with pending work whose waves stopped completing times
+        # out, where the fault plane can fail models at all
+        watch_models = inj is not None and inj.blames == "model"
+        model_beats = HeartbeatTracker(list(self.models),
+                                       timeout=rec.heartbeat_timeout_s,
+                                       now=0.0)
+        replica_beats = HeartbeatTracker(list(self.replica_ids),
+                                         timeout=rec.heartbeat_timeout_s,
+                                         now=0.0)
         kills: dict[str, float] = {}
         partitions: list[tuple[str, float, float]] = []
         if inj is not None:
@@ -592,73 +465,82 @@ class FleetServer:
                 t_kill = inj.kill_time(rid)
                 if t_kill is not None:
                     kills[rid] = t_kill
-                for s, e in inj.partition_windows(rid):
-                    partitions.append((rid, s, e))
+                partitions.extend((rid, s, e)
+                                  for s, e in inj.partition_windows(rid))
         part_done = [False] * len(partitions)
+        mesh_plans = [(0.0, replan(
+            self.n_replicas, model_parallel=MESH_MODEL_PARALLEL,
+            global_batch=MESH_GLOBAL_BATCH, pod_size=MESH_POD_SIZE).data,
+            0, "initial")]
         now = 0.0
         i, n = 0, len(undisp)
         terminal = 0
-        seq = 0
+        seq = 0                          # retry-heap tiebreak
 
         def partitioned(rid: str, t: float) -> bool:
             return inj is not None and inj.partitioned(rid, t)
 
-        def candidates_for_place() -> list[_ReplicaState]:
-            usable = [st for st in states.values() if st.usable()]
-            if usable:
-                return usable
-            # every live replica is suspect: a drained fleet beats a
-            # wedged one — fall back to suspects rather than dropping
-            return [st for st in states.values() if st.alive]
+        def health_event(t: float, model: str, new: str | None,
+                         why: str) -> None:
+            if new is not None:
+                events.append(FaultEvent(t, -1, model, "health",
+                                         f"-> {new} ({why})"))
 
-        def place(r: ZooRequest, t: float) -> str | None:
-            """Route ``r`` onto a replica queue; None = nowhere left.
-            A request whose sharded wave was aborted mid-flight carries a
-            :func:`~repro.distributed.elastic.reshard_wave` pin — honor
-            it while that survivor is usable (re-sharding moves in-flight
-            state deterministically; free placement is the fallback)."""
+        def place(r: ZooRequest, route: str, t: float) -> str | None:
+            """Queue ``r`` for variant ``route`` on a replica; None =
+            nowhere left.  A request whose cooperative wave was aborted
+            mid-flight carries a :func:`~repro.distributed.elastic
+            .reshard_wave` pin — honor it while that survivor is usable;
+            free placement is the fallback."""
             pinned = resharded.pop(r.uid, None)
             if pinned is not None and states[pinned].usable():
                 st = states[pinned]
-                r.replica = pinned
-                r.served_by = r.model
-                st.pending[r.model].append(r)
-                tenant_depth[r.tenant] = tenant_depth.get(r.tenant, 0) + 1
-                return pinned
-            cands = candidates_for_place()
-            if not cands:
-                return None
-            rid = self.placement.place(t, self._views(t, cands), r)
-            st = states[rid]
-            r.replica = rid
-            r.served_by = r.model
-            st.pending[r.model].append(r)
+            else:
+                # every live replica suspect: a drained fleet beats a
+                # wedged one — fall back to suspects rather than dropping
+                cands = [s for s in states.values() if s.usable()] \
+                    or [s for s in states.values() if s.alive]
+                if len(cands) > 1:
+                    cands = [states[self.placement.place(
+                        t, self._views(t, cands), r)]]
+                if not cands:
+                    return None
+                st = cands[0]
+            r.replica, r.served_by = st.rid, route
+            st.pending[route].append(r)
             tenant_depth[r.tenant] = tenant_depth.get(r.tenant, 0) + 1
-            return rid
+            return st.rid
+
+        def shed(r: ZooRequest, t: float, why: str, detail: str) -> None:
+            nonlocal terminal
+            r.status, r.error = "shed", RequestShedError(
+                detail, uid=r.uid, model=r.model)
+            events.append(FaultEvent(t, -1, r.model, "shed", why,
+                                     uids=(r.uid,)))
+            terminal += 1
 
         def quarantine_lost(r: ZooRequest, t: float, why: str) -> None:
             nonlocal terminal
-            r.status = "quarantined"
-            r.error = ReplicaLostError(why, uid=r.uid, model=r.model,
-                                       replica=r.replica or "")
-            events.append(FleetEvent(t, r.replica or "-", "quarantine",
-                                     why, uids=(r.uid,)))
+            r.status, r.error = "quarantined", ReplicaLostError(
+                why, uid=r.uid, model=r.model, replica=r.replica or "")
+            events.append(FaultEvent(t, -1, r.model, "quarantine", why,
+                                     uids=(r.uid,),
+                                     replica=r.replica or "-"))
             terminal += 1
 
         def do_replan(t: float, why: str) -> None:
             alive = sum(st.usable() for st in states.values())
             try:
-                plan = replan(alive,
-                              model_parallel=self.mesh_model_parallel,
-                              global_batch=self.mesh_global_batch,
-                              pod_size=self.mesh_pod_size)
+                plan = replan(alive, model_parallel=MESH_MODEL_PARALLEL,
+                              global_batch=MESH_GLOBAL_BATCH,
+                              pod_size=MESH_POD_SIZE)
             except InsufficientReplicasError as e:
-                events.append(FleetEvent(t, "-", "replan_failed",
+                events.append(FaultEvent(t, -1, "", "replan_failed",
                                          f"{why}: {e.message}"))
                 return
             mesh_plans.append((t, plan.data, plan.wasted_chips, why))
-            events.append(FleetEvent(
-                t, "-", "replan",
+            events.append(FaultEvent(
+                t, -1, "", "replan",
                 f"{why}: {alive} usable -> data={plan.data} "
                 f"wasted={plan.wasted_chips}"))
 
@@ -670,92 +552,120 @@ class FleetServer:
                 for r in moved:
                     tenant_depth[r.tenant] -= 1
                     st.drained_away += 1
-                    new_rid = place(r, t)
+                    new_rid = place(r, model, t)
                     if new_rid is None:
                         quarantine_lost(
                             r, t, f"{why}; no surviving replica to "
                             "drain to")
                     else:
-                        events.append(FleetEvent(
-                            t, st.rid, "drain",
+                        events.append(FaultEvent(
+                            t, -1, model, "drain",
                             f"{why}: queued request -> {new_rid}",
-                            uids=(r.uid,), model=model))
+                            uids=(r.uid,), replica=st.rid))
 
         def fire_kill(rid: str, t: float) -> None:
             st = states[rid]
             del kills[rid]
-            st.alive = False
-            st.suspect = False
-            events.append(FleetEvent(t, rid, "kill", "replica died"))
-            beats.deregister(rid)        # stop tripping liveness forever
+            st.alive = st.suspect = False
+            events.append(FaultEvent(t, -1, "", "kill", "replica died",
+                                     replica=rid))
+            replica_beats.deregister(rid)   # stop tripping liveness forever
             drain_queue(st, t, f"replica {rid} died")
             do_replan(t, f"{rid} dead")
 
         def fail_wave(wave: list[ZooRequest], rid: str, model: str,
                       t: float, kind: str, attempt: int) -> None:
-            """Retry-or-quarantine a failed attempt's requests — the
-            zoo's recovery discipline with fleet-typed terminal errors."""
+            """Retry-or-quarantine every request of a failed attempt."""
             nonlocal terminal, seq
             for r in wave:
                 r.retries += 1
                 if r.retries > rec.max_retries:
                     err_cls = {"timeout": WaveTimeoutError,
+                               "corrupt": CorruptOutputError,
                                "replica_dead": ReplicaLostError}.get(
                                    kind, ServeError)
                     kw = {"replica": rid} \
                         if err_cls is ReplicaLostError else {}
-                    r.status = "quarantined"
-                    r.error = err_cls(
+                    r.status, r.error = "quarantined", err_cls(
                         f"wave {kind} x{r.retries} attempts (retry "
                         f"budget {rec.max_retries} spent)", uid=r.uid,
                         model=model, **kw)
-                    events.append(FleetEvent(
-                        t, rid, "quarantine",
+                    events.append(FaultEvent(
+                        t, attempt, model, "quarantine",
                         f"{kind} after {r.retries} attempts",
-                        uids=(r.uid,), attempt=attempt, model=model))
+                        uids=(r.uid,), replica=rid))
                     terminal += 1
                 else:
                     delay = self._backoff(r.retries)
                     seq += 1
                     heapq.heappush(retry_heap, (t + delay, seq, r))
-                    events.append(FleetEvent(
-                        t, rid, "retry",
+                    events.append(FaultEvent(
+                        t, attempt, model, "retry",
                         f"{kind}; backoff {delay * 1e6:.0f}us",
-                        uids=(r.uid,), attempt=attempt, model=model))
+                        uids=(r.uid,), replica=rid))
 
         def admit(r: ZooRequest, t: float) -> None:
-            nonlocal terminal
+            """Admission control at the request's admission instant."""
+            route = r.model
             if adm.max_queue is not None \
                     and tenant_depth.get(r.tenant, 0) >= adm.max_queue:
-                r.status = "shed"
-                r.error = RequestShedError(
-                    f"tenant {r.tenant!r} queue full "
-                    f"({adm.max_queue} pending)", uid=r.uid, model=r.model)
-                events.append(FleetEvent(t, "-", "shed",
-                                         f"queue full (tenant {r.tenant})",
-                                         uids=(r.uid,), model=r.model))
-                terminal += 1
+                shed(r, t, f"queue full (tenant {r.tenant})",
+                     f"tenant {r.tenant!r} queue full "
+                     f"({adm.max_queue} pending)")
                 return
             if r.deadline_s is not None and adm.predictive_shedding:
-                best = t + self.models[r.model].wave_cost(1).total_s
+                # best case: dispatched immediately, solo wave — if even
+                # that misses, scheduling it can only waste array time
+                best = t + self.models[route].wave_cost(1).total_s
                 if best > r.deadline_s:
-                    r.status = "shed"
-                    r.error = RequestShedError(
-                        f"cost model predicts deadline miss: best-case "
-                        f"finish {best:.6f}s > deadline "
-                        f"{r.deadline_s:.6f}s", uid=r.uid, model=r.model)
-                    events.append(FleetEvent(
-                        t, "-", "shed", "predicted deadline miss",
-                        uids=(r.uid,), model=r.model))
-                    terminal += 1
-                    return
-            if place(r, t) is None:
+                    alt = self._fallbacks.get(route)
+                    if (alt is not None and rec.allow_degraded
+                            and r.allow_degraded
+                            and health[alt].state != "failed"
+                            and t + self.models[alt].wave_cost(1).total_s
+                            <= r.deadline_s):
+                        events.append(FaultEvent(
+                            t, -1, route, "degrade",
+                            f"predicted miss on {route} -> {alt}",
+                            uids=(r.uid,)))
+                        route = alt
+                    else:
+                        shed(r, t, "predicted deadline miss",
+                             f"cost model predicts deadline miss: "
+                             f"best-case finish {best:.6f}s > deadline "
+                             f"{r.deadline_s:.6f}s")
+                        return
+            if route == r.model:
+                route, why = self._route(r, health)
+                if why is not None:
+                    events.append(FaultEvent(t, -1, r.model, "degrade",
+                                             why, uids=(r.uid,)))
+            if place(r, route, t) is None:
                 quarantine_lost(r, t, "no surviving replica at admission")
 
-        mesh_plans.append((0.0, replan(
-            self.n_replicas, model_parallel=self.mesh_model_parallel,
-            global_batch=self.mesh_global_batch,
-            pod_size=self.mesh_pod_size).data, 0, "initial"))
+        def admit_due(strict: bool) -> None:
+            """Admit, at ``now``, the arrivals and then the retries due
+            before it (``strict``) or at it."""
+            nonlocal i
+            while i < n and (undisp[i].arrival_s < now or not strict
+                             and undisp[i].arrival_s == now):
+                admit(undisp[i], now)
+                i += 1
+            while retry_heap and (retry_heap[0][0] < now or not strict
+                                  and retry_heap[0][0] == now):
+                _, _, r = heapq.heappop(retry_heap)
+                route, why = self._route(r, health)
+                if why is not None:
+                    events.append(FaultEvent(now, -1, r.model, "degrade",
+                                             why, uids=(r.uid,)))
+                if place(r, route, now) is None:
+                    quarantine_lost(r, now, "no surviving replica for retry")
+
+        def usable_free() -> float:
+            """The earliest instant a usable replica's conv array frees:
+            requests are admitted no earlier (0 when none is usable)."""
+            return min((st.conv_free for st in states.values()
+                        if st.usable()), default=0.0)
 
         guard = 0
         max_iters = (128 + 16 * n * (rec.max_retries + 2)
@@ -764,77 +674,82 @@ class FleetServer:
             guard += 1
             if guard > max_iters:          # never wedge, even on a bug
                 raise ServeError(
-                    f"fleet scheduler exceeded {max_iters} iterations "
-                    f"with {n - terminal} request(s) unresolved — "
-                    "scheduling invariant broken")
+                    f"scheduler exceeded {max_iters} iterations with "
+                    f"{n - terminal} request(s) unresolved — scheduling "
+                    "invariant broken")
             # -- next modeled instant anything can happen -------------------
-            nxt: list[float] = []
-            for st in states.values():
-                if st.usable() and st.pending_n():
-                    nxt.append(st.conv_free)
+            t_free = usable_free()
+            nxt = [st.conv_free for st in states.values()
+                   if st.usable() and st.pending_n()]
             if i < n:
-                nxt.append(undisp[i].arrival_s)
+                nxt.append(max(undisp[i].arrival_s, t_free))
             if retry_heap:
-                nxt.append(retry_heap[0][0])
-            for t_kill in kills.values():
-                nxt.append(t_kill)
+                nxt.append(max(retry_heap[0][0], t_free))
+            nxt.extend(kills.values())
             for w, (rid, s, e) in enumerate(partitions):
                 if part_done[w] or not states[rid].alive:
                     continue
                 if e <= now:
                     part_done[w] = True
                     continue
-                for t in (s, s + rec.heartbeat_timeout_s, e):
-                    if t > now:
-                        nxt.append(t)
+                nxt.extend(t for t in (s, s + rec.heartbeat_timeout_s, e)
+                           if t > now)
             if not nxt:
                 # nothing can ever happen again: quarantine the rest
                 # (defensive — the drain paths should already have)
                 for _, _, r in sorted(retry_heap):
-                    if r.status == "pending":
-                        quarantine_lost(r, now,
-                                        "fleet idle with no live replica")
+                    quarantine_lost(r, now,
+                                    "idle with no live replica")
                 retry_heap.clear()
                 while i < n:
                     admit(undisp[i], max(now, undisp[i].arrival_s))
                     i += 1
                 continue
             now = max(now, min(nxt))
-            # -- replica deaths ---------------------------------------------
-            for rid in [rid for rid, t in kills.items() if t <= now]:
+            # -- admission happens at max(t, earliest usable conv_free),
+            # but never after a replica transition: what is due before a
+            # death is placed ahead of it, what is due at a suspect or a
+            # rejoin ahead of that, as a placement on arrival would be ----
+            dying = [rid for rid, t in kills.items() if t <= now]
+            if dying:
+                admit_due(strict=True)
+            for rid in dying:
                 fire_kill(rid, kills[rid])
-            # -- arrivals / retries -----------------------------------------
-            while i < n and undisp[i].arrival_s <= now:
-                admit(undisp[i], undisp[i].arrival_s)
-                i += 1
-            while retry_heap and retry_heap[0][0] <= now:
-                t_r, _, r = heapq.heappop(retry_heap)
-                if place(r, t_r) is None:
-                    quarantine_lost(r, t_r,
-                                    "no surviving replica for retry")
-            # -- heartbeats: every live replica beats unless partitioned ----
-            for st in states.values():
-                if st.alive and not partitioned(st.rid, now):
-                    beats.beat(st.rid, now)
-            failed_now = beats.failed(now)
-            for rid in failed_now:
-                st = states[rid]
-                if st.alive and not st.suspect:
-                    st.suspect = True
-                    events.append(FleetEvent(
-                        now, rid, "suspect",
-                        f"no heartbeat for > "
-                        f"{rec.heartbeat_timeout_s * 1e6:.0f}us "
-                        "(partitioned?)"))
-                    drain_queue(st, now, f"replica {rid} suspected")
-                    do_replan(now, f"{rid} suspect")
-            for st in states.values():
-                if st.alive and st.suspect and st.rid not in failed_now:
-                    st.suspect = False
-                    events.append(FleetEvent(
-                        now, st.rid, "rejoin",
-                        "heartbeats resumed; replica back in rotation"))
-                    do_replan(now, f"{st.rid} rejoined")
+            suspects, rejoins = [], []
+            if partitions:     # only a dropped heartbeat makes a suspect
+                for st in states.values():
+                    if st.alive and not partitioned(st.rid, now):
+                        replica_beats.beat(st.rid, now)
+                failed_now = replica_beats.failed(now)
+                suspects = [states[rid] for rid in failed_now
+                            if states[rid].alive and not states[rid].suspect]
+                rejoins = [st for st in states.values() if st.alive
+                           and st.suspect and st.rid not in failed_now]
+            if suspects or rejoins or usable_free() <= now:
+                admit_due(strict=False)
+            for st in suspects:
+                st.suspect = True
+                events.append(FaultEvent(
+                    now, -1, "", "suspect",
+                    f"no heartbeat for > "
+                    f"{rec.heartbeat_timeout_s * 1e6:.0f}us "
+                    "(partitioned?)", replica=st.rid))
+                drain_queue(st, now, f"replica {st.rid} suspected")
+                do_replan(now, f"{st.rid} suspect")
+            for st in rejoins:
+                st.suspect = False
+                events.append(FaultEvent(
+                    now, -1, "", "rejoin",
+                    "heartbeats resumed; replica back in rotation",
+                    replica=st.rid))
+                do_replan(now, f"{st.rid} rejoined")
+            if watch_models:
+                for m in self.models:
+                    if not any(st.pending[m] for st in states.values()):
+                        model_beats.beat(m, now)
+                for m in model_beats.failed(now):
+                    health_event(now, m, health[m].force_failed(),
+                                 "heartbeat timeout")
             # -- dispatch one wave ------------------------------------------
             ready = [st for st in states.values()
                      if st.usable() and st.pending_n()
@@ -844,264 +759,205 @@ class FleetServer:
             st = min(ready, key=lambda s: (s.conv_free, s.index))
             rid = st.rid
             cands = {m: q for m, q in st.pending.items() if q}
+            depths = tuple(sorted((m, len(q)) for m, q in cands.items()))
             chosen = self.policy.pick(now, cands, self._cost)
             zm = self.models[chosen]
-            queue = self.policy.wave_order(st.pending[chosen])
-
-            # -- cooperative sharded wave (the shard_waves lane) ------------
-            # The fleet-wide queue of the chosen model exceeding one
-            # replica's planner micro-batch is the modeled crossover
-            # trigger (perf_model.fleet_shard_crossover_batch breaks
-            # even one row past a full microbatch wave): instead of
-            # fanning independent per-replica waves, cut ONE wave of up
-            # to data x bb rows from every free healthy replica's queue
-            # and run it across the mesh.  Below data=2 the lane
-            # degrades to the per-replica path with a typed event,
-            # never an error.
-            merged: list[ZooRequest] = []
-            participants: list[_ReplicaState] = []
+            participants, wave = [st], None
+            # Cooperative sharded wave: the free usable replicas' queue of
+            # the chosen model past one planner micro-batch is the modeled
+            # crossover trigger (perf_model.fleet_shard_crossover_batch
+            # breaks even one row past a full micro-batch wave): cut ONE
+            # wave of up to data x bb rows and run it across the mesh.
+            # Below data=2 it falls back to the one-replica wave.
             if self.shard_waves:
-                participants = sorted(
-                    (s for s in states.values()
-                     if s.usable() and s.conv_free <= now),
-                    key=lambda s: s.index)
+                free = sorted((s for s in states.values()
+                               if s.usable() and s.conv_free <= now),
+                              key=lambda s: s.index)
                 merged = self.policy.wave_order(
-                    [r for p in participants for r in p.pending[chosen]])
-            if self.shard_waves and len(merged) > zm.microbatch:
-                if len(participants) < 2:
-                    events.append(FleetEvent(
-                        now, rid, "shard_fallback",
-                        "mesh below data=2 "
-                        f"({len(participants)} usable replica(s) free); "
-                        "cooperative wave falls back to the per-replica "
-                        "lane", model=chosen))
-                else:
-                    shard_rids = tuple(s.rid for s in participants)
-                    data = len(participants)
-                    cut = zm.sharded_microbatch(data)
-                    wave = merged[:cut]
-                    cut_ids = {id(r) for r in wave}
-                    for p in participants:
-                        p.pending[chosen] = [
-                            r for r in p.pending[chosen]
-                            if id(r) not in cut_ids]
-                    for r in wave:
-                        tenant_depth[r.tenant] -= 1
-                    cost = zm.sharded_wave_cost(len(wave),
-                                                data).as_wave_cost()
-                    attempt = self._attempt_idx
-                    self._attempt_idx += 1
-                    faults = inj.wave_faults(st.index, attempt) \
-                        if inj is not None else None
-                    kind = faults.kind if faults is not None else "none"
-                    uids = tuple(r.uid for r in wave)
-                    stall = faults.stall_factor if kind == "stall" else 1.0
-                    timed_out = stall >= rec.wave_timeout_factor
-                    eff = cost.scaled(min(stall,
-                                          rec.wave_timeout_factor)) \
-                        if stall != 1.0 else cost
-                    conv_done = now + eff.conv_s
-                    fc_start = max(conv_done,
-                                   max(p.fc_free for p in participants))
-                    fc_done = fc_start + eff.fc_s
-
-                    victims = [(kills[p.rid], p.rid) for p in participants
-                               if p.rid in kills
-                               and now < kills[p.rid] <= fc_done]
-                    if victims:
-                        # a participant dies mid-wave: abort the whole
-                        # cooperative wave, re-shard its rows over the
-                        # survivors, retry with backoff
-                        t_kill, dead_rid = min(victims)
-                        events.append(FleetEvent(
-                            t_kill, dead_rid, "shard_abort",
-                            f"participant {dead_rid} died mid-wave; "
-                            f"cooperative data={data} wave aborted",
-                            uids=uids, attempt=attempt, model=chosen))
-                        decisions.append(FleetWaveDecision(
-                            index=len(decisions), t_s=now, replica=rid,
-                            model=chosen, uids=uids, batch=len(wave),
-                            conv_s=eff.conv_s, fc_s=eff.fc_s,
-                            fault="replica_dead", stall_factor=stall,
-                            shards=shard_rids))
-                        attempts.append(FleetWaveAttempt(
-                            attempt, rid, chosen, list(wave), faults,
-                            deliver=(), execute=False,
-                            shards=shard_rids))
-                        for p in participants:
-                            p.waves += 1
-                        fire_kill(dead_rid, t_kill)
-                        survivors = [p.rid for p in participants
-                                     if states[p.rid].usable()]
-                        try:
-                            asg = reshard_wave(uids, survivors)
-                        except InsufficientReplicasError as e:
-                            events.append(FleetEvent(
-                                t_kill, "-", "replan_failed",
-                                f"reshard: {e.message}", uids=uids,
-                                attempt=attempt, model=chosen))
-                        else:
-                            resharded.update(
-                                {u: r for r, us in asg.assignment
-                                 for u in us})
-                            events.append(FleetEvent(
-                                t_kill, dead_rid, "reshard",
-                                "in-flight wave re-sharded over "
-                                f"data={asg.data}: " + " ".join(
-                                    f"{r}x{len(us)}"
-                                    for r, us in asg.assignment),
-                                uids=uids, attempt=attempt,
-                                model=chosen))
-                        fail_wave(wave, dead_rid, chosen, t_kill,
-                                  "replica_dead", attempt)
-                        continue
-
-                    # the cooperative wave occupies every participant
-                    for p in participants:
-                        p.conv_free = max(conv_done, fc_start)
-                        p.fc_free = fc_done
-                        p.busy_s += eff.total_s
-                        p.waves += 1
-
-                    if timed_out:
-                        events.append(FleetEvent(
-                            now, rid, "timeout",
-                            f"stall x{stall:g} >= timeout factor "
-                            f"{rec.wave_timeout_factor:g}, sharded "
-                            "wave aborted", uids=uids, attempt=attempt,
-                            model=chosen))
-                        decisions.append(FleetWaveDecision(
-                            index=len(decisions), t_s=now, replica=rid,
-                            model=chosen, uids=uids, batch=len(wave),
-                            conv_s=eff.conv_s, fc_s=eff.fc_s,
-                            fault="timeout", stall_factor=stall,
-                            shards=shard_rids))
-                        attempts.append(FleetWaveAttempt(
-                            attempt, rid, chosen, list(wave), faults,
-                            deliver=(), execute=False,
-                            shards=shard_rids))
-                        fail_wave(wave, rid, chosen, fc_done,
-                                  "timeout", attempt)
-                        continue
-
-                    for p in participants:
-                        if not partitioned(p.rid, fc_done):
-                            beats.beat(p.rid, fc_done)
-                    verdict = monitors[rid].observe(attempt, stall)
-                    if verdict == "straggler":
-                        events.append(FleetEvent(
-                            fc_done, rid, "stall",
-                            f"straggler verdict: x{stall:g} modeled "
-                            "sharded wave time", uids=uids,
-                            attempt=attempt, model=chosen))
-                    for r in wave:
-                        r.dispatch_s, r.finish_s = now, fc_done
-                        r.status = "served"
-                        r.replica = rid
-                    terminal += len(wave)
-                    decisions.append(FleetWaveDecision(
-                        index=len(decisions), t_s=now, replica=rid,
-                        model=chosen, uids=uids, batch=len(wave),
-                        conv_s=eff.conv_s, fc_s=eff.fc_s, fault=kind,
-                        stall_factor=stall, shards=shard_rids))
-                    attempts.append(FleetWaveAttempt(
-                        attempt, rid, chosen, list(wave), faults,
-                        deliver=uids, shards=shard_rids))
-                    continue
-
-            wave, rest = queue[:zm.microbatch], queue[zm.microbatch:]
-            st.pending[chosen] = rest
+                    [r for p in free for r in p.pending[chosen]])
+                if len(merged) > zm.microbatch and len(free) < 2:
+                    events.append(FaultEvent(
+                        now, -1, chosen, "shard_fallback",
+                        f"mesh below data=2 ({len(free)} usable "
+                        "replica(s) free); cooperative wave falls back "
+                        "to the per-replica lane", replica=rid))
+                elif len(merged) > zm.microbatch:
+                    participants = free
+                    wave = merged[:zm.sharded_microbatch(len(free))]
+                    cut = {id(r) for r in wave}
+                    for p in free:
+                        p.pending[chosen] = [r for r in p.pending[chosen]
+                                             if id(r) not in cut]
+            if wave is None:
+                queue = self.policy.wave_order(st.pending[chosen])
+                wave, st.pending[chosen] = \
+                    queue[:zm.microbatch], queue[zm.microbatch:]
             for r in wave:
                 tenant_depth[r.tenant] -= 1
-            cost = zm.wave_cost(len(wave))
+            shards = tuple(p.rid for p in participants) \
+                if len(participants) > 1 else ()
+            cost = zm.sharded_wave_cost(len(wave), len(shards)) \
+                .as_wave_cost() if shards else zm.wave_cost(len(wave))
             attempt = self._attempt_idx
             self._attempt_idx += 1
-            faults: ReplicaFaults | None = None
-            if inj is not None:
-                faults = inj.wave_faults(st.index, attempt)
-            kind = faults.kind if faults is not None else "none"
+            verdict = inj.verdict(st.index, attempt, len(wave)) \
+                if inj is not None else None
+            kind = verdict.kind if verdict is not None else "none"
+            on_model = verdict is not None and verdict.blames == "model"
             uids = tuple(r.uid for r in wave)
-            stall = faults.stall_factor if kind == "stall" else 1.0
+            stall = verdict.stall_factor if kind == "stall" else 1.0
             timed_out = stall >= rec.wave_timeout_factor
             eff = cost.scaled(min(stall, rec.wave_timeout_factor)) \
                 if stall != 1.0 else cost
+            if kind == "dispatch":
+                # a transient PlanError at dispatch: no array occupied
+                eff = dataclasses.replace(eff, conv_s=0.0, fc_s=0.0)
+
+            def decide(fault: str) -> None:
+                decisions.append(WaveDecision(
+                    index=len(decisions), t_s=now, model=chosen,
+                    uids=uids, batch=len(wave), conv_s=eff.conv_s,
+                    fc_s=eff.fc_s, queue_depths=depths, fault=fault,
+                    stall_factor=stall, replica=rid, shards=shards))
+
+            def lost(at: float, why: str) -> None:
+                attempts.append(WaveAttempt(
+                    attempt, chosen, list(wave), verdict, deliver=(),
+                    execute=False, replica=rid, shards=shards))
+                fail_wave(wave, rid, chosen, at, why, attempt)
+                if on_model:
+                    health_event(at, chosen,
+                                 health[chosen].on_failure(rec), why)
+
+            if kind == "dispatch":
+                events.append(FaultEvent(now, attempt, chosen, "dispatch",
+                                         "injected transient dispatch "
+                                         "failure", uids, replica=rid))
+                decide("dispatch")
+                lost(now, "dispatch")
+                continue
             conv_done = now + eff.conv_s
-            fc_start = max(conv_done, st.fc_free)
+            fc_start = max(conv_done, max(p.fc_free for p in participants))
             fc_done = fc_start + eff.fc_s
 
-            t_kill = kills.get(rid)
-            if t_kill is not None and now < t_kill <= fc_done:
-                # the replica dies mid-wave: the wave is lost with it
-                events.append(FleetEvent(
-                    t_kill, rid, "replica_dead",
+            victims = sorted((kills[p.rid], p.rid) for p in participants
+                             if p.rid in kills
+                             and now < kills[p.rid] <= fc_done)
+            if victims:
+                # a replica dies mid-wave: the wave is lost with it (a
+                # cooperative wave aborts whole and re-shards its rows
+                # over the survivors), and its requests retry
+                t_kill, dead = victims[0]
+                events.append(FaultEvent(
+                    t_kill, attempt, chosen,
+                    "shard_abort" if shards else "replica_dead",
+                    f"participant {dead} died mid-wave; cooperative "
+                    f"data={len(shards)} wave aborted" if shards else
                     "replica died mid-wave; in-flight wave lost",
-                    uids=uids, attempt=attempt, model=chosen))
-                decisions.append(FleetWaveDecision(
-                    index=len(decisions), t_s=now, replica=rid,
-                    model=chosen, uids=uids, batch=len(wave),
-                    conv_s=eff.conv_s, fc_s=eff.fc_s,
-                    fault="replica_dead", stall_factor=stall))
-                attempts.append(FleetWaveAttempt(
-                    attempt, rid, chosen, list(wave), faults,
-                    deliver=(), execute=False))
-                st.waves += 1
-                fire_kill(rid, t_kill)
-                fail_wave(wave, rid, chosen, t_kill, "replica_dead",
+                    uids, replica=dead))
+                decide("replica_dead")
+                attempts.append(WaveAttempt(
+                    attempt, chosen, list(wave), verdict, deliver=(),
+                    execute=False, replica=rid, shards=shards))
+                for p in participants:
+                    p.waves += 1
+                fire_kill(dead, t_kill)
+                if shards:
+                    survivors = [p.rid for p in participants
+                                 if states[p.rid].usable()]
+                    try:
+                        asg = reshard_wave(uids, survivors)
+                    except InsufficientReplicasError as e:
+                        events.append(FaultEvent(
+                            t_kill, attempt, chosen, "replan_failed",
+                            f"reshard: {e.message}", uids))
+                    else:
+                        resharded.update({u: r for r, us in asg.assignment
+                                          for u in us})
+                        events.append(FaultEvent(
+                            t_kill, attempt, chosen, "reshard",
+                            "in-flight wave re-sharded over "
+                            f"data={asg.data}: " + " ".join(
+                                f"{r}x{len(us)}" for r, us in
+                                asg.assignment), uids, replica=dead))
+                fail_wave(wave, dead, chosen, t_kill, "replica_dead",
                           attempt)
                 continue
 
-            # the wave runs to completion (cleanly, late, or aborted)
-            st.conv_free = max(conv_done, fc_start)
-            st.fc_free = fc_done
-            st.busy_s += eff.total_s
-            st.waves += 1
+            # one-deep stage buffer, like the pipelined CNNServer: the next
+            # wave's conv stage may start only once this wave's features
+            # have been handed to the SA-FC array
+            for p in participants:
+                p.conv_free, p.fc_free = max(conv_done, fc_start), fc_done
+                p.busy_s += eff.total_s
+                p.waves += 1
 
             if timed_out:
-                events.append(FleetEvent(
-                    now, rid, "timeout",
+                # aborted at the timeout: the arrays were occupied that
+                # long, but nothing completed — no heartbeat, all retry
+                events.append(FaultEvent(
+                    now, attempt, chosen, "timeout",
                     f"stall x{stall:g} >= timeout factor "
-                    f"{rec.wave_timeout_factor:g}, wave aborted",
-                    uids=uids, attempt=attempt, model=chosen))
-                decisions.append(FleetWaveDecision(
-                    index=len(decisions), t_s=now, replica=rid,
-                    model=chosen, uids=uids, batch=len(wave),
-                    conv_s=eff.conv_s, fc_s=eff.fc_s, fault="timeout",
-                    stall_factor=stall))
-                attempts.append(FleetWaveAttempt(
-                    attempt, rid, chosen, list(wave), faults,
-                    deliver=(), execute=False))
-                fail_wave(wave, rid, chosen, fc_done, "timeout", attempt)
+                    f"{rec.wave_timeout_factor:g}, "
+                    f"{'sharded ' if shards else ''}wave aborted", uids,
+                    replica=rid))
+                decide("timeout")
+                lost(fc_done, "timeout")
                 continue
 
-            if not partitioned(rid, fc_done):
-                beats.beat(rid, fc_done)
-            verdict = monitors[rid].observe(attempt, stall)
-            if verdict == "straggler":
-                events.append(FleetEvent(
-                    fc_done, rid, "stall",
-                    f"straggler verdict: x{stall:g} modeled wave time",
-                    uids=uids, attempt=attempt, model=chosen))
-            for r in wave:
+            # the wave completed (cleanly, late, or with corrupt rows)
+            for p in participants:
+                if not partitioned(p.rid, fc_done):
+                    replica_beats.beat(p.rid, fc_done)
+            model_beats.beat(chosen, fc_done)
+            # a straggler verdict needs a fault plane to stall the wave
+            if verdict is not None and straggler(
+                    chosen if on_model else rid, attempt, stall):
+                events.append(FaultEvent(
+                    fc_done, attempt, chosen, "stall",
+                    f"straggler verdict: x{stall:g} modeled "
+                    f"{'sharded ' if shards else ''}wave time", uids,
+                    replica=rid))
+                if on_model:
+                    health_event(fc_done, chosen,
+                                 health[chosen].on_straggler(rec),
+                                 "straggler")
+            corrupt_rows = frozenset(verdict.corrupt_rows) \
+                if kind == "corrupt" else frozenset()
+            served = [r for j, r in enumerate(wave) if j not in corrupt_rows]
+            failed = [r for j, r in enumerate(wave) if j in corrupt_rows]
+            for r in served:
                 r.dispatch_s, r.finish_s = now, fc_done
-                r.status = "served"
-                r.replica = rid
-            terminal += len(wave)
-            decisions.append(FleetWaveDecision(
-                index=len(decisions), t_s=now, replica=rid, model=chosen,
-                uids=uids, batch=len(wave), conv_s=eff.conv_s,
-                fc_s=eff.fc_s, fault=kind, stall_factor=stall))
-            attempts.append(FleetWaveAttempt(
-                attempt, rid, chosen, list(wave), faults, deliver=uids))
-        return decisions, attempts, events, states, mesh_plans
+                r.status, r.replica = "served", rid
+            terminal += len(served)
+            decide(kind)
+            attempts.append(WaveAttempt(
+                attempt, chosen, list(wave), verdict,
+                deliver=tuple(r.uid for r in served), replica=rid,
+                shards=shards))
+            if failed:
+                events.append(FaultEvent(
+                    fc_done, attempt, chosen, "corrupt",
+                    f"non-finite logits in rows "
+                    f"{tuple(sorted(corrupt_rows))}",
+                    uids=tuple(r.uid for r in failed), replica=rid))
+                fail_wave(failed, rid, chosen, fc_done, "corrupt", attempt)
+                health_event(fc_done, chosen,
+                             health[chosen].on_failure(rec), "corrupt")
+            else:
+                health_event(fc_done, chosen,
+                             health[chosen].on_clean(rec), "clean wave")
+        return decisions, attempts, events, health, states, mesh_plans
 
-    # -- execution (real kernels on replica lanes, bitwise parity) ----------
-    def _execute(self, attempts: list[FleetWaveAttempt],
-                 events: list[FleetEvent]) -> None:
-        """Run every completed attempt through its replica's lane — the
-        zoo executor lifted per replica, with the same host-side
-        integrity guard and never-wedge discipline.  Images are placed
-        on the replica's device; on CPU host devices the kernels are
-        bit-identical across devices, preserving the parity contract."""
+    # -- execution (real kernels, bitwise per-request logits) ---------------
+    def _execute(self, attempts: list[WaveAttempt],
+                 events: list[FaultEvent], call: int) -> None:
+        """Run every completed attempt on its replica's lane, then deliver
+        its rows through :meth:`_guard`.  A lane on the default device
+        gets the host images, any other lane images placed on its device.
+        An executor exception quarantines the attempt's undelivered
+        requests instead of wedging the drain.  ``call`` is the
+        ``serve()`` call's index, the ident of its spans."""
         import jax
 
         for a in attempts:
@@ -1111,66 +967,86 @@ class FleetServer:
                 self._execute_sharded(a, events)
                 continue
             srv = self._lane(a.replica, a.model)
-            device = self.replica_device(
-                self.replica_ids.index(a.replica))
+            device = self.replica_device(self.replica_ids.index(a.replica))
+            local = device == jax.devices()[0]
             exec_uids: list[int] = []
             for r in a.requests:
-                eu = self._exec_uid
-                self._exec_uid += 1
+                eu = next(_EXEC_UIDS)
                 exec_uids.append(eu)
-                srv.submit(CNNRequest(uid=eu,
-                                      image=jax.device_put(r.image,
-                                                           device)))
+                srv.submit(CNNRequest(uid=eu, image=r.image if local
+                                      else jax.device_put(r.image, device)))
             try:
                 completed = {c.uid: c for c in srv.step_wave()}
             except Exception as e:      # noqa: BLE001 — never wedge
                 srv.cancel(exec_uids)
-                deliver = set(a.deliver)
-                for r in a.requests:
-                    if r.uid in deliver:
-                        r.status = "quarantined"
-                        r.error = ServeError(
-                            f"wave execution raised {type(e).__name__}: "
-                            f"{e}", uid=r.uid, model=a.model)
-                        events.append(FleetEvent(
-                            -1.0, a.replica, "quarantine",
-                            f"executor raised {type(e).__name__}",
-                            uids=(r.uid,), attempt=a.index,
-                            model=a.model))
+                self._lost(a, e, events)
                 continue
-            deliver = set(a.deliver)
-            for r, eu in zip(a.requests, exec_uids):
-                done = completed.get(eu)
-                if done is None:
+            self._guard(a, [None if eu not in completed
+                            else completed[eu].logits for eu in exec_uids],
+                        events, call)
+
+    def _lost(self, a: WaveAttempt, e: Exception,
+              events: list[FaultEvent]) -> None:
+        """Quarantine the requests an attempt would have delivered when
+        its executor raised."""
+        deliver = set(a.deliver)
+        for r in a.requests:
+            if r.uid in deliver:
+                r.status, r.error = "quarantined", ServeError(
+                    f"wave execution raised {type(e).__name__}: {e}",
+                    uid=r.uid, model=a.model)
+                events.append(FaultEvent(
+                    -1.0, a.index, a.model, "quarantine",
+                    f"executor raised {type(e).__name__}",
+                    uids=(r.uid,), replica=a.replica))
+
+    def _guard(self, a: WaveAttempt, rows: list, events: list[FaultEvent],
+               call: int) -> None:
+        """Deliver one executed attempt's rows (``None`` where the
+        executor lost one) through the integrity guard:
+        :func:`~repro.serve.errors.all_finite` on each row's host copy of
+        its logits, with no device call.  Rows the chaos plane corrupts
+        are overwritten first, at the flush boundary; the guard must
+        agree with the modeled schedule, and also refuses *genuine*
+        non-finite rows the schedule expected clean."""
+        corrupt_rows = frozenset(a.faults.corrupt_rows) \
+            if a.faults is not None and a.faults.kind == "corrupt" \
+            else frozenset()
+        deliver = set(a.deliver)
+        with telemetry.span("zoo.guard", call):
+            telemetry.count("zoo.guard_rows",
+                            sum(row is not None for row in rows))
+            for j, (r, logits) in enumerate(zip(a.requests, rows)):
+                if logits is None:       # executor lost a row: typed, loud
                     if r.uid in deliver:
-                        r.status = "quarantined"
-                        r.error = ServeError(
+                        r.status, r.error = "quarantined", ServeError(
                             "executor returned no completion for the "
-                            "request's wave row", uid=r.uid,
-                            model=a.model)
-                        events.append(FleetEvent(
-                            -1.0, a.replica, "quarantine",
+                            "request's wave row", uid=r.uid, model=a.model)
+                        events.append(FaultEvent(
+                            -1.0, a.index, a.model, "quarantine",
                             "executor lost a wave row", uids=(r.uid,),
-                            attempt=a.index, model=a.model))
+                            replica=a.replica))
                     continue
-                logits = np.asarray(done.logits)
+                logits = np.asarray(logits)
+                if j in corrupt_rows:
+                    logits = FaultInjector.corrupt_array(logits)
                 if not all_finite(logits):
+                    telemetry.count("zoo.guard_rejects")
                     if r.uid in deliver:
-                        r.status = "quarantined"
-                        r.error = CorruptOutputError(
-                            "non-finite logits at the integrity guard",
-                            uid=r.uid, model=a.model)
-                        events.append(FleetEvent(
-                            -1.0, a.replica, "quarantine",
-                            "integrity guard: genuine non-finite "
-                            "logits", uids=(r.uid,), attempt=a.index,
-                            model=a.model))
+                        r.status, r.error = "quarantined", \
+                            CorruptOutputError(
+                                "non-finite logits at the integrity guard",
+                                uid=r.uid, model=a.model)
+                        events.append(FaultEvent(
+                            -1.0, a.index, a.model, "quarantine",
+                            "integrity guard: genuine non-finite logits",
+                            uids=(r.uid,), replica=a.replica))
                     continue
                 if r.uid in deliver:
                     r.logits, r.done = logits, True
 
-    def _execute_sharded(self, a: FleetWaveAttempt,
-                         events: list[FleetEvent]) -> None:
+    def _execute_sharded(self, a: WaveAttempt,
+                         events: list[FaultEvent]) -> None:
         """Run one cooperative wave over the participants' mesh: the
         row batch is committed to the ``("data",)`` axis with
         ``jax.device_put`` + ``NamedSharding``
@@ -1185,15 +1061,14 @@ class FleetServer:
         under one ``jax.jit`` computes each row bitwise as the batch-1
         forward does (``tests/test_stage_program.py``), so each served
         row stays **bitwise equal** to the single-device unbatched
-        forward.  Same host-side integrity guard and never-wedge
-        discipline as the per-replica executor.  Its host work is the
-        span ``fleet.sharded_wave``."""
+        forward.  Its host work is the span ``fleet.sharded_wave``."""
         # ident: the serve() call this wave belongs to
-        with telemetry.span("fleet.sharded_wave", self._serve_calls - 1):
-            self._run_sharded(a, events)
+        call = self._serve_calls - 1
+        with telemetry.span("fleet.sharded_wave", call):
+            self._run_sharded(a, events, call)
 
-    def _run_sharded(self, a: FleetWaveAttempt,
-                     events: list[FleetEvent]) -> None:
+    def _run_sharded(self, a: WaveAttempt, events: list[FaultEvent],
+                     call: int) -> None:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec
@@ -1202,7 +1077,6 @@ class FleetServer:
         from repro.models import cnn
 
         m = self.models[a.model]
-        deliver = set(a.deliver)
         try:
             mesh = self.shard_mesh(a.shards)
             x = jnp.stack([jnp.asarray(r.image, m.server.dtype)
@@ -1217,109 +1091,116 @@ class FleetServer:
             params = self._mesh_params(a.model, a.shards, mesh)
             logits = np.asarray(forward(params, xs))[:rows]
         except Exception as e:          # noqa: BLE001 — never wedge
-            for r in a.requests:
-                if r.uid in deliver:
-                    r.status = "quarantined"
-                    r.error = ServeError(
-                        f"sharded wave execution raised "
-                        f"{type(e).__name__}: {e}", uid=r.uid,
-                        model=a.model)
-                    events.append(FleetEvent(
-                        -1.0, a.replica, "quarantine",
-                        f"sharded executor raised {type(e).__name__}",
-                        uids=(r.uid,), attempt=a.index, model=a.model))
+            self._lost(a, e, events)
             return
-        for i, r in enumerate(a.requests):
-            row = logits[i]
-            if not all_finite(row):
-                if r.uid in deliver:
-                    r.status = "quarantined"
-                    r.error = CorruptOutputError(
-                        "non-finite logits at the integrity guard",
-                        uid=r.uid, model=a.model)
-                    events.append(FleetEvent(
-                        -1.0, a.replica, "quarantine",
-                        "integrity guard: genuine non-finite logits",
-                        uids=(r.uid,), attempt=a.index, model=a.model))
-                continue
-            if r.uid in deliver:
-                r.logits, r.done = row, True
+        self._guard(a, list(logits), events, call)
 
     # -- drain ---------------------------------------------------------------
-    def serve(self, *, execute: bool = True) -> FleetReport:
-        """Drain every queue: schedule (modeled time, device-count
-        independent), execute on replica lanes (``execute=False`` for
-        modeled-only analysis), account.  Every admitted request ends in
-        exactly one terminal status.  The call is the span
-        ``fleet.serve``, its index the span's ident."""
+    def serve(self, *, execute: bool = True) -> ZooReport:
+        """Drain every per-tenant queue: schedule (modeled time), execute
+        (real kernels; skipped with ``execute=False`` for modeled-only
+        analysis — the schedule, statuses and accounting are
+        execution-independent by construction), account.  Returns the
+        :class:`~repro.serve.zoo.ZooReport`; the admitted requests are
+        completed in place, each in exactly one terminal status."""
         call = self._serve_calls
         self._serve_calls += 1
-        with telemetry.span("fleet.serve", call):
-            return self._serve(execute)
+        with telemetry.span("zoo.serve", call):
+            return self._serve(execute, call)
 
-    def _serve(self, execute: bool) -> FleetReport:
+    def _serve(self, execute: bool, call: int) -> ZooReport:
         queued = [r for q in self.tenants.values() for r in q]
         for q in self.tenants.values():
             q.clear()
         rejected, self._rejected = self._rejected, []
         requests = queued + rejected
         if not requests:
-            return FleetReport(self.placement.name, self.policy.name,
-                               self.n_replicas, (), (), (), 0.0, (), (),
-                               ())
-        decisions: list[FleetWaveDecision] = []
-        attempts: list[FleetWaveAttempt] = []
-        events: list[FleetEvent] = []
-        states: dict[str, _ReplicaState] = {}
-        mesh_plans: list[tuple[float, int, int, str]] = []
-        for r in rejected:
-            events.append(FleetEvent(r.arrival_s, "-", "shed",
-                                     "stale deadline at submit",
-                                     uids=(r.uid,), model=r.model))
+            return ZooReport(self.policy.name, (), (), 0.0, 0.0, 0.0, (),
+                             placement=self.placement.name,
+                             n_replicas=self.n_replicas)
+        decisions, attempts, health, states, mesh_plans = [], [], {}, {}, []
+        events = [FaultEvent(r.arrival_s, -1, r.model, "shed",
+                             "stale deadline at submit", uids=(r.uid,))
+                  for r in rejected]    # admission-time typed rejections
         if queued:
-            decisions, attempts, sched_events, states, mesh_plans = \
-                self._schedule(queued)
+            with telemetry.span("zoo.schedule", call):
+                decisions, attempts, sched_events, health, states, \
+                    mesh_plans = self._schedule(queued)
             events.extend(sched_events)
         if execute:
-            self._execute(attempts, events)
-        terminal = ("served", "shed", "quarantined")
+            with telemetry.span("zoo.execute", call):
+                self._execute(attempts, events, call)
+        with telemetry.span("zoo.account", call):
+            return self._account(requests, decisions, events, health,
+                                 states, mesh_plans)
+
+    def _account(self, requests: list[ZooRequest],
+                 decisions: list[WaveDecision], events: list[FaultEvent],
+                 health: dict[str, ModelHealth],
+                 states: dict[str, _ReplicaState],
+                 mesh_plans: list) -> ZooReport:
+        # the zero-unaccounted guarantee, enforced defensively: anything
+        # the scheduler somehow left non-terminal becomes a typed error
+        # result rather than a silent drop
         for r in requests:
-            if r.status not in terminal:      # defensive zero-unaccounted
-                r.status = "quarantined"
-                r.error = ServeError(
-                    "internal: request left non-terminal by the fleet "
+            if r.status not in ("served", "shed", "quarantined"):
+                r.status, r.error = "quarantined", ServeError(
+                    "internal: request left non-terminal by the "
                     "scheduler", uid=r.uid, model=r.model)
-                events.append(FleetEvent(-1.0, r.replica or "-",
-                                         "quarantine",
+                events.append(FaultEvent(-1.0, -1, r.model, "quarantine",
                                          "internal: non-terminal request",
-                                         uids=(r.uid,), model=r.model))
+                                         uids=(r.uid,)))
         served = [r for r in requests if r.status == "served"]
         makespan = (max(r.finish_s for r in served)
                     - min(r.arrival_s for r in requests)) if served else 0.0
         by_tenant: dict[str, list[ZooRequest]] = {}
         for r in requests:
             by_tenant.setdefault(r.tenant, []).append(r)
-        served_by_replica: dict[str, int] = {}
+        by_replica: dict[str, int] = {}
         for r in served:
-            if r.replica is not None:
-                served_by_replica[r.replica] = \
-                    served_by_replica.get(r.replica, 0) + 1
-        per_replica = tuple(
-            ReplicaStats(replica=rid, waves=st.waves,
-                         served=served_by_replica.get(rid, 0),
-                         busy_s=st.busy_s, drained_away=st.drained_away,
-                         state=st.state_name)
-            for rid, st in sorted(states.items()))
-        return FleetReport(
-            placement=self.placement.name,
+            by_replica[r.replica] = by_replica.get(r.replica, 0) + 1
+        return ZooReport(
             policy=self.policy.name,
-            n_replicas=self.n_replicas,
             requests=tuple(sorted(requests, key=lambda r: r.uid)),
             decisions=tuple(decisions),
-            events=tuple(events),
             makespan_s=makespan,
-            per_replica=per_replica,
-            per_tenant=tuple(
-                ModelZooServer._tenant_stats(t, rs)
-                for t, rs in sorted(by_tenant.items())),
+            conv_busy_s=sum(d.conv_s * max(1, len(d.shards))
+                            for d in decisions),
+            fc_busy_s=sum(d.fc_s * max(1, len(d.shards))
+                          for d in decisions),
+            per_tenant=tuple(_tenant_stats(t, rs) for t, rs in
+                             sorted(by_tenant.items())),
+            events=tuple(events),
+            health=tuple((m, h.state) for m, h in sorted(health.items())),
+            placement=self.placement.name,
+            n_replicas=self.n_replicas,
+            per_replica=tuple(
+                ReplicaStats(replica=rid, waves=st.waves,
+                             served=by_replica.get(rid, 0),
+                             busy_s=st.busy_s, drained_away=st.drained_away,
+                             state=st.state_name)
+                for rid, st in sorted(states.items())),
             mesh_plans=tuple(mesh_plans))
+
+
+def _tenant_stats(tenant: str, reqs: list[ZooRequest]) -> TenantStats:
+    served = [r for r in reqs if r.status == "served"]
+    lats = np.array([r.latency_s for r in served], dtype=np.float64)
+    has = lats.size > 0
+    return TenantStats(
+        tenant=tenant, n=len(reqs),
+        mean_latency_s=float(lats.mean()) if has else 0.0,
+        p50_s=float(np.percentile(lats, 50)) if has else 0.0,
+        p95_s=float(np.percentile(lats, 95)) if has else 0.0,
+        p99_s=float(np.percentile(lats, 99)) if has else 0.0,
+        deadlines=sum(r.deadline_s is not None for r in reqs),
+        misses=sum(bool(r.missed_deadline) for r in reqs),
+        served=len(served),
+        shed=sum(r.status == "shed" for r in reqs),
+        quarantined=sum(r.status == "quarantined" for r in reqs),
+        retries=sum(r.retries for r in reqs),
+        degraded=sum(r.degraded for r in served))
+
+
+# the benchmark harness imports this name for its fleet cells
+FleetServer = ModelZooServer

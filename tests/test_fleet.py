@@ -12,12 +12,13 @@ import pytest
 from repro.serve.errors import (InsufficientReplicasError,
                                 ReplicaLostError, ServeError,
                                 WaveTimeoutError)
-from repro.serve.faults import ReplicaChaosConfig, ReplicaFaultInjector
+from repro.serve.faults import (ChaosConfig, FaultInjector,
+                                ReplicaChaosConfig, ReplicaFaultInjector)
 from repro.serve.fleet import (PLACEMENTS, FleetServer,
                                LeastLoadedPlacement, ReplicaView,
                                RoundRobinPlacement)
-from repro.serve.zoo import (FIFOPolicy, ModelZooServer, RecoveryConfig,
-                             ZooRequest, build_zoo)
+from repro.serve.zoo import (AdmissionConfig, FIFOPolicy, ModelZooServer,
+                             RecoveryConfig, ZooRequest, build_zoo)
 
 RES = {"alexnet": 67}
 WIDTH = 0.125
@@ -143,28 +144,70 @@ def test_fleet_spreads_simultaneous_arrivals():
 
 # -- single-replica equivalence with the zoo ---------------------------------
 
-def test_single_replica_fleet_schedule_equals_zoo():
-    """One replica, no chaos: the fleet scheduler IS the zoo scheduler —
-    same decisions (time, model, uids, batch, stage costs), same
-    terminal statuses, same makespan."""
-    fleet = fresh_fleet(names=("alexnet", "alexnet-int8"), n_replicas=1)
-    zoo = ModelZooServer(zoo_models(("alexnet", "alexnet-int8")),
-                         policy=FIFOPolicy())
+WAVE_CHAOS = dict(
+    faults=ChaosConfig(seed=2, dispatch_fail_rate=0.15, corrupt_rate=0.15,
+                       stall_rate=0.25, stall_factors=(4.0, 24.0)),
+    admission=AdmissionConfig(max_queue=3, predictive_shedding=True))
+
+
+@pytest.mark.parametrize("chaos", [None, WAVE_CHAOS],
+                         ids=["no-chaos", "wave-chaos"])
+def test_single_replica_fleet_schedule_equals_zoo(chaos):
+    """One replica: the fleet IS the zoo — same decisions (time, model,
+    uids, batch, stage costs, faults), same events, same terminal
+    statuses, same makespan — with no chaos, and under wave chaos
+    (dispatch, corrupt and stall faults, bounded queues, predictive
+    shedding and the int8 sibling's degraded fallback)."""
+    names = ("alexnet", "alexnet-int8")
+
+    def kw():
+        if chaos is None:
+            return {}
+        return dict(faults=FaultInjector(chaos["faults"]),
+                    admission=chaos["admission"])
+
+    fleet = fresh_fleet(names=names, n_replicas=1, **kw())
+    zoo = ModelZooServer(zoo_models(names), policy=FIFOPolicy(), **kw())
+    fp32 = fleet.models["alexnet"].wave_cost(1).total_s
     for srv in (fleet, zoo):
-        for k in range(6):
-            model = "alexnet" if k % 2 == 0 else "alexnet-int8"
+        for k in range(12):
+            model = "alexnet" if k % 3 else "alexnet-int8"
             srv.submit(ZooRequest(uid=k, model=model, image=img(k),
-                                  tenant=f"t{k % 2}",
-                                  arrival_s=k * 2e-5))
+                                  tenant=f"t{k % 2}", arrival_s=k * 2e-5,
+                                  deadline_s=k * 2e-5 + (0.9 + k % 4)
+                                  * fp32))
     frep = fleet.serve(execute=False)
     zrep = zoo.serve(execute=False)
-    key = lambda d: (d.t_s, d.model, d.uids, d.batch, d.conv_s, d.fc_s)
-    assert [key(d) for d in frep.decisions] \
-        == [key(d) for d in zrep.decisions]
+    assert frep.decisions == zrep.decisions
+    assert frep.events == zrep.events
     assert {r.uid: r.status for r in frep.requests} \
         == {r.uid: r.status for r in zrep.requests}
     assert frep.makespan_s == zrep.makespan_s
     assert all(d.replica == "r0" for d in frep.decisions)
+    if chaos is not None:
+        kinds = {e.kind for e in zrep.events}
+        assert {"retry", "shed", "degrade"} <= kinds
+        assert {d.fault for d in zrep.decisions} - {"none"}
+
+
+def test_one_replica_zoo_runs_every_wave_on_the_model_server():
+    """The one-chip zoo executes on ``ZooModel.server`` itself: no
+    second lane, no second copy of the weights."""
+    models = zoo_models()
+    srv = models[0].server
+    zoo = ModelZooServer(models, policy=FIFOPolicy())
+    step_wave, calls = srv.step_wave, []
+
+    def counted():
+        calls.append(1)
+        return step_wave()
+
+    srv.step_wave = counted
+    submit_n(zoo, 3, spacing=0.0)
+    rep = zoo.serve()
+    assert len(rep.served) == 3 and len(calls) == len(rep.decisions) == 2
+    assert zoo._lanes == {"r0": {"alexnet-int8": srv}}
+    assert zoo._lane("r0", "alexnet-int8") is srv
 
 
 def test_multi_replica_never_slower_than_one():

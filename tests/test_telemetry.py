@@ -308,6 +308,28 @@ def test_guard_rejects_are_counted_on_the_guard():
 
 # -- the fleet -----------------------------------------------------------------
 
+def test_two_replica_fleet_spans_and_guard_rows(zoo_models):
+    """A fleet is the same plane: its drain is ``zoo.schedule`` and one
+    ``zoo.guard`` per executed wave, and ``zoo.guard_rows`` counts every
+    row it delivered."""
+    from repro.serve.fleet import FleetServer
+
+    fleet = FleetServer(zoo_models, n_replicas=2)
+    key = ("zoo.guard_rows", "zoo.guard")
+    before = telemetry.counts().get(key, 0)
+    for i, im in enumerate(_images(4)):
+        fleet.submit(ZooRequest(uid=i, model="alexnet", image=im))
+    lo = time.perf_counter_ns()
+    rep = fleet.serve()
+    hi = time.perf_counter_ns()
+    assert len(rep.served) == 4
+    assert {d.replica for d in rep.decisions} == {"r0", "r1"}
+    names = [r.name for r in telemetry.records(lo, hi)]
+    assert names.count("zoo.schedule") == 1
+    assert names.count("zoo.guard") == len(rep.decisions)
+    assert telemetry.counts().get(key, 0) - before == 4
+
+
 FLEET = r"""
 import json, sys, time
 import numpy as np
@@ -373,7 +395,7 @@ def test_fleet_cooperative_wave_compiles_land_on_its_span():
     r = json.loads(out.stdout.strip().splitlines()[-1])
     assert r["served"] == 6 and r["sharded"] >= 1
     spans = r["spans"]
-    (root,) = [s for s in spans if s[0] == "fleet.serve"]
+    (root,) = [s for s in spans if s[0] == "zoo.execute"]
     waves = [s for s in spans if s[0] == "fleet.sharded_wave"]
     assert len(waves) == r["sharded"]
     assert all(w[1] == root[3] and w[2] == root[2] for w in waves)
