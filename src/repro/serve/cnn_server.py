@@ -39,9 +39,20 @@ wave that dispatched it (``stage='conv'|'fc'``, ``wave=i``) — the
 serving-side twin of the stage-split schedule tables.  The server keeps
 the reports of its most recent :data:`RECENT_WAVES` waves.
 
+A wave's images reach the device in one transfer: host images are
+stacked on the host and moved with one ``jax.device_put`` to the server's
+device (images already on a device are stacked there).  A caller that
+knows the next wave (:meth:`CNNServer.prefetch`) has its upload issued
+inside the current :meth:`~CNNServer.step_wave`, after both stage
+programs are dispatched and before the wave's barrier, so the transfer
+runs while the device computes; the next wave uses that array only if
+its rows are exactly the rows uploaded.
+
 Each wave's host work is marked with :mod:`repro.serve.telemetry` spans
 (ident = the wave index): ``cnn.wave`` (one :meth:`CNNServer.step_wave`,
-counting its rows as ``cnn.rows``), ``cnn.upload`` (the image stack),
+counting its rows as ``cnn.rows``), ``cnn.upload`` (the image upload,
+ident = the wave it uploads, counted as ``cnn.uploads``, and as
+``cnn.upload_ahead`` where it is issued inside the previous wave),
 ``cnn.conv_dispatch`` / ``cnn.fc_dispatch`` (the host calling each
 stage's program, counted as ``cnn.stage_calls``, and tracing it where
 that wave size is new, counted as ``cnn.stage_builds``; the conv
@@ -52,7 +63,9 @@ and ``cnn.logits_wait`` (the wave's one barrier).
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -176,6 +189,14 @@ class CNNServer:
         self._wave_counter = 0
         self._uids: set = set()
         self._inflight: _StageBuffer | None = None
+        # where uploads go: the device the parameters are committed to,
+        # else JAX's default (an uncommitted array, as the parameters are)
+        leaf = jax.tree.leaves(params)[0]
+        self._device = next(iter(leaf.devices())) \
+            if isinstance(leaf, jax.Array) and leaf.committed else None
+        self._ahead: list[CNNRequest] = []     # the next wave, if known
+        # (uids, images on the device) uploaded ahead for the next wave
+        self._staged: tuple[tuple[int, ...], jax.Array] | None = None
 
     @property
     def preferred_microbatch(self) -> int:
@@ -232,12 +253,16 @@ class CNNServer:
         return pair
 
     # -- serving ------------------------------------------------------------
+    @property
+    def image_shape(self) -> tuple[int, int, int]:
+        return (self.in_res, self.in_res, self.in_ch)
+
     def submit(self, req: CNNRequest) -> None:
         """Admit one request.  Duplicate uids are REJECTED (``ValueError``):
         a uid names one request for the lifetime of the server — waves,
         traces and zoo accounting all key on it, so re-submitting a uid
         would silently alias two requests in every report."""
-        shape = (self.in_res, self.in_res, self.in_ch)
+        shape = self.image_shape
         if tuple(req.image.shape) != shape:
             raise ValueError(f"request {req.uid}: image shape "
                              f"{tuple(req.image.shape)} != server {shape}")
@@ -247,6 +272,32 @@ class CNNServer:
         self._uids.add(req.uid)
         self.queue.append(req)
 
+    def prefetch(self, requests: Sequence[CNNRequest]) -> None:
+        """Name the requests of the wave that will follow the next
+        :meth:`step_wave`: that call uploads their images once its own
+        wave's stage programs are dispatched, before it waits on them.
+        They are not queued; only a wave of exactly these requests uses
+        the upload, any other uploads its own.  Requests
+        :meth:`submit` would refuse are not uploaded ahead, so their
+        error stays with their own wave."""
+        ok = all(tuple(r.image.shape) == self.image_shape for r in requests)
+        self._ahead = list(requests) if ok else []
+
+    def _upload(self, wave_idx: int, images: list, *,
+                ahead: bool = False) -> jax.Array:
+        """One wave's images on the server's device, in span
+        ``cnn.upload``: host images stacked on the host and moved in one
+        transfer, images already on a device stacked there first."""
+        with telemetry.span("cnn.upload", wave_idx):
+            telemetry.count("cnn.uploads")
+            if ahead:
+                telemetry.count("cnn.upload_ahead")
+            if any(isinstance(im, jax.Array) for im in images):
+                x = jnp.stack([jnp.asarray(im, self.dtype) for im in images])
+            else:
+                x = np.stack([np.asarray(im, self.dtype) for im in images])
+            return jax.device_put(x, self._device)
+
     def _conv_stage_dispatch(self, wave_idx: int,
                              wave: list[CNNRequest]) -> _StageBuffer:
         """Stage 1 (SA-CONV array): dispatch the conv+fused-pool stack of
@@ -254,8 +305,11 @@ class CNNServer:
         features to the stage buffer — no blocking here, so the next
         stage can be issued while this one runs."""
         from repro.models import cnn
-        with telemetry.span("cnn.upload", wave_idx):
-            x = jnp.stack([jnp.asarray(r.image, self.dtype) for r in wave])
+        staged, self._staged = self._staged, None
+        if staged is not None and staged[0] == tuple(r.uid for r in wave):
+            x = staged[1]
+        else:
+            x = self._upload(wave_idx, [r.image for r in wave])
         conv_sched, _ = self._stage_schedules(len(wave))
         eng = self.engine.with_schedule(conv_sched)
         with eng.tracing() as tr, eng.tagging(stage="conv", wave=wave_idx), \
@@ -264,9 +318,12 @@ class CNNServer:
             _count_bands(tr)
         return _StageBuffer(wave_idx, list(wave), feats, tr)
 
-    def _fc_stage_complete(self, buf: _StageBuffer) -> list[CNNRequest]:
+    def _fc_stage_complete(self, buf: _StageBuffer,
+                           ahead: Sequence[CNNRequest] = ()
+                           ) -> list[CNNRequest]:
         """Stage 2 (SA-FC array): run the classifier head on the buffered
-        features, block, deliver logits, and file the WaveReport."""
+        features, upload the ``ahead`` wave's images while the device
+        works, block, deliver logits, and file the WaveReport."""
         from repro.models import cnn
         _, fc_sched = self._stage_schedules(len(buf.requests))
         eng = self.engine.with_schedule(fc_sched)
@@ -274,6 +331,9 @@ class CNNServer:
                 telemetry.span("cnn.fc_dispatch", buf.wave):
             logits = cnn.cnn_fc_stage(self.net, self.params, buf.feats,
                                       eng=eng)
+        if ahead:
+            self._staged = (tuple(r.uid for r in ahead), self._upload(
+                self._wave_counter, [r.image for r in ahead], ahead=True))
         with telemetry.span("cnn.logits_wait", buf.wave):
             logits = np.asarray(logits)               # the pipeline barrier
         for i, r in enumerate(buf.requests):
@@ -300,13 +360,22 @@ class CNNServer:
         A stage that raises never loses requests: the wave's undelivered
         requests are pushed back to the head of the queue before the
         exception propagates, so the caller can retry, cancel, or
-        quarantine them — the queue never silently wedges."""
+        quarantine them — the queue never silently wedges.  It also drops
+        any upload made ahead, so no later wave reads it.
+
+        The requests named by :meth:`prefetch` have their upload issued
+        inside this wave, while the device runs it."""
+        ahead, self._ahead = self._ahead, []
         if self._inflight is None and not self.queue:
             return []
         with telemetry.span("cnn.wave", self._wave_counter):
-            return self._step_wave()
+            try:
+                return self._step_wave(ahead)
+            except Exception:
+                self._staged = None
+                raise
 
-    def _step_wave(self) -> list[CNNRequest]:
+    def _step_wave(self, ahead: list[CNNRequest]) -> list[CNNRequest]:
         finished: list[CNNRequest] = []
         if self._inflight is not None:
             buf, self._inflight = self._inflight, None
@@ -323,7 +392,7 @@ class CNNServer:
         try:
             buf = self._conv_stage_dispatch(self._wave_counter, wave)
             self._wave_counter += 1
-            finished.extend(self._fc_stage_complete(buf))
+            finished.extend(self._fc_stage_complete(buf, ahead))
         except Exception:
             self.queue[:0] = [r for r in wave if not r.done]
             raise

@@ -953,28 +953,35 @@ class ModelZooServer:
     def _execute(self, attempts: list[WaveAttempt],
                  events: list[FaultEvent], call: int) -> None:
         """Run every completed attempt on its replica's lane, then deliver
-        its rows through :meth:`_guard`.  A lane on the default device
-        gets the host images, any other lane images placed on its device.
-        An executor exception quarantines the attempt's undelivered
-        requests instead of wedging the drain.  ``call`` is the
-        ``serve()`` call's index, the ident of its spans."""
-        import jax
+        its rows through :meth:`_guard`.  Lanes get the host images and
+        move each wave to their device in one transfer.  Where the next
+        attempt to run goes to the same lane, the lane is told its rows
+        (:meth:`CNNServer.prefetch`), so their upload runs under this
+        wave's device work.  An executor exception quarantines the
+        attempt's undelivered requests instead of wedging the drain.
+        ``call`` is the ``serve()`` call's index, the ident of its
+        spans."""
+        def rows(a: WaveAttempt) -> list[CNNRequest]:
+            return [CNNRequest(uid=next(_EXEC_UIDS), image=r.image)
+                    for r in a.requests]
 
-        for a in attempts:
-            if not a.execute:
-                continue
+        runs = [a for a in attempts if a.execute]
+        ahead: list[CNNRequest] | None = None
+        for k, a in enumerate(runs):
             if a.shards:
                 self._execute_sharded(a, events)
                 continue
             srv = self._lane(a.replica, a.model)
-            device = self.replica_device(self.replica_ids.index(a.replica))
-            local = device == jax.devices()[0]
-            exec_uids: list[int] = []
-            for r in a.requests:
-                eu = next(_EXEC_UIDS)
-                exec_uids.append(eu)
-                srv.submit(CNNRequest(uid=eu, image=r.image if local
-                                      else jax.device_put(r.image, device)))
+            reqs = rows(a) if ahead is None else ahead
+            exec_uids = [c.uid for c in reqs]
+            for c in reqs:
+                srv.submit(c)
+            nxt = runs[k + 1] if k + 1 < len(runs) else None
+            ahead = None
+            if nxt is not None and not nxt.shards \
+                    and (nxt.replica, nxt.model) == (a.replica, a.model):
+                ahead = rows(nxt)
+                srv.prefetch(ahead)
             try:
                 completed = {c.uid: c for c in srv.step_wave()}
             except Exception as e:      # noqa: BLE001 — never wedge

@@ -201,3 +201,106 @@ def test_server_refuses_an_unplannable_conv_stack_when_built(alexnet_params):
         CNNServer("alexnet", alexnet_params, in_res=RES, width_mult=WIDTH,
                   max_batch=8, engine=eng)
     assert ei.value.op == "conv1"
+
+
+# -- the wave's upload ---------------------------------------------------------
+
+def _uploads():
+    """Process totals of ``cnn.uploads`` and ``cnn.upload_ahead``."""
+    from repro.serve import telemetry
+    c = telemetry.counts()
+    return tuple(c.get((k, "cnn.upload"), 0)
+                 for k in ("cnn.uploads", "cnn.upload_ahead"))
+
+
+def _assert_bitwise_unbatched(srv, reqs):
+    for r in reqs:
+        ref = cnn.cnn_forward("alexnet", srv.params,
+                              jnp.asarray(r.image)[None], eng=srv.engine)
+        np.testing.assert_array_equal(np.asarray(ref)[0], r.logits)
+
+
+def test_a_wave_of_host_images_is_one_device_put(alexnet_params,
+                                                 monkeypatch):
+    """A wave's host images reach the device in one ``jax.device_put`` of
+    the stacked wave, and each row serves bitwise as its unbatched
+    forward."""
+    srv = CNNServer("alexnet", alexnet_params, in_res=RES, width_mult=WIDTH,
+                    max_batch=8)
+    reqs = _requests(8, seed=5)
+    for r in reqs:
+        srv.submit(r)
+    put, shapes = jax.device_put, []
+
+    def counted(x, *a, **kw):
+        shapes.append(np.shape(x))
+        return put(x, *a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", counted)
+    assert len(srv.step_wave()) == 8
+    monkeypatch.undo()
+    assert shapes == [(8, RES, RES, 3)]
+    _assert_bitwise_unbatched(srv, reqs)
+
+
+def test_a_wave_of_device_images_serves_bitwise(alexnet_params):
+    """Images already on the device are stacked there; the wave serves
+    bitwise as the host-image path does."""
+    srv = CNNServer("alexnet", alexnet_params, in_res=RES, width_mult=WIDTH,
+                    max_batch=8)
+    reqs = [CNNRequest(uid=r.uid, image=jax.device_put(r.image))
+            for r in _requests(3, seed=6)]
+    for r in reqs:
+        srv.submit(r)
+    up0 = _uploads()
+    assert len(srv.step_wave()) == 3
+    assert np.subtract(_uploads(), up0).tolist() == [1, 0]
+    _assert_bitwise_unbatched(srv, reqs)
+
+
+def test_prefetched_rows_are_uploaded_ahead_and_used_only_when_exact(
+        alexnet_params):
+    """prefetch(): the next wave's upload is issued inside the current
+    step_wave; a wave of exactly those rows uses it, a wave of any other
+    rows uploads its own and the early upload is dropped."""
+    srv = CNNServer("alexnet", alexnet_params, in_res=RES, width_mult=WIDTH,
+                    max_batch=8)
+    srv.microbatch = 2
+    reqs = _requests(6, seed=7)
+    up0 = _uploads()
+
+    def delta():
+        return np.subtract(_uploads(), up0).tolist()
+
+    srv.submit(reqs[0])
+    srv.submit(reqs[1])
+    srv.prefetch(reqs[2:4])
+    assert [r.uid for r in srv.step_wave()] == [0, 1]
+    assert delta() == [2, 1] and srv.queue == []  # prefetch never queues
+    srv.submit(reqs[2])
+    srv.submit(reqs[3])
+    srv.prefetch(reqs[4:6])
+    assert [r.uid for r in srv.step_wave()] == [2, 3]
+    assert delta() == [3, 2]                      # wave 2,3 used its upload
+    srv.submit(reqs[4])                           # only part of the rows
+    assert [r.uid for r in srv.step_wave()] == [4]
+    assert delta() == [4, 2] and srv._staged is None
+    srv.submit(reqs[5])
+    srv.prefetch(reqs[:1])
+    assert [r.uid for r in srv.step_wave()] == [5]
+    assert srv.step_wave() == []                  # an empty queue forgets
+    assert delta() == [6, 3]
+    _assert_bitwise_unbatched(srv, reqs)
+
+
+def test_prefetch_skips_rows_submit_would_refuse(alexnet_params):
+    srv = CNNServer("alexnet", alexnet_params, in_res=RES, width_mult=WIDTH,
+                    max_batch=8)
+    srv.submit(_requests(1, seed=8)[0])
+    bad = CNNRequest(uid=9, image=np.zeros((RES, RES + 1, 3), np.float32))
+    srv.prefetch([bad])
+    up0 = _uploads()
+    assert len(srv.step_wave()) == 1
+    assert np.subtract(_uploads(), up0).tolist() == [1, 0]
+    with pytest.raises(ValueError, match="image shape"):
+        srv.submit(bad)

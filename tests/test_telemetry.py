@@ -248,9 +248,13 @@ def test_waves_are_bounded_and_trace_joins_the_stages(monkeypatch):
 
 def test_zoo_serve_span_tree(zoo_models):
     """Two waves in one serve(): the exact tree, with the call index on
-    the zoo's spans and the wave index on the executor's."""
+    the zoo's spans and the wave index on the executor's.  The second
+    wave's upload runs inside the first, between its FC dispatch and its
+    barrier, under the second wave's index."""
     zoo = ModelZooServer(zoo_models)
     rows0 = telemetry.counts().get(("cnn.rows", "cnn.wave"), 0)
+    ups0, ahead0 = (telemetry.counts().get((k, "cnn.upload"), 0)
+                    for k in ("cnn.uploads", "cnn.upload_ahead"))
     for i, im in enumerate(_images(4)):
         zoo.submit(ZooRequest(uid=i, model="alexnet", image=im))
     lo = time.perf_counter_ns()
@@ -265,11 +269,9 @@ def test_zoo_serve_span_tree(zoo_models):
                 if r.parent == parent]
 
     first = zoo_models[0].server._wave_counter - 2
-    wave = [("cnn.upload", None, []), ("cnn.conv_dispatch", None, []),
-            ("cnn.fc_dispatch", None, []), ("cnn.logits_wait", None, [])]
-
-    def waves(w):
-        return [(n, w, k) for n, _, k in wave]
+    second = [("cnn.conv_dispatch", first + 1, []),
+              ("cnn.fc_dispatch", first + 1, []),
+              ("cnn.logits_wait", first + 1, [])]
 
     roots = [r for r in out if r.parent not in by_seq]
     assert [r.name for r in roots] == ["zoo.serve"]
@@ -277,12 +279,20 @@ def test_zoo_serve_span_tree(zoo_models):
     assert tree(roots[0].seq) == [
         ("zoo.schedule", call, []),
         ("zoo.execute", call, [
-            ("cnn.wave", first, waves(first)),
+            ("cnn.wave", first, [
+                ("cnn.upload", first, []),
+                ("cnn.conv_dispatch", first, []),
+                ("cnn.fc_dispatch", first, []),
+                ("cnn.upload", first + 1, []),
+                ("cnn.logits_wait", first, [])]),
             ("zoo.guard", call, []),
-            ("cnn.wave", first + 1, waves(first + 1)),
+            ("cnn.wave", first + 1, second),
             ("zoo.guard", call, [])]),
         ("zoo.account", call, [])]
     assert telemetry.counts()[("cnn.rows", "cnn.wave")] - rows0 == 4
+    assert telemetry.counts()[("cnn.uploads", "cnn.upload")] - ups0 == 2
+    assert telemetry.counts()[("cnn.upload_ahead", "cnn.upload")] \
+        - ahead0 == 1
 
     for i, im in enumerate(_images(1, seed=1), start=10):
         zoo.submit(ZooRequest(uid=i, model="alexnet", image=im))

@@ -299,3 +299,54 @@ def test_zoo_serving_bitwise_parity_all_variants(small_zoo):
     # serving again with nothing queued is a no-op report
     empty = zoo.serve()
     assert empty.requests == () and empty.decisions == ()
+
+
+# -- the wave's upload -------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def alexnet_zoo8():
+    return build_zoo(("alexnet",), seed=0, in_res=RES, width_mult=WIDTH,
+                     max_batch=8)
+
+
+def _uploads():
+    from repro.serve import telemetry
+    c = telemetry.counts()
+    return np.array([c.get((k, "cnn.upload"), 0)
+                     for k in ("cnn.uploads", "cnn.upload_ahead")])
+
+
+def test_bulk_call_uploads_each_wave_once_seven_of_them_ahead(alexnet_zoo8):
+    """One 64-image serve() call at cap 8: eight waves, eight uploads,
+    the last seven issued inside the wave before them, and every row
+    bitwise equal to its unbatched forward.  A one-wave call uploads
+    nothing ahead."""
+    import jax.numpy as jnp
+
+    from repro.models import cnn
+
+    m = alexnet_zoo8[0]
+    assert m.server.microbatch == 8
+    zoo = ModelZooServer(alexnet_zoo8)
+    reqs = [ZooRequest(uid=k, model="alexnet",
+                       image=_img("alexnet", seed=100 + k))
+            for k in range(64)]
+    for r in reqs:
+        zoo.submit(r)
+    up0 = _uploads()
+    rep = zoo.serve()
+    assert [d.batch for d in rep.decisions] == [8] * 8
+    assert (_uploads() - up0).tolist() == [8, 7]
+    for r in reqs:
+        assert r.status == "served"
+        ref = cnn.cnn_forward("alexnet", m.params,
+                              jnp.asarray(r.image)[None],
+                              eng=m.server.engine)
+        np.testing.assert_array_equal(np.asarray(ref)[0], r.logits)
+
+    for k in range(8):
+        zoo.submit(ZooRequest(uid=100 + k, model="alexnet",
+                              image=_img("alexnet", seed=k)))
+    up0 = _uploads()
+    assert len(zoo.serve().decisions) == 1
+    assert (_uploads() - up0).tolist() == [1, 0]

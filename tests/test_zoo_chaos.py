@@ -380,3 +380,82 @@ def test_default_configs_preserve_legacy_serve_contract():
     assert report.shed == () and report.quarantined == ()
     assert report.events == () and report.makespan_s > 0.0
     assert report.shed_rate == 0.0 and report.degraded_served == 0
+
+
+# -- uploads made ahead under faults -----------------------------------------
+
+def _uploads():
+    from repro.serve import telemetry
+    c = telemetry.counts()
+    return np.array([c.get((k, "cnn.upload"), 0)
+                     for k in ("cnn.uploads", "cnn.upload_ahead")])
+
+
+def _assert_bitwise_unbatched(zoo, reqs):
+    import jax.numpy as jnp
+
+    from repro.models import cnn
+    for r in reqs:
+        m = zoo.models[r.model]
+        ref = cnn.cnn_forward(m.spec.net, m.params,
+                              jnp.asarray(r.image)[None],
+                              eng=m.server.engine)
+        np.testing.assert_array_equal(np.asarray(ref)[0], r.logits)
+
+
+def test_wave_that_raises_drops_the_upload_made_for_the_next(monkeypatch):
+    """Wave 0 raises at its barrier, after wave 1's upload was issued
+    inside it: wave 0's rows are quarantined, the lane drops that upload,
+    wave 1 uploads its own and serves bitwise, and the lane is left
+    empty for the next call."""
+    from repro.models import cnn
+
+    zoo = fresh_zoo(policy=FIFOPolicy())
+    srv = zoo.models["alexnet-int8"].server
+    reqs = submit_n(zoo, 6, spacing=0.0)
+    fc, calls = cnn.cnn_fc_stage, []
+
+    class Unreadable:
+        def __array__(self, *a, **kw):
+            raise RuntimeError("barrier failed")
+
+    def first_fails(*a, **kw):
+        calls.append(1)
+        out = fc(*a, **kw)
+        return Unreadable() if len(calls) == 1 else out
+
+    monkeypatch.setattr(cnn, "cnn_fc_stage", first_fails)
+    up0 = _uploads()
+    report = zoo.serve()
+    monkeypatch.undo()
+    assert_accounted(report, 6)
+    assert [r.status for r in reqs] == ["quarantined"] * 2 + ["served"] * 4
+    assert "RuntimeError" in str(reqs[0].error)
+    # waves 0, 1 (ahead, dropped), 1 again, 2 (ahead, used)
+    assert (_uploads() - up0).tolist() == [4, 2]
+    assert srv.queue == [] and srv._staged is None
+    _assert_bitwise_unbatched(zoo, reqs[2:])
+    more = submit_n(zoo, 2, spacing=0.0, uid0=10)
+    assert_accounted(zoo.serve(), 2)
+    _assert_bitwise_unbatched(zoo, more)
+
+
+def test_retried_waves_serve_bitwise_with_uploads_ahead():
+    """Modeled dispatch failures put retries and never-run attempts
+    between the waves that run: every served row is still bitwise its
+    unbatched forward, and no upload is used by a wave it was not made
+    for."""
+    zoo = fresh_zoo(policy=FIFOPolicy(),
+                    faults=FaultInjector(ChaosConfig(
+                        seed=3, dispatch_fail_rate=0.4)),
+                    recovery=RecoveryConfig(max_retries=3))
+    reqs = submit_n(zoo, 10, spacing=0.0)
+    up0 = _uploads()
+    report = zoo.serve()
+    assert_accounted(report, 10)
+    assert report.retry_count > 0
+    served = [r for r in reqs if r.status == "served"]
+    assert served
+    _assert_bitwise_unbatched(zoo, served)
+    ran = sum(1 for d in report.decisions if d.fault in ("none", "corrupt"))
+    assert (_uploads() - up0)[0] == ran
